@@ -35,6 +35,7 @@ __all__ = [                 # re-exported for the fig*.py drivers
     "ycsb_worker", "ClusterConfig", "GAMConfig", "SELCCConfig",
     "SELCCLayer", "available_protocols", "BASELINES", "HARD_LIMIT",
     "build_layer", "run_micro", "emit", "timer", "write_bench_json",
+    "shard_mesh",
 ]
 
 HARD_LIMIT = 300.0          # sim-seconds safety net
@@ -42,6 +43,23 @@ HARD_LIMIT = 300.0          # sim-seconds safety net
 # Baseline sweep used by the comparison figures; any registered backend
 # name is a valid series.
 BASELINES = ("selcc", "sel", "gam", "rpc")
+
+
+def shard_mesh(n_shards: int):
+    """A ``("shards",)`` mesh over the first ``n_shards`` devices of this
+    process.  Cells run in-process (one process per chip), so a CPU run
+    that needs several devices gets them from the caller's environment:
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
+    import jax
+    devices = jax.devices()
+    if len(devices) < n_shards:
+        raise RuntimeError(
+            f"a {n_shards}-shard cell needs {n_shards} devices, JAX has "
+            f"{len(devices)}; on "
+            f"the CPU set XLA_FLAGS=--xla_force_host_platform_device_count"
+            f"={n_shards}")
+    return jax.make_mesh((n_shards,), ("shards",),
+                         devices=devices[:n_shards])
 
 
 def build_layer(protocol: str, n_compute: int, threads: int,

@@ -15,22 +15,20 @@ SAME YCSB-style Zipf op stream (apps/workloads.device_rounds_batches):
 * ``single`` — the unsharded PR-2 engine (``rounds.run_rounds``) as the
   flat reference the sharded planes must match.
 
-Each shard count runs in a SUBPROCESS with
-``--xla_force_host_platform_device_count=<shards>`` (the flag must be
-set before jax imports), so every cell gets a fresh jit cache and its
-own honest wall clock.  Emits CSV rows plus ``BENCH_rounds_sharded.json``
-via ``benchmarks.common.write_bench_json`` — the artifact the CI
+Every shard count runs in this process, on the first ``shards`` devices
+(one process per chip: a child could not reach a device its parent
+holds).  On the CPU the caller provides the devices:
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.  Emits CSV rows
+plus ``BENCH_rounds_sharded.json`` via
+``benchmarks.common.write_bench_json`` — the artifact the CI
 ``bench-gate`` job uploads and gates on (benchmarks/check_regression.py).
 """
 
 from __future__ import annotations
 
 import argparse
-import json
 import math
 import os
-import subprocess
-import sys
 import time
 
 N_NODES = 8
@@ -43,8 +41,8 @@ ZIPF_THETA = 1.1            # hotter than YCSB default: ~6.5 rounds/batch,
                             # deletes is a structural, not marginal, cost
 
 
-def _child(shards: int, write_back: bool, iters: int) -> dict:
-    """Runs inside the subprocess: XLA_FLAGS is already set."""
+def _cell(shards: int, write_back: bool, iters: int) -> dict:
+    """One shard count, on the first ``shards`` devices."""
     import jax
     import numpy as np
 
@@ -52,7 +50,8 @@ def _child(shards: int, write_back: bool, iters: int) -> dict:
                                       device_rounds_batches)
     from repro.core import rounds as rp
 
-    mesh = jax.make_mesh((shards,), ("shards",))
+    from .common import shard_mesh
+    mesh = shard_mesh(shards)
     cfg = DeviceRoundsConfig(n_nodes=N_NODES, n_lines=N_LINES,
                              r_slots=R_SLOTS, read_ratio=READ_RATIO,
                              zipf_theta=ZIPF_THETA, iters=iters + 1)
@@ -187,27 +186,6 @@ def _child(shards: int, write_back: bool, iters: int) -> dict:
     return out
 
 
-def _run_cell(shards: int, write_back: bool, iters: int) -> dict:
-    """Spawn the per-shard-count subprocess and parse its JSON line."""
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={shards}",
-        PYTHONPATH="src" + (os.pathsep + os.environ["PYTHONPATH"]
-                            if os.environ.get("PYTHONPATH") else ""),
-    )
-    cmd = [sys.executable, "-m", "benchmarks.fig7_rounds", "--child",
-           "--shards", str(shards), "--iters", str(iters)]
-    if write_back:
-        cmd.append("--write-back")
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"fig7_rounds child (shards={shards}) failed:\n"
-            f"{out.stderr[-3000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
 def main(quick: bool = False, smoke: bool = False) -> list:
     from .common import emit, write_bench_json
     if smoke:
@@ -221,7 +199,7 @@ def main(quick: bool = False, smoke: bool = False) -> list:
     for write_back in modes:
         series = "wb" if write_back else "wt"
         for s in shard_counts:
-            m = _run_cell(s, write_back, iters)
+            m = _cell(s, write_back, iters)
             for metric, value in m.items():
                 emit("fig7_rounds", series, s, metric, value, rows=rows)
             if "recorder_overhead" in m:
@@ -254,15 +232,7 @@ def main(quick: bool = False, smoke: bool = False) -> list:
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--child", action="store_true")
-    ap.add_argument("--shards", type=int, default=1)
-    ap.add_argument("--iters", type=int, default=8)
-    ap.add_argument("--write-back", action="store_true")
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
-    if args.child:
-        print(json.dumps(_child(args.shards, args.write_back,
-                                args.iters)))
-    else:
-        main(quick=args.quick, smoke=args.smoke)
+    main(quick=args.quick, smoke=args.smoke)

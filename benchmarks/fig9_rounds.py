@@ -26,9 +26,9 @@ extra rounds — exactly the congestion the telemetry counters expose
 and re-homing repairs.  Uniform traffic (theta=0) runs as the control:
 placement must not cost anything when there is nothing to fix.
 
-All cells share one subprocess (fixed 4-way
-``--xla_force_host_platform_device_count``); legs are interleaved
-batch-by-batch and summarized by median per-batch time (same
+All cells run in this process on the first four devices (on the CPU:
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``); legs are
+interleaved batch-by-batch and summarized by median per-batch time (same
 methodology note as fig7_rounds).  Emits CSV rows plus
 ``BENCH_rounds_skew.json``; ``meta.speedup_floors`` relaxes the gate
 to the calibrated floors (``rehome_speedup`` >= 1.3 on the skewed
@@ -40,10 +40,6 @@ CI trajectories record WHERE the load sat, not just how fast it went.
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
-import sys
 import time
 
 N_SHARDS = 4
@@ -68,16 +64,16 @@ def _remap(line):
     return ((line % lps) * N_SHARDS + line // lps).astype(line.dtype)
 
 
-def _child(iters: int) -> dict:
-    """Runs inside the subprocess: XLA_FLAGS is already set (4 devs)."""
-    import jax
+def _cells(iters: int) -> dict:
+    """Every cell, on the first ``N_SHARDS`` devices."""
     import numpy as np
 
     from repro.apps.workloads import (DeviceRoundsConfig,
                                       device_rounds_batches)
     from repro.core import rounds as rp
 
-    mesh = jax.make_mesh((N_SHARDS,), ("shards",))
+    from .common import shard_mesh
+    mesh = shard_mesh(N_SHARDS)
 
     def open_plane(home_directory=False, replicas=False):
         state = rp.make_sharded_state(
@@ -164,28 +160,10 @@ def _child(iters: int) -> dict:
     return cells
 
 
-def _run_child(iters: int) -> dict:
-    """Spawn the 4-device subprocess and parse its JSON line."""
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={N_SHARDS}",
-        PYTHONPATH="src" + (os.pathsep + os.environ["PYTHONPATH"]
-                            if os.environ.get("PYTHONPATH") else ""),
-    )
-    cmd = [sys.executable, "-m", "benchmarks.fig9_rounds", "--child",
-           "--iters", str(iters)]
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                         timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"fig9_rounds child failed:\n{out.stderr[-3000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
 def main(quick: bool = False, smoke: bool = False) -> list:
     from .common import emit, write_bench_json
     iters = 9 if (smoke or quick) else 15
-    cells = _run_child(iters)
+    cells = _cells(iters)
     rows: list = []
     telemetry = {}
     for key, m in cells.items():
@@ -224,12 +202,7 @@ def main(quick: bool = False, smoke: bool = False) -> list:
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--child", action="store_true")
-    ap.add_argument("--iters", type=int, default=6)
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
-    if args.child:
-        print(json.dumps(_child(args.iters)))
-    else:
-        main(quick=args.quick, smoke=args.smoke)
+    main(quick=args.quick, smoke=args.smoke)

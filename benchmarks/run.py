@@ -8,6 +8,10 @@ time per figure.  ``--smoke`` is the CI trajectory job: a fast subset
 that writes the machine-readable ``BENCH_*.json`` artifacts — the
 device-plane rounds sweeps, the DES plane (``BENCH_selcc.json``), and
 the serving engine (``BENCH_serving.json``).
+
+Everything runs in this one process (one process per chip).  The
+sharded cells take the first devices JAX reports; on the CPU the caller
+provides them: ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 
 from __future__ import annotations
@@ -67,6 +71,9 @@ def main() -> None:
                          "roofline")
     args = ap.parse_args()
 
+    sys.path.insert(0, "src")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("figure,series,x,metric,value")
     if args.smoke:
         t0 = time.time()

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from ..kernels.latch_ops.ops import apply_batch
 
 FIELDS = ("line", "op", "arg_hi", "arg_lo", "cmp_hi", "cmp_lo")
@@ -38,8 +36,9 @@ def make_sharded_words(n_lines: int, mesh, axis: str = "model"):
     n = mesh.shape[axis]
     assert n_lines % n == 0
     words = jnp.zeros((n_lines, 2), jnp.int32)
+    from .rounds.sharded import auto_mesh
     return jax.device_put(
-        words, jax.sharding.NamedSharding(mesh, P(axis, None)))
+        words, jax.sharding.NamedSharding(auto_mesh(mesh), P(axis, None)))
 
 
 def _bucket(requests, n_shards: int, cap: int, fields=FIELDS, home=None):
@@ -128,9 +127,10 @@ def distributed_latch_round(words, requests, *, mesh, axis: str = "model",
                 unbucket(r_ok.astype(jnp.int32)),
                 jax.lax.psum(dropped, axis))
 
+    from .rounds.sharded import auto_mesh
     spec_req = {k: P(axis) for k in FIELDS}
-    return shard_map(
-        body, mesh=mesh,
+    return jax.shard_map(
+        body, mesh=auto_mesh(mesh),
         in_specs=(P(axis, None), spec_req),
         out_specs=(P(axis, None), P(axis), P(axis), P(axis), P()),
         check_vma=False,

@@ -487,10 +487,13 @@ class DevicePlane:
         self.state = dict(self.state)
         if self.sharded:
             from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from .sharded import auto_mesh
+            mesh = auto_mesh(self.mesh)
             for k, v in leaves.items():
                 self.state[k] = jax.device_put(
-                    jnp.asarray(v), NamedSharding(
-                        self.mesh, P(*([None] * v.ndim))))
+                    jnp.asarray(v),
+                    NamedSharding(mesh, P(*([None] * v.ndim))))
         else:
             for k, v in leaves.items():
                 self.state[k] = jnp.asarray(v)

@@ -68,9 +68,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from ...compat import shard_map
 from .. import coherence as co
 from ..distributed_rounds import _bucket
 from . import state as st
@@ -80,6 +79,18 @@ OP_FIELDS = ("node", "line", "isw")
 
 
 # --------------------------------------------------------------- state I/O
+
+def auto_mesh(mesh):
+    """``mesh`` with every axis in ``Auto`` mode.  ``jax.make_mesh``
+    builds ``Explicit`` axes by default, which make the sharding part of
+    each array's type; the stripe permutations (reshapes across the
+    sharded line axis) are then refused.  The plane places and runs its
+    state on the ``Auto`` form, so it accepts either kind of mesh."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
 
 def _line_spec(name: str, ndim: int, axis: str) -> P:
     if name in st.GLOBAL_LEAVES:
@@ -103,6 +114,7 @@ def shard_state(state, mesh, axis: str = "shards"):
         raise ValueError(
             f"n_lines={n_lines} not divisible by n_shards={n_shards}")
     striped = st.stripe_state(state, n_shards)
+    mesh = auto_mesh(mesh)
     return {k: jax.device_put(
         v, NamedSharding(mesh, _line_spec(k, v.ndim, axis)))
         for k, v in striped.items()}
@@ -366,8 +378,8 @@ def coherence_round_sharded(state, node_id, line, is_write, wdata=None,
             cap=cap, backend=backend)
         return state_l, served, ver, data
 
-    return shard_map(
-        spmd, mesh=mesh,
+    return jax.shard_map(
+        spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis), P(axis)),
         out_specs=(specs, P(axis), P(axis), P(axis)),
         check_vma=False,
@@ -458,8 +470,8 @@ def run_rounds_sharded(state, node_id, line, is_write, wdata=None, *,
     tele_specs = (P(axis, None), P(axis, None), P(axis), P(axis),
                   P(axis), P(axis))
     (state, versions, data, rounds, done, occ, dfr, srv, rsrv, hits,
-     whits) = shard_map(
-        spmd, mesh=mesh,
+     whits) = jax.shard_map(
+        spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis), P(axis)),
         out_specs=(specs, P(axis), P(axis), P(), P()) + tele_specs,
         check_vma=False,
@@ -608,8 +620,8 @@ def run_descent_sharded(state, node_id, key, root, *, transition, mesh,
     tele_specs = (P(axis, None), P(axis, None), P(axis), P(axis),
                   P(axis), P(axis))
     (state, cur, lanes, levels, hops, paths, plen, steps, gdone, occ,
-     dfr, srv, rsrv, hits, whits) = shard_map(
-        spmd, mesh=mesh,
+     dfr, srv, rsrv, hits, whits) = jax.shard_map(
+        spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis)),
         out_specs=(specs, P(axis), P(axis), P(axis), P(axis), P(axis),
                    P(axis), P(), P()) + tele_specs,
@@ -694,8 +706,8 @@ def evict_lines_sharded(state, node_id, line, *, mesh,
                 state_l["replica_ok"], ~emask)
         return state_l
 
-    return shard_map(
-        spmd, mesh=mesh,
+    return jax.shard_map(
+        spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis)),
         out_specs=specs,
         check_vma=False,
@@ -773,8 +785,8 @@ def rehome_exchange(state, src_slot, dst_slot, new_home, *, mesh,
         out["home"] = perm_new
         return out
 
-    return shard_map(
-        spmd, mesh=mesh,
+    return jax.shard_map(
+        spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(), P(), P()),
         out_specs=specs,
         check_vma=False,

@@ -74,11 +74,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ...compat import shard_map
 from .. import coherence as co
 from .driver import add_tele, run_rounds, zero_flat_tele
 from .engine import _note_trace
-from .sharded import _add_tele, _route_round, _state_specs, _zero_tele
+from .sharded import (_add_tele, _route_round, _state_specs, _zero_tele,
+                      auto_mesh)
 from .state import payload_width
 
 LOCK_LANE = 0
@@ -404,8 +404,8 @@ def run_txn_rounds_sharded(state, node_id, glines, rmask, wmask, ts, *,
     tele_specs = (P(axis, None), P(axis, None), P(axis), P(axis),
                   P(axis), P(axis))
     (state, dec, estep, retr, it, alldone, ok, rounds, occ, dfr, srv,
-     rsrv, hits, whits) = shard_map(
-        spmd, mesh=mesh,
+     rsrv, hits, whits) = jax.shard_map(
+        spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=(specs, P(axis), P(axis), P(axis), P(), P(), P(),
                    P()) + tele_specs,

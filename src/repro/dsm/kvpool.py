@@ -99,6 +99,9 @@ def make_pool(cfg: KVPoolConfig, mesh=None, axis: str = "shards"):
                          f"mesh's {n_shards} shards")
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from ..core.rounds.sharded import auto_mesh
+    mesh = auto_mesh(mesh)
+
     def put(name, arr):
         if arr.ndim == 0:                       # counters: replicated
             spec = P()

@@ -10,10 +10,7 @@ from .gcl_fetch import gcl_fetch
 from .ref import gcl_fetch_ref
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
-def fetch(pages, words, req_page, bit_hi, bit_lo, *, backend: str = "ref",
-          interpret: bool = True):
-    if backend == "pallas":
-        return gcl_fetch(pages, words, req_page, bit_hi, bit_lo,
-                         interpret=interpret)
-    return gcl_fetch_ref(pages, words, req_page, bit_hi, bit_lo)
+@functools.partial(jax.jit, static_argnames=("backend",))
+def fetch(pages, words, req_page, bit_hi, bit_lo, *, backend: str = "ref"):
+    fn = gcl_fetch if backend == "pallas" else gcl_fetch_ref
+    return fn(pages, words, req_page, bit_hi, bit_lo)

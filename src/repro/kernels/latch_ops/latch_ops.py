@@ -3,9 +3,9 @@
 TPU adaptation of RDMA atomics (DESIGN.md Sec. 2): every GCL's 64-bit
 latch word is owned by its home shard; a coherence round delivers up to R
 requests to the shard, and this kernel applies them *sequentially* (the
-serialization that the NIC atomic unit provides in the paper) against the
-VMEM-resident block of latch words, returning the pre-op word per request
-(exactly what RDMA_CAS/RDMA_FAA return — the directory ride-back trick).
+serialization that the NIC atomic unit provides in the paper), returning
+the pre-op word per request (exactly what RDMA_CAS/RDMA_FAA return — the
+directory ride-back trick).
 
 Latch words are carried as 2 x int32 lanes (TPUs are 32-bit machines):
     hi = (writer_id+1) << 24 | readers[55:32]   lo = readers[31:0]
@@ -16,101 +16,89 @@ Request encoding (int32):
     req_arg_hi/lo[R]       swap value (CAS) or addend (FAA)
     req_cmp_hi/lo[R]       compare value (CAS only)
 
-Grid: one step per line-block of N_BLOCK words; requests whose line falls
-in the block are applied in request order; replies accumulate into a
-persistent output block (index_map pins them to block 0).
+The atomic unit is the TPU's scalar core: the R requests, and the R
+words they name, sit in SMEM and one scalar loop applies them in request
+order.  The wrapper gathers each request's word from the [N, 2] plane
+and names its LEAD slot (the first request to the same line); requests
+to one line read and update the lead's running word, which is how they
+serialize.  The final words scatter back in one pass (every slot of a
+line carries the same final value, so the scatter is order-free).
 """
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-N_BLOCK = 1024      # latch words per grid step (8 KB of VMEM)
+from ..platform import compiled_on_tpu
+
+SIGN_BIT = -2147483648     # int32 0x80000000: unsigned compare via signed
 
 
 def _kernel(line_ref, op_ref, arg_hi_ref, arg_lo_ref, cmp_hi_ref,
-            cmp_lo_ref, words_ref, out_words_ref, old_hi_ref, old_lo_ref,
-            ok_ref):
-    blk = pl.program_id(0)
-    base = blk * N_BLOCK
-    out_words_ref[...] = words_ref[...]
+            cmp_lo_ref, lead_ref, word_hi_ref, word_lo_ref, old_hi_ref,
+            old_lo_ref, ok_ref, fin_hi_ref, fin_lo_ref):
     r = line_ref.shape[0]
 
-    @pl.when(blk == 0)
-    def _init_replies():
-        old_hi_ref[...] = jnp.zeros_like(old_hi_ref)
-        old_lo_ref[...] = jnp.zeros_like(old_lo_ref)
-        ok_ref[...] = jnp.zeros_like(ok_ref)
+    def load(i, c):
+        fin_hi_ref[i] = word_hi_ref[i]
+        fin_lo_ref[i] = word_lo_ref[i]
+        return c
 
-    def body(i, _):
-        line = line_ref[i]
-        in_blk = jnp.logical_and(line >= base, line < base + N_BLOCK)
+    def apply(i, c):
+        valid = line_ref[i] >= 0
+        j = lead_ref[i]
+        hi = fin_hi_ref[j]
+        lo = fin_lo_ref[j]
+        is_cas = op_ref[i] == 0
+        # CAS: whole-64-bit compare
+        hit = jnp.logical_and(hi == cmp_hi_ref[i], lo == cmp_lo_ref[i])
+        # FAA: 64-bit add, carry out of the low lane = unsigned overflow
+        sum_lo = lo + arg_lo_ref[i]
+        carry = ((sum_lo ^ SIGN_BIT) < (lo ^ SIGN_BIT)).astype(jnp.int32)
+        new_hi = jnp.where(is_cas, jnp.where(hit, arg_hi_ref[i], hi),
+                           hi + arg_hi_ref[i] + carry)
+        new_lo = jnp.where(is_cas, jnp.where(hit, arg_lo_ref[i], lo),
+                           sum_lo)
+        fin_hi_ref[j] = jnp.where(valid, new_hi, hi)
+        fin_lo_ref[j] = jnp.where(valid, new_lo, lo)
+        old_hi_ref[i] = jnp.where(valid, hi, 0)
+        old_lo_ref[i] = jnp.where(valid, lo, 0)
+        ok_ref[i] = jnp.where(valid,
+                              jnp.where(is_cas, hit.astype(jnp.int32), 1),
+                              0)
+        return c
 
-        @pl.when(in_blk)
-        def _apply():
-            idx = line - base
-            hi = out_words_ref[idx, 0]
-            lo = out_words_ref[idx, 1]
-            is_cas = op_ref[i] == 0
-            # CAS: whole-64-bit compare
-            cas_hit = jnp.logical_and(hi == cmp_hi_ref[i],
-                                      lo == cmp_lo_ref[i])
-            cas_hi = jnp.where(cas_hit, arg_hi_ref[i], hi)
-            cas_lo = jnp.where(cas_hit, arg_lo_ref[i], lo)
-            # FAA: 64-bit add with carry across the two lanes (uint32)
-            ulo = lo.astype(jnp.uint32)
-            uadd = arg_lo_ref[i].astype(jnp.uint32)
-            sum_lo = ulo + uadd
-            carry = (sum_lo < ulo).astype(jnp.int32)
-            faa_hi = hi + arg_hi_ref[i] + carry
-            faa_lo = sum_lo.astype(jnp.int32)
-            new_hi = jnp.where(is_cas, cas_hi, faa_hi)
-            new_lo = jnp.where(is_cas, cas_lo, faa_lo)
-            out_words_ref[idx, 0] = new_hi
-            out_words_ref[idx, 1] = new_lo
-            old_hi_ref[i] = hi
-            old_lo_ref[i] = lo
-            ok_ref[i] = jnp.where(is_cas, cas_hit.astype(jnp.int32), 1)
-        return 0
-
-    jax.lax.fori_loop(0, r, body, 0)
+    jax.lax.fori_loop(0, r, load, 0)
+    jax.lax.fori_loop(0, r, apply, 0)
 
 
-def latch_apply(words, line, op, arg_hi, arg_lo, cmp_hi, cmp_lo,
-                interpret: bool = False):
+def _serialize(*reqs, interpret: bool):
+    r = reqs[0].shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        _kernel,
+        in_specs=[smem] * len(reqs),
+        out_specs=[smem] * 5,
+        out_shape=[jax.ShapeDtypeStruct((r,), jnp.int32)] * 5,
+        interpret=interpret,
+    )(*reqs)
+
+
+def latch_apply(words, line, op, arg_hi, arg_lo, cmp_hi, cmp_lo):
     """words: [N, 2] int32; request arrays [R] int32 (line = -1 for empty).
     Returns (new_words [N,2], old_hi [R], old_lo [R], ok [R])."""
     n = words.shape[0]
-    r = line.shape[0]
-    assert n % N_BLOCK == 0, f"words ({n}) must pad to {N_BLOCK}"
-    grid = (n // N_BLOCK,)
-    out = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((N_BLOCK, 2), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((N_BLOCK, 2), lambda i: (i, 0)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 2), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(line, op, arg_hi, arg_lo, cmp_hi, cmp_lo, words)
-    return out[0], out[1], out[2], out[3]
+    valid = line >= 0
+    idx = jnp.maximum(line, 0)
+    same = jnp.logical_and(line[:, None] == line[None, :], valid[None, :])
+    lead = jnp.argmax(same, axis=1).astype(jnp.int32)
+    word = words[idx]
+    old_hi, old_lo, ok, fin_hi, fin_lo = compiled_on_tpu(
+        _serialize, line, op, arg_hi, arg_lo, cmp_hi, cmp_lo, lead,
+        word[:, 0], word[:, 1])
+    final = jnp.stack([fin_hi[lead], fin_lo[lead]], axis=1)
+    new_words = words.at[jnp.where(valid, idx, n)].set(final, mode="drop")
+    return new_words, old_hi, old_lo, ok

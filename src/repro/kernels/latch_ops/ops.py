@@ -1,8 +1,8 @@
 """jit'd public wrapper for the latch_ops kernel.
 
-``backend='pallas'`` targets TPU (validated on CPU with interpret=True);
-``backend='ref'`` is the jnp oracle — the serving integration picks ref
-on CPU automatically.
+``backend='pallas'`` runs the kernel (compiled on a TPU, interpreted
+elsewhere — see ``kernels/platform.py``); ``backend='ref'`` is the jnp
+oracle.
 """
 
 from __future__ import annotations
@@ -10,34 +10,19 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
-from .latch_ops import N_BLOCK, latch_apply
+from .latch_ops import latch_apply
 from .ref import latch_apply_ref
 
 OP_CAS = 0
 OP_FAA = 1
 
 
-def pad_words(words):
-    n = words.shape[0]
-    pad = (-n) % N_BLOCK
-    if pad:
-        words = jnp.pad(words, ((0, pad), (0, 0)))
-    return words, n
-
-
-@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
-def apply_batch(words, requests, backend: str = "ref",
-                interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("backend",))
+def apply_batch(words, requests, backend: str = "ref"):
     """words: [N,2] int32.  requests: dict with line/op/arg_hi/arg_lo/
     cmp_hi/cmp_lo int32 [R].  Returns (new_words, old_hi, old_lo, ok)."""
     r = requests
-    if backend == "pallas":
-        padded, n = pad_words(words)
-        new_w, old_hi, old_lo, ok = latch_apply(
-            padded, r["line"], r["op"], r["arg_hi"], r["arg_lo"],
-            r["cmp_hi"], r["cmp_lo"], interpret=interpret)
-        return new_w[:n], old_hi, old_lo, ok
-    return latch_apply_ref(words, r["line"], r["op"], r["arg_hi"],
-                           r["arg_lo"], r["cmp_hi"], r["cmp_lo"])
+    fn = latch_apply if backend == "pallas" else latch_apply_ref
+    return fn(words, r["line"], r["op"], r["arg_hi"], r["arg_lo"],
+              r["cmp_hi"], r["cmp_lo"])

@@ -6,14 +6,15 @@ import functools
 
 import jax
 
+from ..platform import compiled_on_tpu
 from .paged_attention import paged_attention
 from .ref import paged_attention_ref
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
+@functools.partial(jax.jit, static_argnames=("backend",))
 def decode_paged(q, k_pages, v_pages, page_tbl, lens, *,
-                 backend: str = "ref", interpret: bool = True):
+                 backend: str = "ref"):
     if backend == "pallas":
-        return paged_attention(q, k_pages, v_pages, page_tbl, lens,
-                               interpret=interpret)
+        return compiled_on_tpu(paged_attention, q, k_pages, v_pages,
+                               page_tbl, lens)
     return paged_attention_ref(q, k_pages, v_pages, page_tbl, lens)

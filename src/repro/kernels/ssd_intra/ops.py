@@ -6,13 +6,13 @@ import functools
 
 import jax
 
+from ..platform import compiled_on_tpu
 from .ref import ssd_intra_ref
 from .ssd_intra import ssd_intra
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
-def intra_chunk(cb, cs, win, *, backend: str = "ref",
-                interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("backend",))
+def intra_chunk(cb, cs, win, *, backend: str = "ref"):
     if backend == "pallas":
-        return ssd_intra(cb, cs, win, interpret=interpret)
+        return compiled_on_tpu(ssd_intra, cb, cs, win)
     return ssd_intra_ref(cb, cs, win)

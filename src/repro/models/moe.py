@@ -24,8 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from . import layers
 
 
@@ -131,7 +129,7 @@ def moe_ffn(x, p, cfg, parallel=None):
                         ("we_g", "we_u", "we_d") if k in p}})
         routed = {k: p[k] for k in ("router", "we_g", "we_u", "we_d")
                   if k in p}
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs,
             out_specs=(xspec, P()),
             check_vma=False)(x, routed)

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 
 def pipeline_forward(stage_fn, params_stacked, x_micro, *, mesh,
                      axis: str = "pipe"):
@@ -74,18 +72,16 @@ def pipeline_forward(stage_fn, params_stacked, x_micro, *, mesh,
         # broadcast results from the last stage to all (bijection-safe:
         # zero elsewhere + psum over the pipe axis)
         outs = jnp.where(sid == n_stages - 1, outs, 0)
-        outs = jax.lax.psum(outs, axis)
-        return outs[None]
+        return jax.lax.psum(outs, axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), params_stacked)
-    out = shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_params, P(axis)),
-        out_specs=P(axis),
+        out_specs=P(),
         check_vma=False,
     )(params_stacked,
       jnp.broadcast_to(x_micro[None], (n_stages,) + x_micro.shape))
-    return out[0]
 
 
 def split_stages(stacked_params, n_stages: int):

@@ -1,5 +1,6 @@
-"""Pallas kernel validation: interpret-mode vs pure-jnp oracle, swept over
-shapes/dtypes (+ hypothesis sweeps for the latch kernel)."""
+"""Pallas kernel validation: the kernels (interpreted on the CPU, see
+kernels/platform.py) vs pure-jnp oracles, swept over shapes/dtypes (+
+hypothesis sweeps for the latch kernel)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +28,7 @@ def test_flash_attention_matches_ref(b, s, hq, hkv, hd, causal, dtype):
     v = jnp.asarray(rng.normal(size=(b, s, hkv, hd)), dtype)
     ref = attention(q, k, v, causal=causal, backend="ref")
     pal = attention(q, k, v, causal=causal, backend="pallas",
-                    interpret=True, block_q=128, block_k=128)
+                    block_q=128, block_k=128)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert float(jnp.max(jnp.abs(ref.astype(jnp.float32)
                                  - pal.astype(jnp.float32)))) < tol
@@ -48,8 +49,7 @@ def test_paged_attention_matches_ref(b, hq, hkv, hd, page, mp, pool,
     tbl = jnp.asarray(rng.integers(0, pool, (b, mp)), jnp.int32)
     lens = jnp.asarray(rng.integers(1, mp * page, b), jnp.int32)
     ref = decode_paged(q, kp, vp, tbl, lens, backend="ref")
-    pal = decode_paged(q, kp, vp, tbl, lens, backend="pallas",
-                       interpret=True)
+    pal = decode_paged(q, kp, vp, tbl, lens, backend="pallas")
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     assert float(jnp.max(jnp.abs(ref.astype(jnp.float32)
                                  - pal.astype(jnp.float32)))) < tol
@@ -72,7 +72,7 @@ def test_latch_ops_match_ref(seed, r):
             np.asarray(words)[np.maximum(line, 0), 1], jnp.int32),
     }
     ref = apply_batch(words, req, backend="ref")
-    pal = apply_batch(words, req, backend="pallas", interpret=True)
+    pal = apply_batch(words, req, backend="pallas")
     for a, b in zip(ref, pal):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -194,8 +194,7 @@ def test_gcl_fetch_matches_ref(pool, elems, r):
     bit_hi = jnp.zeros((r,), jnp.int32)
     bit_lo = jnp.asarray(rng.integers(1, 2 ** 8, r), jnp.int32)
     ref = fetch(pages, words, req_page, bit_hi, bit_lo, backend="ref")
-    pal = fetch(pages, words, req_page, bit_hi, bit_lo, backend="pallas",
-                interpret=True)
+    pal = fetch(pages, words, req_page, bit_hi, bit_lo, backend="pallas")
     np.testing.assert_allclose(np.asarray(ref[0]), np.asarray(pal[0]))
     np.testing.assert_array_equal(np.asarray(ref[3]), np.asarray(pal[3]))
     np.testing.assert_array_equal(np.asarray(ref[4]), np.asarray(pal[4]))
@@ -215,7 +214,7 @@ def test_ssd_intra_matches_ref(b, q, h, p, dtype):
                      * 0.1, dtype)
     win = jnp.asarray(rng.normal(size=(b, q, h, p)), dtype)
     ref = intra_chunk(cb, cs, win, backend="ref")
-    pal = intra_chunk(cb, cs, win, backend="pallas", interpret=True)
+    pal = intra_chunk(cb, cs, win, backend="pallas")
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-4
     err = float(jnp.max(jnp.abs(ref.astype(jnp.float32)
                                 - pal.astype(jnp.float32))))
@@ -236,7 +235,7 @@ def test_ssd_intra_matches_model_branch():
     x = jnp.asarray(rng.normal(size=(b, q, h, p)), jnp.float32)
     cb = jnp.einsum("bqn,bkn->bqk", cmat, bmat)
     win = dt[..., None] * x
-    got = intra_chunk(cb, cs, win, backend="pallas", interpret=True)
+    got = intra_chunk(cb, cs, win, backend="pallas")
     # the model's einsum form
     seg = cs[:, :, None, :] - cs[:, None, :, :]
     l_mat = jnp.where(jnp.tril(jnp.ones((q, q), bool))[None, :, :, None],
