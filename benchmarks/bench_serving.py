@@ -29,9 +29,9 @@ Observability: the engine legs run with a ``FlightRecorder`` attached
 to the pool's plane, so the artifact carries the full flight record —
 ``meta.telemetry`` embeds the recorder snapshot (spans/rounds/serve
 totals, hottest lines) plus the engine's queue-wait and time-per-
-output-token histogram snapshots, and the last measured run's span
-ring exports to ``BENCH_serving_trace.json`` (chrome://tracing /
-Perfetto — the artifact CI uploads next to ``BENCH_serving.json``).
+output-token histogram snapshots.  For a timeline, profile the run
+with ``jax.profiler``: every plane dispatch is a ``plane.<verb>``
+annotation there, on the device's clock.
 """
 
 from __future__ import annotations
@@ -186,10 +186,6 @@ def main(quick: bool = False, smoke: bool = False) -> list:
              st.tpot["p50"] * 1e3, rows=rows)
         emit("serving", "engine", N_SLOTS, "tpot_p99_ms",
              st.tpot["p99"] * 1e3, rows=rows)
-
-    # the last measured engine run's span ring, viewable in
-    # chrome://tracing / Perfetto; CI uploads it next to the JSON
-    rec.export_chrome_trace("BENCH_serving_trace.json")
 
     # gate_max_regress 0.6: a serve tick is a few SMALL dispatches
     # (fused append + attend) plus host-side bookkeeping, jittery under

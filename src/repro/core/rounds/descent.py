@@ -92,10 +92,10 @@ def run_descent(state, node_id, key, root, *, transition, n_nodes: int,
         st, cur, done, lanes, levels, hops, paths, plen, steps, tele \
             = carry
         line = jnp.where(done, jnp.int32(-1), cur)
-        st, served, _, d = _round_impl(st, node_id, line, no_write,
-                                       no_bytes, n_nodes=n_nodes,
-                                       backend=backend)
-        tele = _tele_round(tele, line, served, no_write, n_lines)
+        st, served, _, d, events = _round_impl(
+            st, node_id, line, no_write, no_bytes, n_nodes=n_nodes,
+            backend=backend)
+        tele = _tele_round(tele, line, served, no_write, events, n_lines)
         at_leaf, hop, nxt = transition(d, key)
         move = jnp.logical_and(served, ~done)
         hop = jnp.logical_and(move, hop)

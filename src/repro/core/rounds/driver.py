@@ -18,7 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .engine import _note_trace, coherence_round
+from .engine import _note_trace, _round_jit
 from .state import payload_width
 
 
@@ -27,14 +27,17 @@ def zero_flat_tele(n_lines: int) -> dict:
     sharded drivers return, shaped for S = 1 home (``occupancy`` /
     ``deferred`` [1, 1], ``served_per_home`` / ``replica_served`` [1],
     per-line ``slot_hits`` / ``slot_whits`` [L]; the flat engine is
-    line-major, so slot == line).  Rides the fused loops' carries —
-    accumulating costs two scatter-adds per round, zero host syncs."""
+    line-major, so slot == line; ``events`` [1, 3], the round body's
+    protocol events in ``engine.EVENT_KEYS`` order).  Rides the fused
+    loops' carries — accumulating costs two scatter-adds per round,
+    zero host syncs."""
     z1 = jnp.zeros((1,), jnp.int32)
     return {"occupancy": jnp.zeros((1, 1), jnp.int32),
             "deferred": jnp.zeros((1, 1), jnp.int32),
             "served_per_home": z1, "replica_served": z1,
             "slot_hits": jnp.zeros((n_lines,), jnp.int32),
-            "slot_whits": jnp.zeros((n_lines,), jnp.int32)}
+            "slot_whits": jnp.zeros((n_lines,), jnp.int32),
+            "events": jnp.zeros((1, 3), jnp.int32)}
 
 
 def add_tele(a: dict, b: dict) -> dict:
@@ -42,10 +45,12 @@ def add_tele(a: dict, b: dict) -> dict:
     return {k: a[k] + b[k] for k in a}
 
 
-def _tele_round(tele: dict, pending, served, is_write,
+@jax.named_scope("round/telemetry")
+def _tele_round(tele: dict, pending, served, is_write, events,
                 n_lines: int) -> dict:
-    """Fold one round's serve results into a flat telemetry carry:
-    ``pending`` is the PRE-round line per slot (-1 = done/pad)."""
+    """Fold one round's serve results and protocol ``events`` into a
+    flat telemetry carry: ``pending`` is the PRE-round line per slot
+    (-1 = done/pad)."""
     valid = pending >= 0
     hit = jnp.logical_and(served, valid)
     hit_line = jnp.where(hit, pending, n_lines)      # n_lines = dropped
@@ -58,7 +63,8 @@ def _tele_round(tele: dict, pending, served, is_write,
     return {"occupancy": occ, "deferred": tele["deferred"],
             "served_per_home": srv,
             "replica_served": tele["replica_served"],
-            "slot_hits": hits, "slot_whits": whits}
+            "slot_hits": hits, "slot_whits": whits,
+            "events": tele["events"] + events[None, :]}
 
 
 @functools.partial(jax.jit,
@@ -100,10 +106,11 @@ def run_rounds(state, node_id, line, is_write, wdata=None, *,
 
     def body(carry):
         st, pending, versions, data, rounds, tele = carry
-        st, served, ver, rdata = coherence_round(
+        st, served, ver, rdata, events = _round_jit(
             st, node_id, pending, is_write, wdata, n_nodes=n_nodes,
             backend=backend)
-        tele = _tele_round(tele, pending, served, is_write, n_lines)
+        tele = _tele_round(tele, pending, served, is_write, events,
+                           n_lines)
         versions = jnp.where(served, ver, versions)
         data = jnp.where(served[:, None], rdata, data)
         pending = jnp.where(served, jnp.int32(-1), pending)

@@ -63,8 +63,6 @@ Cache states per (node, line): 0=I 1=S 2=M (coherence.I/S/M).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
@@ -80,6 +78,14 @@ I, S, M = co.I, co.S, co.M
 TRACE_COUNTS: dict = {}
 
 
+# Protocol events a round counts, in the order of ``_round_impl``'s
+# ``events`` vector: latch requests issued by group representatives (a
+# CAS or FAA on the word), the requests that failed (they retry next
+# round), and served slots whose group hit the node's own cached copy
+# with no request at all (SELCC's lazy latch).
+EVENT_KEYS = ("latch_requests", "latch_failed", "local_hits")
+
+
 def _note_trace(key) -> None:
     TRACE_COUNTS[key] = TRACE_COUNTS.get(key, 0) + 1
 
@@ -92,9 +98,17 @@ def _round_impl(state, node_id, line, is_write, wdata=None, *,
     LOCAL slab and ``line`` carries local (striped) indices.
 
     ``wdata`` [R, W] carries write payloads on a payload-plane state
-    (``None`` = all-zero payloads); returns a 4-tuple ``(state', served,
-    version, data)`` where ``data`` [R, W] holds each served slot's read
-    payload (W = 0 on version-only states)."""
+    (``None`` = all-zero payloads); returns a 5-tuple ``(state', served,
+    version, data, events)`` where ``data`` [R, W] holds each served
+    slot's read payload (W = 0 on version-only states) and ``events``
+    int32 [3] counts this round's protocol events in
+    :data:`EVENT_KEYS` order.
+
+    Each phase runs under a ``jax.named_scope`` (``round/coalesce``,
+    ``round/latch``, ``round/fetch``, ``round/boundary``,
+    ``round/reply``, ``round/replica``, ``round/telemetry``), so a
+    profile attributes device time to a phase by the ops' HLO metadata
+    rather than by fusion numbers."""
     co.check_node_capacity(n_nodes)
     write_back = "dirty" in state
     words = state["words"]
@@ -112,161 +126,173 @@ def _round_impl(state, node_id, line, is_write, wdata=None, *,
     _note_trace(("round", n_nodes, n_lines, r, backend, write_back,
                  width))
 
-    valid = line >= 0
-    idx = jnp.maximum(line, 0)
-    is_w = jnp.logical_and(is_write.astype(bool), valid)
-
     # ------------- 0. coalesce duplicate (node, line) slots ---------------
-    key = node_id * n_lines + idx
-    eq = jnp.logical_and(key[:, None] == key[None, :],
-                         jnp.logical_and(valid[:, None], valid[None, :]))
-    first = jnp.argmax(eq, axis=1)                 # my group's first slot
-    is_rep = jnp.logical_and(valid, first == jnp.arange(r))
-    grp_write = jnp.any(jnp.logical_and(eq, is_w[None, :]), axis=1)
-    lower = jnp.tril(jnp.ones((r, r), bool), k=-1)
-    in_grp_w = jnp.logical_and(eq, is_w[None, :])
-    w_rank = jnp.sum(jnp.logical_and(in_grp_w, lower), axis=1) \
-        .astype(jnp.int32)                         # writes before me
-    n_w_grp = jnp.sum(in_grp_w, axis=1).astype(jnp.int32)
-    # last write slot of my group — slot order IS the serialization
-    # order, so its wdata is the group's final payload
-    last_w = jnp.maximum(
-        jnp.max(jnp.where(in_grp_w, jnp.arange(r), -1), axis=1), 0)
+    with jax.named_scope("round/coalesce"):
+        valid = line >= 0
+        idx = jnp.maximum(line, 0)
+        is_w = jnp.logical_and(is_write.astype(bool), valid)
+        key = node_id * n_lines + idx
+        eq = jnp.logical_and(key[:, None] == key[None, :],
+                             jnp.logical_and(valid[:, None],
+                                             valid[None, :]))
+        first = jnp.argmax(eq, axis=1)             # my group's first slot
+        is_rep = jnp.logical_and(valid, first == jnp.arange(r))
+        grp_write = jnp.any(jnp.logical_and(eq, is_w[None, :]), axis=1)
+        lower = jnp.tril(jnp.ones((r, r), bool), k=-1)
+        in_grp_w = jnp.logical_and(eq, is_w[None, :])
+        w_rank = jnp.sum(jnp.logical_and(in_grp_w, lower), axis=1) \
+            .astype(jnp.int32)                     # writes before me
+        n_w_grp = jnp.sum(in_grp_w, axis=1).astype(jnp.int32)
+        # last write slot of my group — slot order IS the serialization
+        # order, so its wdata is the group's final payload
+        last_w = jnp.maximum(
+            jnp.max(jnp.where(in_grp_w, jnp.arange(r), -1), axis=1), 0)
 
-    # ------------- 1. local hits (lazy latches) ---------------------------
-    st = cstate[node_id, idx]
-    hit_read = jnp.logical_and(~grp_write, st >= S)
-    hit_write = jnp.logical_and(grp_write, st == M)
-    hit = jnp.logical_and(is_rep, jnp.logical_or(hit_read, hit_write))
+    with jax.named_scope("round/latch"):
+        # --------- 1. local hits (lazy latches) ---------------------------
+        st = cstate[node_id, idx]
+        hit_read = jnp.logical_and(~grp_write, st >= S)
+        hit_write = jnp.logical_and(grp_write, st == M)
+        hit = jnp.logical_and(is_rep, jnp.logical_or(hit_read, hit_write))
 
-    # ------------- 2. latch requests for misses ---------------------------
-    miss = jnp.logical_and(is_rep, ~hit)
-    upgrade = jnp.logical_and(miss, jnp.logical_and(grp_write, st == S))
-    fresh_w = jnp.logical_and(miss, jnp.logical_and(grp_write, st != S))
-    read_miss = jnp.logical_and(miss, ~grp_write)
-    bit_hi, bit_lo = co.bit_lanes(node_id)
-    wf = co.writer_field_hi(node_id)
-    req = {
-        "line": jnp.where(miss, line, -1).astype(jnp.int32),
-        "op": jnp.where(grp_write, OP_CAS, OP_FAA).astype(jnp.int32),
-        "arg_hi": jnp.where(grp_write, wf, bit_hi).astype(jnp.int32),
-        "arg_lo": jnp.where(grp_write, 0, bit_lo).astype(jnp.int32),
-        # S->X upgrade compares against the holder's own bit; a fresh
-        # write compares against FREE (zeros)
-        "cmp_hi": jnp.where(upgrade, bit_hi, 0).astype(jnp.int32),
-        "cmp_lo": jnp.where(upgrade, bit_lo, 0).astype(jnp.int32),
-    }
-    _, old_hi, _, ok = apply_batch(words, req, backend=backend)
-    ok = ok.astype(bool)
-    old_writer = co.writer_of_hi(old_hi)
-    no_writer = old_writer < 0
-    read_grant = jnp.logical_and(read_miss, no_writer)
-    write_grant = jnp.logical_and(jnp.logical_or(upgrade, fresh_w), ok)
-    granted = jnp.logical_or(read_grant, write_grant)
-    served_rep = jnp.logical_or(hit, granted)
+        # --------- 2. latch requests for misses ---------------------------
+        miss = jnp.logical_and(is_rep, ~hit)
+        upgrade = jnp.logical_and(miss,
+                                  jnp.logical_and(grp_write, st == S))
+        fresh_w = jnp.logical_and(miss,
+                                  jnp.logical_and(grp_write, st != S))
+        read_miss = jnp.logical_and(miss, ~grp_write)
+        bit_hi, bit_lo = co.bit_lanes(node_id)
+        wf = co.writer_field_hi(node_id)
+        req = {
+            "line": jnp.where(miss, line, -1).astype(jnp.int32),
+            "op": jnp.where(grp_write, OP_CAS, OP_FAA).astype(jnp.int32),
+            "arg_hi": jnp.where(grp_write, wf, bit_hi).astype(jnp.int32),
+            "arg_lo": jnp.where(grp_write, 0, bit_lo).astype(jnp.int32),
+            # S->X upgrade compares against the holder's own bit; a fresh
+            # write compares against FREE (zeros)
+            "cmp_hi": jnp.where(upgrade, bit_hi, 0).astype(jnp.int32),
+            "cmp_lo": jnp.where(upgrade, bit_lo, 0).astype(jnp.int32),
+        }
+        _, old_hi, _, ok = apply_batch(words, req, backend=backend)
+        ok = ok.astype(bool)
+        old_writer = co.writer_of_hi(old_hi)
+        no_writer = old_writer < 0
+        read_grant = jnp.logical_and(read_miss, no_writer)
+        write_grant = jnp.logical_and(jnp.logical_or(upgrade, fresh_w), ok)
+        granted = jnp.logical_or(read_grant, write_grant)
+        served_rep = jnp.logical_or(hit, granted)
 
-    # ------------- grants + versions --------------------------------------
-    # start version of the serialized group: the node's own copy on a
-    # hit (may run ahead of memory under write-back), memory otherwise
-    # (upgrades keep a coherent S copy, so memory is equally current).
-    start = jnp.where(hit, cver[node_id, idx], mver[idx])
-    k = jnp.where(jnp.logical_and(served_rep, grp_write), n_w_grp, 0)
-    final = start + k
-    # NOTE on scatters: invalid/no-op slots are routed to row n_nodes /
-    # line n_lines and dropped, so duplicate in-bounds indices never
-    # carry stale values (scatter order is unspecified).
-    upd = granted
-    cstate = cstate.at[jnp.where(upd, node_id, n_nodes), idx].set(
-        jnp.where(read_grant, jnp.int8(S), jnp.int8(M)), mode="drop")
-    cver = cver.at[jnp.where(served_rep, node_id, n_nodes), idx].set(
-        final, mode="drop")
-    wrote = jnp.logical_and(served_rep, grp_write)
-    if write_back:
-        dirty = dirty.at[jnp.where(wrote, node_id, n_nodes), idx].set(
-            True, mode="drop")
-    else:
-        mver = mver.at[jnp.where(wrote, idx, n_lines)].add(k, mode="drop")
+        # --------- grants + versions --------------------------------------
+        # start version of the serialized group: the node's own copy on a
+        # hit (may run ahead of memory under write-back), memory otherwise
+        # (upgrades keep a coherent S copy, so memory is equally current).
+        start = jnp.where(hit, cver[node_id, idx], mver[idx])
+        k = jnp.where(jnp.logical_and(served_rep, grp_write), n_w_grp, 0)
+        final = start + k
+        # NOTE on scatters: invalid/no-op slots are routed to row n_nodes /
+        # line n_lines and dropped, so duplicate in-bounds indices never
+        # carry stale values (scatter order is unspecified).
+        upd = granted
+        cstate = cstate.at[jnp.where(upd, node_id, n_nodes), idx].set(
+            jnp.where(read_grant, jnp.int8(S), jnp.int8(M)), mode="drop")
+        cver = cver.at[jnp.where(served_rep, node_id, n_nodes), idx].set(
+            final, mode="drop")
+        wrote = jnp.logical_and(served_rep, grp_write)
+        if write_back:
+            dirty = dirty.at[jnp.where(wrote, node_id, n_nodes), idx].set(
+                True, mode="drop")
+        else:
+            mver = mver.at[jnp.where(wrote, idx, n_lines)].add(
+                k, mode="drop")
 
     # ------------- payload plane: fetch-on-grant + write-apply ------------
     gdata = None
     if width:
-        # fetch-on-grant: a miss grant installs the memory bytes (the
-        # paper's combined latch+read round trip — on the pallas backend
-        # the gather reuses the gcl_fetch kernel); a hit serves the
-        # node's own local copy, which may run ahead under write-back
-        if backend == "pallas":
-            fetch_req = jnp.where(granted, idx, -1).astype(jnp.int32)
-            no_bits = jnp.zeros_like(fetch_req)
-            fetched_g, _, _, _, _ = gcl_fetch_op(
-                mdata, words, fetch_req, no_bits, no_bits,
-                backend="pallas")
-            fetched = jnp.where(granted[:, None], fetched_g, mdata[idx])
-        else:
-            fetched = mdata[idx]
-        base = jnp.where(hit[:, None], cdata[node_id, idx], fetched)
-        # write-apply: the group's final payload is its LAST write slot's
-        # wdata (slot order = serialization order, version start+k)
-        gdata = jnp.where(grp_write[:, None], wdata[last_w], base)
-        cdata = cdata.at[jnp.where(served_rep, node_id, n_nodes), idx] \
-            .set(gdata, mode="drop")
-        if not write_back:
-            mdata = mdata.at[jnp.where(wrote, idx, n_lines)].set(
-                gdata, mode="drop")
+        with jax.named_scope("round/fetch"):
+            # fetch-on-grant: a miss grant installs the memory bytes (the
+            # paper's combined latch+read round trip — on the pallas
+            # backend the gather reuses the gcl_fetch kernel); a hit
+            # serves the node's own local copy, which may run ahead under
+            # write-back
+            if backend == "pallas":
+                fetch_req = jnp.where(granted, idx, -1).astype(jnp.int32)
+                no_bits = jnp.zeros_like(fetch_req)
+                fetched_g, _, _, _, _ = gcl_fetch_op(
+                    mdata, words, fetch_req, no_bits, no_bits,
+                    backend="pallas")
+                fetched = jnp.where(granted[:, None], fetched_g,
+                                    mdata[idx])
+            else:
+                fetched = mdata[idx]
+            base = jnp.where(hit[:, None], cdata[node_id, idx], fetched)
+            # write-apply: the group's final payload is its LAST write
+            # slot's wdata (slot order = serialization order, version
+            # start+k)
+            gdata = jnp.where(grp_write[:, None], wdata[last_w], base)
+            cdata = cdata.at[jnp.where(served_rep, node_id, n_nodes),
+                             idx].set(gdata, mode="drop")
+            if not write_back:
+                mdata = mdata.at[jnp.where(wrote, idx, n_lines)].set(
+                    gdata, mode="drop")
 
     # ------------- 3/4. round-boundary invalidations ----------------------
-    fail_w = jnp.logical_and(jnp.logical_or(upgrade, fresh_w), ~ok)
-    fail_r = jnp.logical_and(read_miss, ~no_writer)
-    wr_cnt = jnp.zeros((n_lines,), jnp.int32).at[
-        jnp.where(fail_w, idx, n_lines)].add(1, mode="drop")
-    rd_fail = jnp.zeros((n_lines,), bool).at[
-        jnp.where(fail_r, idx, n_lines)].set(True, mode="drop")
-    self_wr_fail = jnp.zeros((n_nodes, n_lines), jnp.int32).at[
-        jnp.where(fail_w, node_id, n_nodes), idx].set(1, mode="drop")
-    # PeerWr/PeerUpgr from any OTHER node kills a holder (upgraders never
-    # kill themselves; two racing upgraders kill each other and fall back
-    # to fresh acquisition — Algorithm 2's release+reacquire)
-    other_fail = (wr_cnt[None, :] - self_wr_fail) > 0
-    holder = cstate >= S
-    kill = jnp.logical_and(other_fail, holder)
-    # PeerRd with no competing writer: the M holder downgrades
-    m_mask = cstate == M
-    dg_line = jnp.logical_and(jnp.logical_and(rd_fail, wr_cnt == 0),
-                              jnp.any(m_mask, axis=0))
-    dg_mask = jnp.logical_and(dg_line[None, :], m_mask)
-    if write_back:
-        # a dirty M holder leaving M (killed or downgraded) writes back
-        flush = jnp.logical_and(jnp.logical_or(kill, dg_mask),
-                                jnp.logical_and(m_mask, dirty))
-        flush_ver = jnp.max(jnp.where(flush, cver, 0), axis=0)
-        mver = jnp.where(jnp.any(flush, axis=0), flush_ver, mver)
-        if width:
-            # dirty-flush-with-bytes: the holder's cache_data IS the
-            # flush source of truth (at most one M holder per line, so
-            # the masked sum selects exactly its row)
-            flush_data = jnp.sum(
-                jnp.where(flush[:, :, None], cdata, 0), axis=0)
-            mdata = jnp.where(jnp.any(flush, axis=0)[:, None],
-                              flush_data, mdata)
-        dirty = jnp.logical_and(dirty, ~jnp.logical_or(kill, dg_mask))
-    cstate = jnp.where(kill, jnp.int8(I), cstate)
-    cstate = jnp.where(dg_mask, jnp.int8(S), cstate)
-    # the word IS the directory: rebuild it from the post-boundary states
-    # (also clears failed readers' transient bits without a second pass)
-    words = co.directory_from_state(cstate)
+    with jax.named_scope("round/boundary"):
+        fail_w = jnp.logical_and(jnp.logical_or(upgrade, fresh_w), ~ok)
+        fail_r = jnp.logical_and(read_miss, ~no_writer)
+        wr_cnt = jnp.zeros((n_lines,), jnp.int32).at[
+            jnp.where(fail_w, idx, n_lines)].add(1, mode="drop")
+        rd_fail = jnp.zeros((n_lines,), bool).at[
+            jnp.where(fail_r, idx, n_lines)].set(True, mode="drop")
+        self_wr_fail = jnp.zeros((n_nodes, n_lines), jnp.int32).at[
+            jnp.where(fail_w, node_id, n_nodes), idx].set(1, mode="drop")
+        # PeerWr/PeerUpgr from any OTHER node kills a holder (upgraders
+        # never kill themselves; two racing upgraders kill each other and
+        # fall back to fresh acquisition — Algorithm 2's release+reacquire)
+        other_fail = (wr_cnt[None, :] - self_wr_fail) > 0
+        holder = cstate >= S
+        kill = jnp.logical_and(other_fail, holder)
+        # PeerRd with no competing writer: the M holder downgrades
+        m_mask = cstate == M
+        dg_line = jnp.logical_and(jnp.logical_and(rd_fail, wr_cnt == 0),
+                                  jnp.any(m_mask, axis=0))
+        dg_mask = jnp.logical_and(dg_line[None, :], m_mask)
+        if write_back:
+            # a dirty M holder leaving M (killed or downgraded) writes back
+            flush = jnp.logical_and(jnp.logical_or(kill, dg_mask),
+                                    jnp.logical_and(m_mask, dirty))
+            flush_ver = jnp.max(jnp.where(flush, cver, 0), axis=0)
+            mver = jnp.where(jnp.any(flush, axis=0), flush_ver, mver)
+            if width:
+                # dirty-flush-with-bytes: the holder's cache_data IS the
+                # flush source of truth (at most one M holder per line, so
+                # the masked sum selects exactly its row)
+                flush_data = jnp.sum(
+                    jnp.where(flush[:, :, None], cdata, 0), axis=0)
+                mdata = jnp.where(jnp.any(flush, axis=0)[:, None],
+                                  flush_data, mdata)
+            dirty = jnp.logical_and(dirty, ~jnp.logical_or(kill, dg_mask))
+        cstate = jnp.where(kill, jnp.int8(I), cstate)
+        cstate = jnp.where(dg_mask, jnp.int8(S), cstate)
+        # the word IS the directory: rebuild it from the post-boundary
+        # states (also clears failed readers' transient bits without a
+        # second pass)
+        words = co.directory_from_state(cstate)
 
     # ------------- per-slot replies (coalesced groups fan back out) -------
-    served = jnp.where(valid, served_rep[first], False)
-    slot_start = start[first]
-    version = jnp.where(
-        served,
-        jnp.where(is_w, slot_start + w_rank + 1, slot_start + n_w_grp),
-        0).astype(jnp.int32)
-    if width:
-        # every served slot replies with its group's FINAL payload (the
-        # bytes version start+k names) — reads return real data
-        data = jnp.where(served[:, None], gdata[first], 0)
-    else:
-        data = jnp.zeros((r, 0), jnp.int32)
+    with jax.named_scope("round/reply"):
+        served = jnp.where(valid, served_rep[first], False)
+        slot_start = start[first]
+        version = jnp.where(
+            served,
+            jnp.where(is_w, slot_start + w_rank + 1, slot_start + n_w_grp),
+            0).astype(jnp.int32)
+        if width:
+            # every served slot replies with its group's FINAL payload
+            # (the bytes version start+k names) — reads return real data
+            data = jnp.where(served[:, None], gdata[first], 0)
+        else:
+            data = jnp.zeros((r, 0), jnp.int32)
     # unknown leaves (home directory, replica plane) carry through: the
     # flat engine is placement-oblivious by design
     new_state = dict(state)
@@ -283,18 +309,29 @@ def _round_impl(state, node_id, line, is_write, wdata=None, *,
         # which refreshes through a psum instead — see
         # sharded._replica_refresh): a line with no exclusive holder
         # has a current memory image, so snapshotting it is coherent
-        rep = state["replica"]
-        rok = jnp.logical_and(rep, ~jnp.any(cstate == M, axis=0))
-        new_state["replica_ok"] = rok
-        new_state["replica_version"] = jnp.where(
-            rok, mver, state["replica_version"])
-        if "replica_data" in state:
-            new_state["replica_data"] = jnp.where(
-                rok[:, None], mdata, state["replica_data"])
-    return new_state, served, version, data
+        with jax.named_scope("round/replica"):
+            rep = state["replica"]
+            rok = jnp.logical_and(rep, ~jnp.any(cstate == M, axis=0))
+            new_state["replica_ok"] = rok
+            new_state["replica_version"] = jnp.where(
+                rok, mver, state["replica_version"])
+            if "replica_data" in state:
+                new_state["replica_data"] = jnp.where(
+                    rok[:, None], mdata, state["replica_data"])
+    with jax.named_scope("round/telemetry"):
+        events = jnp.stack([
+            jnp.sum(miss.astype(jnp.int32)),
+            jnp.sum(jnp.logical_or(fail_w, fail_r).astype(jnp.int32)),
+            jnp.sum(jnp.logical_and(valid, hit[first]).astype(jnp.int32)),
+        ])
+    return new_state, served, version, data, events
 
 
-@functools.partial(jax.jit, static_argnames=("n_nodes", "backend"))
+# the fused flat driver calls the round through this jitted form, so one
+# trace serves both it and :func:`coherence_round`
+_round_jit = jax.jit(_round_impl, static_argnames=("n_nodes", "backend"))
+
+
 def coherence_round(state, node_id, line, is_write, wdata=None, *,
                     n_nodes: int, backend: str = "ref"):
     """One round of R op slots (node_id, line, is_write) int32 [R];
@@ -306,8 +343,8 @@ def coherence_round(state, node_id, line, is_write, wdata=None, *,
     Duplicate (node, line) slots are legal and coalesce (see module
     docstring); duplicate LINES across nodes contend through the latch
     kernel exactly like concurrent RDMA atomics."""
-    return _round_impl(state, node_id, line, is_write, wdata,
-                       n_nodes=n_nodes, backend=backend)
+    return _round_jit(state, node_id, line, is_write, wdata,
+                      n_nodes=n_nodes, backend=backend)[:4]
 
 
 def _evict_impl(state, node_id, line):

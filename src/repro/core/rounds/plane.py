@@ -45,18 +45,31 @@ between home shards through the coherent directory,
 :meth:`DevicePlane.replicate` marks read-mostly lines for replica
 serving.  ``core/rounds/placement.py`` turns the counters (or the
 EWMA heat a :class:`~repro.obs.recorder.FlightRecorder` distills from
-them) into migration/replication picks.  Attach a recorder
-(``DevicePlane.open(..., recorder=rec)`` or ``attach_recorder``) and
-every verb dispatch appends one span — wall time, rounds, serve
-totals, jit-compile events — to its bounded ring, host-side only.
+them) into migration/replication picks.
+
+Every verb runs inside ONE bracket, :meth:`DevicePlane._span`: a
+``jax.profiler.TraceAnnotation`` named ``plane.<verb>`` on the
+profiler's clock, so a device trace shows each dispatch beside the
+device work it caused.  ``ops``, ``rmw`` and ``descent`` nest fixed
+child annotations in it — ``plane.pad`` (sharded slot and operand
+padding), ``plane.dispatch`` (the call into the jitted loop),
+``plane.sync`` (the blocking ``bool(done)``), ``plane.telemetry``
+(the counters' device-to-host copy) and ``plane.results`` (the result
+copies) — and report each child's host seconds in
+``PlaneResult.host_s``.  With a recorder attached
+(``DevicePlane.open(..., recorder=rec)`` or ``attach_recorder``) the
+bracket also appends one span — wall time, rounds, serve totals,
+jit-compile events — to the recorder's bounded ring.
 """
 
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ...obs import PlaneTelemetry
 
@@ -82,7 +95,10 @@ class PlaneResult:
       ``replica_served`` [S] (per source shard), and per-line
       ``line_hits``/``line_whits`` [L] (ops served per line id; whits =
       write subset).  The per-line counters are bit-identical between a
-      flat plane and any shard count on the same op trace.
+      flat plane and any shard count on the same op trace;
+    * ``host_s``  — host seconds of each child span of the verb's
+      bracket (``pad``, sharded only; ``dispatch``, ``sync``,
+      ``telemetry``, ``results``).
     """
 
     version: np.ndarray | None
@@ -90,6 +106,28 @@ class PlaneResult:
     rounds: int
     stats: dict = field(default_factory=dict)
     telemetry: PlaneTelemetry | None = None
+    host_s: dict = field(default_factory=dict)
+
+
+class _Span:
+    """What one verb's bracket collects: its children's host seconds,
+    and the rounds, telemetry and attributes a recorder span carries."""
+
+    __slots__ = ("host_s", "rounds", "telemetry", "attrs")
+
+    def __init__(self):
+        self.host_s: dict = {}
+        self.rounds = 0
+        self.telemetry = None
+        self.attrs = None
+
+    @contextmanager
+    def phase(self, name: str):
+        """Child span ``plane.<name>``: a profiler annotation, timed."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("plane." + name):
+            yield
+        self.host_s[name] = time.perf_counter() - t0
 
 
 class DevicePlane:
@@ -178,9 +216,12 @@ class DevicePlane:
         directory; the flat engine presents ops BY line id, so its
         counters are line-indexed already (identity — the home perm
         does not reorder them)."""
+        from .engine import EVENT_KEYS
         c = {k: np.asarray(v) for k, v in tele.items()}
         hits = c.pop("slot_hits")
         whits = c.pop("slot_whits")
+        # one [S, 3] leaf carries the protocol events, a row per home
+        c.update(zip(EVENT_KEYS, c.pop("events").sum(axis=0)))
         if self.sharded:
             l, s = self.n_lines, self.n_shards
             perm = (np.asarray(self.state["home"])
@@ -194,25 +235,38 @@ class DevicePlane:
         c["line_whits"] = whits
         return PlaneTelemetry.from_counters(c)
 
-    def _span_begin(self):
-        """Recorder bracket: (wall clock, TRACE_COUNTS sum) or None."""
-        if self.recorder is None:
-            return None
+    @contextmanager
+    def _span(self, verb: str, batch=()):
+        """The verb's one bracket: the ``plane.<verb>`` profiler
+        annotation and, when a recorder is attached, one recorder span
+        (compile events = the TRACE_COUNTS delta over the dispatch).
+        Yields a :class:`_Span` the verb fills; a verb that raises
+        records no span."""
         from .engine import TRACE_COUNTS
-        return (time.perf_counter(), sum(TRACE_COUNTS.values()))
+        rec = self.recorder
+        if rec is not None:
+            t0, c0 = time.perf_counter(), sum(TRACE_COUNTS.values())
+        span = _Span()
+        with TraceAnnotation("plane." + verb):
+            yield span
+        if rec is not None:
+            rec.record(verb, duration=time.perf_counter() - t0,
+                       batch=batch, rounds=span.rounds,
+                       telemetry=span.telemetry,
+                       compiled=sum(TRACE_COUNTS.values()) - c0,
+                       attrs=span.attrs)
 
-    def _span_end(self, verb: str, mark, *, batch=(), rounds: int = 0,
-                  telemetry=None, attrs=None) -> None:
-        """Close a bracket: append one span to the attached recorder
-        (compile events = the TRACE_COUNTS delta over the dispatch)."""
-        if mark is None or self.recorder is None:
-            return
-        from .engine import TRACE_COUNTS
-        t0, c0 = mark
-        self.recorder.record(
-            verb, duration=time.perf_counter() - t0, batch=batch,
-            rounds=rounds, telemetry=telemetry,
-            compiled=sum(TRACE_COUNTS.values()) - c0, attrs=attrs)
+    def _settle(self, sp: _Span, state, done, tele, failure: str) -> None:
+        """The tail every fused verb shares: wait for the loop
+        (``plane.sync``), raise ``failure`` if it hit its bound, adopt
+        the new state and copy the counters (``plane.telemetry``)."""
+        with sp.phase("sync"):
+            done = bool(done)
+        if not done:
+            raise RuntimeError(failure)
+        self.state = state
+        with sp.phase("telemetry"):
+            sp.telemetry = self._telemetry(tele)
 
     # ------------------------------------------------------------- verbs
     def ops(self, node_id, line, is_write, wdata=None, *,
@@ -221,36 +275,40 @@ class DevicePlane:
         completion through the fused spin loop (flat or sharded)."""
         mr = self.max_rounds if max_rounds is None else max_rounds
         r = np.asarray(line).shape[0]
-        mark = self._span_begin()
-        if self.sharded:
-            from .sharded import pad_ops, run_rounds_sharded
-            if wdata is None:
-                node_id, line, is_write = pad_ops(
-                    node_id, line, is_write, self.n_shards)
+        with self._span("ops", batch=(r,)) as sp:
+            if self.sharded:
+                from .sharded import pad_ops, run_rounds_sharded
+                with sp.phase("pad"):
+                    if wdata is None:
+                        node_id, line, is_write = pad_ops(
+                            node_id, line, is_write, self.n_shards)
+                    else:
+                        node_id, line, is_write, wdata = pad_ops(
+                            node_id, line, is_write, self.n_shards,
+                            wdata)
+                with sp.phase("dispatch"):
+                    state, versions, data, rounds, done, tele = \
+                        run_rounds_sharded(
+                            self.state, node_id, line, is_write, wdata,
+                            mesh=self.mesh, axis=self.axis,
+                            n_nodes=self.n_nodes, max_rounds=mr,
+                            bucket_cap=self.bucket_cap,
+                            backend=self.backend)
             else:
-                node_id, line, is_write, wdata = pad_ops(
-                    node_id, line, is_write, self.n_shards, wdata)
-            state, versions, data, rounds, done, tele = \
-                run_rounds_sharded(
-                    self.state, node_id, line, is_write, wdata,
-                    mesh=self.mesh, axis=self.axis,
-                    n_nodes=self.n_nodes, max_rounds=mr,
-                    bucket_cap=self.bucket_cap, backend=self.backend)
-        else:
-            from .driver import run_rounds
-            state, versions, data, rounds, done, tele = run_rounds(
-                self.state, node_id, line, is_write, wdata,
-                n_nodes=self.n_nodes, max_rounds=mr,
-                backend=self.backend)
-        if not bool(done):
-            raise RuntimeError(f"ops not served after {mr} rounds")
-        self.state = state
-        telemetry = self._telemetry(tele)
-        self._span_end("ops", mark, batch=(r,), rounds=int(rounds),
-                       telemetry=telemetry)
-        return PlaneResult(np.asarray(versions)[:r],
-                           np.asarray(data)[:r], int(rounds), {},
-                           telemetry)
+                from .driver import run_rounds
+                with sp.phase("dispatch"):
+                    state, versions, data, rounds, done, tele = \
+                        run_rounds(self.state, node_id, line, is_write,
+                                   wdata, n_nodes=self.n_nodes,
+                                   max_rounds=mr, backend=self.backend)
+            self._settle(sp, state, done, tele,
+                         f"ops not served after {mr} rounds")
+            with sp.phase("results"):
+                sp.rounds = int(rounds)
+                versions = np.asarray(versions)[:r]
+                data = np.asarray(data)[:r]
+        return PlaneResult(versions, data, sp.rounds, {}, sp.telemetry,
+                           sp.host_s)
 
     def rmw(self, node_id, line, *, modify, operands=(),
             max_rounds: int | None = None) -> PlaneResult:
@@ -262,41 +320,44 @@ class DevicePlane:
         alongside the slots)."""
         mr = self.max_rounds if max_rounds is None else max_rounds
         r = np.asarray(line).shape[0]
-        mark = self._span_begin()
-        if self.sharded:
-            from .sharded import pad_ops, run_rmw_sharded
-            node_id, line, _ = pad_ops(node_id, line,
-                                       np.zeros(r, np.int32),
-                                       self.n_shards)
-            pad = np.asarray(line).shape[0] - r
-            if pad:
-                operands = tuple(
-                    np.concatenate(
-                        [np.asarray(op),
-                         np.zeros((pad,) + np.asarray(op).shape[1:],
-                                  np.asarray(op).dtype)])
-                    for op in operands)
-            state, versions, data, rounds, done, tele = run_rmw_sharded(
-                self.state, node_id, line, tuple(operands),
-                modify=modify, mesh=self.mesh, axis=self.axis,
-                n_nodes=self.n_nodes, max_rounds=mr,
-                bucket_cap=self.bucket_cap, backend=self.backend)
-        else:
-            from .driver import run_rmw
-            state, versions, data, rounds, done, tele = run_rmw(
-                self.state, node_id, line, tuple(operands),
-                modify=modify, n_nodes=self.n_nodes, max_rounds=mr,
-                backend=self.backend)
-        if not bool(done):
-            raise RuntimeError(f"RMW ops not served after {mr} "
-                               f"rounds per phase")
-        self.state = state
-        telemetry = self._telemetry(tele)
-        self._span_end("rmw", mark, batch=(r,), rounds=int(rounds),
-                       telemetry=telemetry)
-        return PlaneResult(np.asarray(versions)[:r],
-                           np.asarray(data)[:r], int(rounds), {},
-                           telemetry)
+        with self._span("rmw", batch=(r,)) as sp:
+            if self.sharded:
+                from .sharded import pad_ops, run_rmw_sharded
+                with sp.phase("pad"):
+                    node_id, line, _ = pad_ops(node_id, line,
+                                               np.zeros(r, np.int32),
+                                               self.n_shards)
+                    pad = np.asarray(line).shape[0] - r
+                    if pad:
+                        operands = tuple(
+                            np.concatenate(
+                                [np.asarray(op),
+                                 np.zeros((pad,) + np.asarray(op).shape[1:],
+                                          np.asarray(op).dtype)])
+                            for op in operands)
+                with sp.phase("dispatch"):
+                    state, versions, data, rounds, done, tele = \
+                        run_rmw_sharded(
+                            self.state, node_id, line, tuple(operands),
+                            modify=modify, mesh=self.mesh, axis=self.axis,
+                            n_nodes=self.n_nodes, max_rounds=mr,
+                            bucket_cap=self.bucket_cap,
+                            backend=self.backend)
+            else:
+                from .driver import run_rmw
+                with sp.phase("dispatch"):
+                    state, versions, data, rounds, done, tele = run_rmw(
+                        self.state, node_id, line, tuple(operands),
+                        modify=modify, n_nodes=self.n_nodes,
+                        max_rounds=mr, backend=self.backend)
+            self._settle(sp, state, done, tele,
+                         f"RMW ops not served after {mr} rounds per phase")
+            with sp.phase("results"):
+                sp.rounds = int(rounds)
+                versions = np.asarray(versions)[:r]
+                data = np.asarray(data)[:r]
+        return PlaneResult(versions, data, sp.rounds, {}, sp.telemetry,
+                           sp.host_s)
 
     def descent(self, node_id, key, root, *, transition,
                 path_cap: int = 16,
@@ -307,40 +368,42 @@ class DevicePlane:
         ``levels``, ``hops``, ``paths``, ``path_len``."""
         ms = self.max_rounds if max_steps is None else max_steps
         r = np.asarray(root).shape[0]
-        mark = self._span_begin()
-        if self.sharded:
-            from .sharded import pad_ops, run_descent_sharded
-            node_id, root, key = pad_ops(node_id, root, key,
-                                         self.n_shards)
-            (state, line, lanes, levels, hops, paths, plen, steps,
-             done, tele) = run_descent_sharded(
-                    self.state, node_id, key, root,
-                    transition=transition, mesh=self.mesh,
-                    axis=self.axis, n_nodes=self.n_nodes, max_steps=ms,
-                    bucket_cap=self.bucket_cap, backend=self.backend,
-                    path_cap=path_cap)
-        else:
-            from .descent import run_descent
-            (state, line, lanes, levels, hops, paths, plen, steps,
-             done, tele) = run_descent(
-                    self.state, node_id, key, root,
-                    transition=transition, n_nodes=self.n_nodes,
-                    max_steps=ms, backend=self.backend,
-                    path_cap=path_cap)
-        if not bool(done):
-            raise RuntimeError(f"descent did not settle after {ms} "
-                               f"steps (broken links?)")
-        self.state = state
-        stats = {"line": np.asarray(line)[:r],
-                 "levels": np.asarray(levels)[:r],
-                 "hops": np.asarray(hops)[:r],
-                 "paths": np.asarray(paths)[:r],
-                 "path_len": np.asarray(plen)[:r]}
-        telemetry = self._telemetry(tele)
-        self._span_end("descent", mark, batch=(r,), rounds=int(steps),
-                       telemetry=telemetry)
-        return PlaneResult(None, np.asarray(lanes)[:r], int(steps),
-                           stats=stats, telemetry=telemetry)
+        with self._span("descent", batch=(r,)) as sp:
+            if self.sharded:
+                from .sharded import pad_ops, run_descent_sharded
+                with sp.phase("pad"):
+                    node_id, root, key = pad_ops(node_id, root, key,
+                                                 self.n_shards)
+                with sp.phase("dispatch"):
+                    (state, line, lanes, levels, hops, paths, plen, steps,
+                     done, tele) = run_descent_sharded(
+                            self.state, node_id, key, root,
+                            transition=transition, mesh=self.mesh,
+                            axis=self.axis, n_nodes=self.n_nodes,
+                            max_steps=ms, bucket_cap=self.bucket_cap,
+                            backend=self.backend, path_cap=path_cap)
+            else:
+                from .descent import run_descent
+                with sp.phase("dispatch"):
+                    (state, line, lanes, levels, hops, paths, plen, steps,
+                     done, tele) = run_descent(
+                            self.state, node_id, key, root,
+                            transition=transition, n_nodes=self.n_nodes,
+                            max_steps=ms, backend=self.backend,
+                            path_cap=path_cap)
+            self._settle(sp, state, done, tele,
+                         f"descent did not settle after {ms} steps "
+                         f"(broken links?)")
+            with sp.phase("results"):
+                sp.rounds = int(steps)
+                stats = {"line": np.asarray(line)[:r],
+                         "levels": np.asarray(levels)[:r],
+                         "hops": np.asarray(hops)[:r],
+                         "paths": np.asarray(paths)[:r],
+                         "path_len": np.asarray(plen)[:r]}
+                lanes = np.asarray(lanes)[:r]
+        return PlaneResult(None, lanes, sp.rounds, stats=stats,
+                           telemetry=sp.telemetry, host_s=sp.host_s)
 
     def txn(self, node_id, glines, rmask, wmask, ts, *, algo: str,
             max_iters: int | None = None,
@@ -348,33 +411,30 @@ class DevicePlane:
         """Run one transaction batch through the fused device CC loop
         (:mod:`repro.core.rounds.txn`); returns a ``TxnBatchResult``."""
         from .txn import run_txn_batch
-        mark = self._span_begin()
-        res = run_txn_batch(self, node_id, glines, rmask, wmask, ts,
-                            algo=algo, max_iters=max_iters,
-                            max_rounds=max_rounds)
-        self._span_end("txn", mark,
-                       batch=tuple(np.asarray(glines).shape),
-                       rounds=res.rounds, telemetry=res.telemetry,
-                       attrs={"algo": algo})
+        with self._span("txn",
+                        batch=tuple(np.asarray(glines).shape)) as sp:
+            res = run_txn_batch(self, node_id, glines, rmask, wmask, ts,
+                                algo=algo, max_iters=max_iters,
+                                max_rounds=max_rounds)
+            sp.rounds, sp.telemetry = res.rounds, res.telemetry
+            sp.attrs = {"algo": algo}
         return res
 
     def evict(self, node_id, line) -> None:
         """Evict (node, line) pairs: release holder latches, flushing
         dirty write-back copies first."""
         r = np.asarray(line).shape[0]
-        mark = self._span_begin()
-        if self.sharded:
-            from .sharded import evict_lines_sharded, pad_ops
-            node_id, line, _ = pad_ops(
-                node_id, line, np.zeros(np.asarray(line).shape[0],
-                                        np.int32), self.n_shards)
-            self.state = evict_lines_sharded(
-                self.state, node_id, line, mesh=self.mesh,
-                axis=self.axis, bucket_cap=self.bucket_cap)
-        else:
-            from .engine import evict_lines
-            self.state = evict_lines(self.state, node_id, line)
-        self._span_end("evict", mark, batch=(r,))
+        with self._span("evict", batch=(r,)):
+            if self.sharded:
+                from .sharded import evict_lines_sharded, pad_ops
+                node_id, line, _ = pad_ops(
+                    node_id, line, np.zeros(r, np.int32), self.n_shards)
+                self.state = evict_lines_sharded(
+                    self.state, node_id, line, mesh=self.mesh,
+                    axis=self.axis, bucket_cap=self.bucket_cap)
+            else:
+                from .engine import evict_lines
+                self.state = evict_lines(self.state, node_id, line)
 
     # -------------------------------------------------------- placement
     def rehome(self, lines, new_homes, victims=None) -> int:

@@ -176,17 +176,37 @@ def pad_ops(node_id, line, is_write, n_shards: int, wdata=None):
 
 # ------------------------------------------------------------ one round
 
+TELE_KEYS = ("occupancy", "deferred", "served_per_home", "replica_served",
+             "slot_hits", "slot_whits", "events")
+
+
 def _zero_tele(n_shards: int, l_local: int):
     """Zeroed telemetry accumulator — matches `_route_round`'s
     per-round deltas: (occupancy[S], deferred[S], served_at_home,
-    replica_served, slot_hits[L_local], slot_whits[L_local])."""
+    replica_served, slot_hits[L_local], slot_whits[L_local],
+    events[3]), ``events`` in ``engine.EVENT_KEYS`` order."""
     z = jnp.zeros((n_shards,), jnp.int32)
     zl = jnp.zeros((l_local,), jnp.int32)
-    return (z, z, jnp.int32(0), jnp.int32(0), zl, zl)
+    return (z, z, jnp.int32(0), jnp.int32(0), zl, zl,
+            jnp.zeros((3,), jnp.int32))
 
 
 def _add_tele(a, b):
     return tuple(x + y for x, y in zip(a, b))
+
+
+def _tele_rows(tele):
+    """A shard's accumulated telemetry tuple as ``shard_map`` outputs
+    under :func:`_tele_specs`: a per-home row of every scalar and
+    [S]-vector counter, the slab's per-slot counters as they are."""
+    occ, dfr, srv, rsrv, hits, whits, events = tele
+    return (occ[None, :], dfr[None, :], srv[None], rsrv[None], hits,
+            whits, events[None, :])
+
+
+def _tele_specs(axis: str):
+    return (P(axis, None), P(axis, None), P(axis), P(axis), P(axis),
+            P(axis), P(axis, None))
 
 
 def _replica_refresh(state_l, *, n_shards: int, axis: str):
@@ -239,98 +259,106 @@ def _route_round(state_l, node_l, pending_l, isw_l, wdata_l, *,
     back unserved (its payload re-presents with it next round)."""
     width = wdata_l.shape[1]
     l_local = state_l["words"].shape[0]
-    valid = pending_l >= 0
-    idx = jnp.maximum(pending_l, 0)
-    # replica serve: a pure read of a replicated line with a valid
-    # boundary-snapshot image never leaves its source shard
-    if "replica" in state_l:
-        rserve = jnp.logical_and(
-            jnp.logical_and(valid, isw_l == 0),
-            jnp.logical_and(state_l["replica"][idx],
-                            state_l["replica_ok"][idx]))
-        route = jnp.where(rserve, jnp.int32(-1), pending_l)
-        # serve from the PRE-round image: the local serve logically
-        # precedes this round's writes (a boundary-snapshot read)
-        rserve_ver = state_l["replica_version"][idx]
-        rserve_data = (state_l["replica_data"][idx]
-                       if "replica_data" in state_l else None)
-    else:
-        rserve = jnp.zeros_like(valid)
-        route = pending_l
-    # destination shard per slot: home directory when present, static
-    # stripe otherwise (pads/replica-served slots -> bucket S = dropped)
-    if "home" in state_l:
-        perm = state_l["home"]
-        home = jnp.where(route >= 0, perm[jnp.maximum(route, 0)]
-                         % n_shards, n_shards)
-    else:
-        home = jnp.where(route >= 0, route % n_shards, n_shards)
-    fields = OP_FIELDS + ("wdata",) if width else OP_FIELDS
-    reqs = {"node": node_l, "line": route, "isw": isw_l}
-    if width:
-        reqs["wdata"] = wdata_l
-    buckets, order, keep, (b_idx, s_idx), _ = _bucket(
-        reqs, n_shards, cap, fields=fields, home=home)
-    recv = {k: jax.lax.all_to_all(buckets[k], axis, 0, 0, tiled=False)
-            for k in fields}
-    flat = {k: v.reshape((n_shards * cap,) + v.shape[2:])
-            for k, v in recv.items()}                           # [S*cap]
-    # global line -> local slab index: directory slot // S when the
-    # placement is dynamic, stripe layout's line // S otherwise
-    if "home" in state_l:
-        loc = jnp.where(flat["line"] >= 0,
-                        perm[jnp.maximum(flat["line"], 0)] // n_shards,
-                        -1).astype(jnp.int32)
-    else:
-        loc = jnp.where(flat["line"] >= 0, flat["line"] // n_shards,
-                        -1).astype(jnp.int32)
-    state_l, served_h, ver_h, data_h = _round_impl(
+    with jax.named_scope("route/bucket"):
+        valid = pending_l >= 0
+        idx = jnp.maximum(pending_l, 0)
+        # replica serve: a pure read of a replicated line with a valid
+        # boundary-snapshot image never leaves its source shard
+        if "replica" in state_l:
+            rserve = jnp.logical_and(
+                jnp.logical_and(valid, isw_l == 0),
+                jnp.logical_and(state_l["replica"][idx],
+                                state_l["replica_ok"][idx]))
+            route = jnp.where(rserve, jnp.int32(-1), pending_l)
+            # serve from the PRE-round image: the local serve logically
+            # precedes this round's writes (a boundary-snapshot read)
+            rserve_ver = state_l["replica_version"][idx]
+            rserve_data = (state_l["replica_data"][idx]
+                           if "replica_data" in state_l else None)
+        else:
+            rserve = jnp.zeros_like(valid)
+            route = pending_l
+        # destination shard per slot: home directory when present, static
+        # stripe otherwise (pads/replica-served slots -> bucket S = dropped)
+        if "home" in state_l:
+            perm = state_l["home"]
+            home = jnp.where(route >= 0, perm[jnp.maximum(route, 0)]
+                             % n_shards, n_shards)
+        else:
+            home = jnp.where(route >= 0, route % n_shards, n_shards)
+        fields = OP_FIELDS + ("wdata",) if width else OP_FIELDS
+        reqs = {"node": node_l, "line": route, "isw": isw_l}
+        if width:
+            reqs["wdata"] = wdata_l
+        buckets, order, keep, (b_idx, s_idx), _ = _bucket(
+            reqs, n_shards, cap, fields=fields, home=home)
+    with jax.named_scope("route/exchange"):
+        recv = {k: jax.lax.all_to_all(buckets[k], axis, 0, 0, tiled=False)
+                for k in fields}
+        flat = {k: v.reshape((n_shards * cap,) + v.shape[2:])
+                for k, v in recv.items()}                       # [S*cap]
+    with jax.named_scope("route/bucket"):
+        # global line -> local slab index: directory slot // S when the
+        # placement is dynamic, stripe layout's line // S otherwise
+        if "home" in state_l:
+            loc = jnp.where(flat["line"] >= 0,
+                            perm[jnp.maximum(flat["line"], 0)] // n_shards,
+                            -1).astype(jnp.int32)
+        else:
+            loc = jnp.where(flat["line"] >= 0, flat["line"] // n_shards,
+                            -1).astype(jnp.int32)
+    state_l, served_h, ver_h, data_h, events = _round_impl(
         state_l, flat["node"], loc, flat["isw"], flat.get("wdata"),
         n_nodes=n_nodes, backend=backend)
     if "replica" in state_l:
-        state_l = _replica_refresh(state_l, n_shards=n_shards, axis=axis)
+        with jax.named_scope("route/replica"):
+            state_l = _replica_refresh(state_l, n_shards=n_shards,
+                                       axis=axis)
 
-    def back(x):
-        return jax.lax.all_to_all(
-            x.reshape((n_shards, cap) + x.shape[1:]), axis, 0, 0,
-            tiled=False)
-    r_served = back(served_h.astype(jnp.int32))
-    r_ver = back(ver_h)
-    inv = jnp.argsort(order)
+    with jax.named_scope("route/exchange"):
+        def back(x):
+            return jax.lax.all_to_all(
+                x.reshape((n_shards, cap) + x.shape[1:]), axis, 0, 0,
+                tiled=False)
+        r_served = back(served_h.astype(jnp.int32))
+        r_ver = back(ver_h)
+        inv = jnp.argsort(order)
 
-    def unbucket(bucketed):
-        gathered = bucketed[b_idx, s_idx]
-        mask = keep.reshape((-1,) + (1,) * (gathered.ndim - 1))
-        gathered = jnp.where(mask, gathered, 0)
-        return gathered[inv]
-    served = jnp.logical_or(unbucket(r_served).astype(bool), rserve)
-    version = unbucket(r_ver)
-    if width:
-        r_data = unbucket(back(data_h))
-    else:
-        r_data = jnp.zeros((pending_l.shape[0], 0), jnp.int32)
-    if "replica" in state_l:
-        version = jnp.where(rserve, rserve_ver, version)
-        if width and rserve_data is not None:
-            r_data = jnp.where(rserve[:, None], rserve_data, r_data)
+        def unbucket(bucketed):
+            gathered = bucketed[b_idx, s_idx]
+            mask = keep.reshape((-1,) + (1,) * (gathered.ndim - 1))
+            gathered = jnp.where(mask, gathered, 0)
+            return gathered[inv]
+        served = jnp.logical_or(unbucket(r_served).astype(bool), rserve)
+        version = unbucket(r_ver)
+        if width:
+            r_data = unbucket(back(data_h))
+        else:
+            r_data = jnp.zeros((pending_l.shape[0], 0), jnp.int32)
+        if "replica" in state_l:
+            version = jnp.where(rserve, rserve_ver, version)
+            if width and rserve_data is not None:
+                r_data = jnp.where(rserve[:, None], rserve_data, r_data)
     # congestion telemetry (this round's delta, all source-local or
     # home-local): bucket occupancy / defers per destination home, ops
-    # served at THIS home, replica-served reads, per-local-slot hits
-    sent = keep[inv]
-    occ = jnp.zeros((n_shards,), jnp.int32).at[
-        jnp.where(sent, home, n_shards)].add(1, mode="drop")
-    dfr = jnp.zeros((n_shards,), jnp.int32).at[
-        jnp.where(jnp.logical_and(route >= 0, ~sent), home,
-                  n_shards)].add(1, mode="drop")
-    served_at_home = jnp.sum(served_h.astype(jnp.int32))
-    hit_slot = jnp.where(served_h, loc, l_local)
-    hits = jnp.zeros((l_local,), jnp.int32).at[hit_slot].add(
-        1, mode="drop")
-    whits = jnp.zeros((l_local,), jnp.int32).at[
-        jnp.where(flat["isw"].astype(bool), hit_slot, l_local)].add(
-        1, mode="drop")
-    tele = (occ, dfr, served_at_home,
-            jnp.sum(rserve.astype(jnp.int32)), hits, whits)
+    # served at THIS home, replica-served reads, per-local-slot hits,
+    # and the home's protocol events
+    with jax.named_scope("round/telemetry"):
+        sent = keep[inv]
+        occ = jnp.zeros((n_shards,), jnp.int32).at[
+            jnp.where(sent, home, n_shards)].add(1, mode="drop")
+        dfr = jnp.zeros((n_shards,), jnp.int32).at[
+            jnp.where(jnp.logical_and(route >= 0, ~sent), home,
+                      n_shards)].add(1, mode="drop")
+        served_at_home = jnp.sum(served_h.astype(jnp.int32))
+        hit_slot = jnp.where(served_h, loc, l_local)
+        hits = jnp.zeros((l_local,), jnp.int32).at[hit_slot].add(
+            1, mode="drop")
+        whits = jnp.zeros((l_local,), jnp.int32).at[
+            jnp.where(flat["isw"].astype(bool), hit_slot, l_local)].add(
+            1, mode="drop")
+        tele = (occ, dfr, served_at_home,
+                jnp.sum(rserve.astype(jnp.int32)), hits, whits, events)
     return state_l, served, version, r_data, tele
 
 
@@ -407,9 +435,11 @@ def run_rounds_sharded(state, node_id, line, is_write, wdata=None, *,
     loop carry: ``occupancy``/``deferred`` [S, S] (row = source shard,
     col = destination home: bucket entries sent / deferred-by-
     overflow), ``served_per_home`` [S], ``replica_served`` [S] (per
-    SOURCE shard), and per-physical-slot ``slot_hits``/``slot_whits``
+    SOURCE shard), per-physical-slot ``slot_hits``/``slot_whits``
     [L] in slab-concatenation order (``DevicePlane`` remaps them to
-    line ids through the directory).  Unserved slots (latch contention
+    line ids through the directory), and ``events`` [S, 3], the round
+    body's protocol events at each home (``engine.EVENT_KEYS``).
+    Unserved slots (latch contention
     OR bucket overflow) re-present themselves — bytes included — round
     after round inside the fused ``lax.while_loop``; the done flag is a
     psum across shards, so the loop runs lockstep until every shard's
@@ -463,23 +493,15 @@ def run_rounds_sharded(state, node_id, line, is_write, wdata=None, *,
                 n_pending(line_l) == 0)
         state_l, pending, versions, data, rounds, tele, done = \
             jax.lax.while_loop(cond, body, init)
-        occ, dfr, srv, rsrv, hits, whits = tele
-        return (state_l, versions, data, rounds, done, occ[None, :],
-                dfr[None, :], srv[None], rsrv[None], hits, whits)
+        return (state_l, versions, data, rounds, done) + _tele_rows(tele)
 
-    tele_specs = (P(axis, None), P(axis, None), P(axis), P(axis),
-                  P(axis), P(axis))
-    (state, versions, data, rounds, done, occ, dfr, srv, rsrv, hits,
-     whits) = jax.shard_map(
+    out = jax.shard_map(
         spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis), P(axis)),
-        out_specs=(specs, P(axis), P(axis), P(), P()) + tele_specs,
+        out_specs=(specs, P(axis), P(axis), P(), P()) + _tele_specs(axis),
         check_vma=False,
     )(state, node_id, line, is_write, wdata)
-    tele = {"occupancy": occ, "deferred": dfr, "served_per_home": srv,
-            "replica_served": rsrv, "slot_hits": hits,
-            "slot_whits": whits}
-    return state, versions, data, rounds, done, tele
+    return out[:5] + (dict(zip(TELE_KEYS, out[5:])),)
 
 
 @functools.partial(
@@ -612,26 +634,17 @@ def run_descent_sharded(state, node_id, key, root, *, transition, mesh,
                 _zero_tele(n_shards, l_local), n_undone(done0) == 0)
         (state_l, cur, _, lanes, levels, hops, paths, plen, steps,
          tele, gdone) = jax.lax.while_loop(cond, body, init)
-        occ, dfr, srv, rsrv, hits, whits = tele
         return (state_l, cur, lanes, levels, hops, paths, plen, steps,
-                gdone, occ[None, :], dfr[None, :], srv[None],
-                rsrv[None], hits, whits)
+                gdone) + _tele_rows(tele)
 
-    tele_specs = (P(axis, None), P(axis, None), P(axis), P(axis),
-                  P(axis), P(axis))
-    (state, cur, lanes, levels, hops, paths, plen, steps, gdone, occ,
-     dfr, srv, rsrv, hits, whits) = jax.shard_map(
+    out = jax.shard_map(
         spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis)),
         out_specs=(specs, P(axis), P(axis), P(axis), P(axis), P(axis),
-                   P(axis), P(), P()) + tele_specs,
+                   P(axis), P(), P()) + _tele_specs(axis),
         check_vma=False,
     )(state, node_id, key, root)
-    tele = {"occupancy": occ, "deferred": dfr, "served_per_home": srv,
-            "replica_served": rsrv, "slot_hits": hits,
-            "slot_whits": whits}
-    return (state, cur, lanes, levels, hops, paths, plen, steps, gdone,
-            tele)
+    return out[:9] + (dict(zip(TELE_KEYS, out[9:])),)
 
 
 # --------------------------------------------------------------- eviction

@@ -77,8 +77,8 @@ from jax.sharding import PartitionSpec as P
 from .. import coherence as co
 from .driver import add_tele, run_rounds, zero_flat_tele
 from .engine import _note_trace
-from .sharded import (_add_tele, _route_round, _state_specs, _zero_tele,
-                      auto_mesh)
+from .sharded import (TELE_KEYS, _add_tele, _route_round, _state_specs,
+                      _tele_rows, _tele_specs, _zero_tele, auto_mesh)
 from .state import payload_width
 
 LOCK_LANE = 0
@@ -396,25 +396,17 @@ def run_txn_rounds_sharded(state, node_id, glines, rmask, wmask, ts, *,
                 _zero_tele(n_shards, l_local), n_live(nv < 0) == 0)
         (state_l, _, done, dec, estep, retr, _, it, ok, rounds,
          tele, alldone) = jax.lax.while_loop(cond, body, init)
-        occ, dfr, srv, rsrv, hits, whits = tele
-        return (state_l, dec, estep, retr, it, alldone, ok, rounds,
-                occ[None, :], dfr[None, :], srv[None], rsrv[None],
-                hits, whits)
+        return (state_l, dec, estep, retr, it, alldone, ok,
+                rounds) + _tele_rows(tele)
 
-    tele_specs = (P(axis, None), P(axis, None), P(axis), P(axis),
-                  P(axis), P(axis))
-    (state, dec, estep, retr, it, alldone, ok, rounds, occ, dfr, srv,
-     rsrv, hits, whits) = jax.shard_map(
+    out = jax.shard_map(
         spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=(specs, P(axis), P(axis), P(axis), P(), P(), P(),
-                   P()) + tele_specs,
+                   P()) + _tele_specs(axis),
         check_vma=False,
     )(state, node_id, glines, rmask, wmask, ts)
-    tele = {"occupancy": occ, "deferred": dfr, "served_per_home": srv,
-            "replica_served": rsrv, "slot_hits": hits,
-            "slot_whits": whits}
-    return (state, dec, estep, retr, it, alldone, ok, rounds, tele)
+    return out[:8] + (dict(zip(TELE_KEYS, out[8:])),)
 
 
 # ------------------------------------------------------ host-facing API

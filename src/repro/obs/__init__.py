@@ -12,15 +12,19 @@ unifies it:
   between planes on the same op trace;
 * :class:`FlightRecorder` — a bounded span ring attached to
   ``DevicePlane`` / ``ServeLoop``: one :class:`Span` per verb dispatch,
-  plus EWMA line/home heat for online placement;
+  plus EWMA line/home heat for online placement, and
+  ``recorder.snapshot()`` (bench ``meta.telemetry``);
 * :class:`MetricsRegistry` — counters / gauges /
   :class:`StreamingHistogram` (log-bucketed p50/p99 without samples)
-  with Prometheus text exposition (``render_prom()``);
-* exporters — ``recorder.export_chrome_trace(path)`` (chrome://tracing
-  / Perfetto) and ``recorder.snapshot()`` (bench ``meta.telemetry``).
+  with Prometheus text exposition (``render_prom()``), for scraping a
+  serving loop.
 
-The recorder is HOST-side only: it brackets dispatches, it never enters
-a trace, so ``engine.TRACE_COUNTS`` proves it adds zero compiled code.
+Every verb's one bracket (``DevicePlane._span``) opens a
+``plane.<verb>`` ``jax.profiler.TraceAnnotation`` with fixed child
+spans (``plane.pad``/``dispatch``/``sync``/``telemetry``/``results``),
+so a profiler trace puts each dispatch on the device's clock; the same
+bracket feeds an attached recorder.  Neither enters a trace, so
+``engine.TRACE_COUNTS`` proves they add zero compiled code.
 """
 
 from .metrics import (Counter, EwmaHeat, Gauge, MetricsRegistry,

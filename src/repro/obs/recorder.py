@@ -1,7 +1,8 @@
-"""`FlightRecorder` — the plane's bounded span ring + exporters.
+"""`FlightRecorder` — the plane's bounded span ring, metrics and heat.
 
 One :class:`Span` per verb dispatch (ops/rmw/descent/txn/evict —
-appended by ``DevicePlane`` when a recorder is attached): verb, batch
+appended by ``DevicePlane._span``, the verb's one bracket, when a
+recorder is attached): verb, batch
 shape, coherence rounds, served/deferred totals from the dispatch's
 :class:`~repro.obs.telemetry.PlaneTelemetry`, wall time, a monotonic
 dispatch index, and the number of jit compile events the dispatch
@@ -21,15 +22,15 @@ Alongside the ring the recorder owns:
   ``placement.plan_rehome`` / ``plan_replication`` consume for ONLINE
   placement from inside a serving loop (no raw stats plumbing).
 
-Exporters: :meth:`export_chrome_trace` writes Chrome-trace/Perfetto
-JSON (open a serving run in ``chrome://tracing`` / ui.perfetto.dev);
 :meth:`snapshot` folds the whole recorder into a plain dict for
-``BENCH_*.json`` ``meta.telemetry``.
+``BENCH_*.json`` ``meta.telemetry``.  For a timeline, take a
+``jax.profiler`` trace instead: the same bracket writes each dispatch
+there as a ``plane.<verb>`` annotation, on the clock the device's
+operations share (Perfetto and TensorBoard read it).
 """
 
 from __future__ import annotations
 
-import json
 import time
 from typing import NamedTuple
 
@@ -57,19 +58,6 @@ class Span(NamedTuple):
     replica_served: int        # replica-path serves
     compiled: int              # TRACE_COUNTS delta (new jit traces)
     attrs: dict = {}           # callers pass a fresh dict (record does)
-
-    def to_chrome_event(self) -> dict:
-        """Chrome-trace 'complete' event (ph=X, microsecond units)."""
-        args = {"rounds": self.rounds, "served": self.served,
-                "deferred": self.deferred,
-                "replica_served": self.replica_served,
-                "batch": list(self.batch), "dispatch": self.index}
-        if self.compiled:
-            args["compiled"] = self.compiled
-        args.update(self.attrs)
-        return {"name": self.verb, "cat": "plane", "ph": "X",
-                "ts": self.ts * 1e6, "dur": max(self.dur, 1e-9) * 1e6,
-                "pid": 0, "tid": 0, "args": args}
 
 
 class FlightRecorder:
@@ -221,21 +209,7 @@ class FlightRecorder:
         head = self._total % self.capacity
         return [s for s in self._ring[head:] + self._ring[:head]]
 
-    # ------------------------------------------------------- exporters
-    def export_chrome_trace(self, path: str | None = None) -> dict:
-        """Chrome-trace JSON document; written to ``path`` if given."""
-        doc = {
-            "traceEvents": [s.to_chrome_event() for s in self.spans()],
-            "displayTimeUnit": "ms",
-            "otherData": {"spans_total": self._total,
-                          "spans_dropped": self.dropped},
-        }
-        if path is not None:
-            with open(path, "w") as f:
-                json.dump(doc, f, indent=1)
-                f.write("\n")
-        return doc
-
+    # --------------------------------------------------------- summary
     def snapshot(self) -> dict:
         """Plain-dict summary for ``BENCH_*.json`` ``meta.telemetry``."""
         verbs: dict = {}

@@ -21,7 +21,17 @@ Field geometry (S = home shards, 1 on a flat plane; L = lines):
   replica image (flat: 0 — the flat engine has no replica serve path);
 * ``line_hits``       [L]  — served ops per LINE id (home-slot counters
   remapped through the directory; the placement probe signal);
-* ``line_whits``      [L]  — the write subset of ``line_hits``.
+* ``line_whits``      [L]  — the write subset of ``line_hits``;
+* ``latch_requests``  []   — group representatives that issued a latch
+  CAS/FAA, summed over the rounds (the round body's ``miss``);
+* ``latch_failed``    []   — those requests that failed and retry next
+  round (a CAS lost to a peer, a read blocked by a writer);
+* ``local_hits``      []   — served slots whose group hit the node's own
+  cached copy with no latch request: SELCC's lazy latch.
+
+The three protocol counters are counted at the home that runs the round
+body, so a sharded plane whose buckets never overflow counts what a flat
+plane counts on the same op trace.
 
 The record is also a read-only mapping (``tele["line_hits"]``,
 ``dict(tele)``) so counter-dict call sites port mechanically, and
@@ -50,6 +60,9 @@ class PlaneTelemetry:
     replica_served: np.ndarray   # [S] replica-served reads per source
     line_hits: np.ndarray        # [L] served ops per line
     line_whits: np.ndarray       # [L] served writes per line
+    latch_requests: np.ndarray   # [] latch CAS/FAA issued
+    latch_failed: np.ndarray     # [] latch requests that failed
+    local_hits: np.ndarray       # [] slots served by a lazy-latch hit
 
     # ------------------------------------------------------ constructors
     @classmethod
@@ -60,7 +73,10 @@ class PlaneTelemetry:
                    served_per_home=np.zeros((s,), np.int64),
                    replica_served=np.zeros((s,), np.int64),
                    line_hits=np.zeros((l,), np.int64),
-                   line_whits=np.zeros((l,), np.int64))
+                   line_whits=np.zeros((l,), np.int64),
+                   latch_requests=np.zeros((), np.int64),
+                   latch_failed=np.zeros((), np.int64),
+                   local_hits=np.zeros((), np.int64))
 
     @classmethod
     def from_counters(cls, counters) -> "PlaneTelemetry":

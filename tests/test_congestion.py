@@ -62,7 +62,8 @@ def test_hot_home_overflow_reports_telemetry():
     res = plane.ops(_i32(0, 1, 0, 1), _i32(0, 0, 1, 1),
                     _i32(1, 1, 1, 1))
     s = res.telemetry
-    assert sorted(s) == ["deferred", "line_hits", "line_whits",
+    assert sorted(s) == ["deferred", "latch_failed", "latch_requests",
+                         "line_hits", "line_whits", "local_hits",
                          "occupancy", "replica_served",
                          "served_per_home"]
     assert s["occupancy"].shape == s["deferred"].shape == (1, 1)
