@@ -41,7 +41,7 @@ get per-dispatch spans, Prometheus metrics, Chrome-trace export and
 the EWMA line/home heat the placement policies consume online.
 """
 
-from ...obs import FlightRecorder, PlaneTelemetry
+from ...obs import TELEMETRY_READS, FlightRecorder, PlaneTelemetry
 from ..coherence import I, M, S
 from .descent import run_descent
 from .driver import run_rmw, run_rounds
@@ -59,7 +59,7 @@ from .txn import (TxnBatchResult, run_txn_batch,
 
 __all__ = [
     "I", "S", "M", "DevicePlane", "FlightRecorder", "PlaneResult",
-    "PlaneTelemetry", "TRACE_COUNTS",
+    "PlaneTelemetry", "TELEMETRY_READS", "TRACE_COUNTS",
     "TxnBatchResult", "check_invariants", "coherence_round",
     "coherence_round_sharded", "evict_lines", "evict_lines_sharded",
     "is_write_back", "make_sharded_state", "make_state", "pad_ops",

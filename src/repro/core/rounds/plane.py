@@ -26,9 +26,9 @@ ONE result type:
     out = plane.txn(node, glines, rmask, wmask, ts, algo="2pl")
 
 Every verb mutates ``plane.state`` in place (the plane IS the memory),
-materializes host arrays exactly once at the end (zero syncs inside the
-fused loops), raises ``RuntimeError`` if the round/step bound was hit,
-and returns a :class:`PlaneResult` — ``version``, ``data``, ``rounds``,
+materializes its result arrays exactly once at the end (zero syncs
+inside the fused loops), raises ``RuntimeError`` if the round/step
+bound was hit, and returns a :class:`PlaneResult` — ``version``, ``data``, ``rounds``,
 ``stats`` — instead of a positional tuple whose arity the caller must
 memorize.  Sharded planes route through the very same calls: the mesh
 dispatch, ``pad_ops`` slot padding and result re-slicing all live HERE,
@@ -39,7 +39,11 @@ loops accumulate in their carries as a typed
 :class:`~repro.obs.telemetry.PlaneTelemetry`
 (``PlaneResult.telemetry``: occupancy/deferred/served counters plus
 per-line hit counts, diff-able bit-for-bit between a flat plane and any
-shard count on the same op trace), and two placement verbs act on it at
+shard count on the same op trace).  The verb copies none of it to the
+host: the record holds the counter leaves on the device and copies a
+group (the small counters, or the per-line pair) the first time a
+consumer reads one of its fields, so a caller that never reads the
+telemetry pays no copy.  Two placement verbs act on it at
 op-quiescent boundaries: :meth:`DevicePlane.rehome` migrates lines
 between home shards through the coherent directory,
 :meth:`DevicePlane.replicate` marks read-mostly lines for replica
@@ -54,7 +58,7 @@ device work it caused.  ``ops``, ``rmw`` and ``descent`` nest fixed
 child annotations in it — ``plane.pad`` (sharded slot and operand
 padding), ``plane.dispatch`` (the call into the jitted loop),
 ``plane.sync`` (the blocking ``bool(done)``), ``plane.telemetry``
-(the counters' device-to-host copy) and ``plane.results`` (the result
+(wrapping the counters, uncopied) and ``plane.results`` (the result
 copies) — and report each child's host seconds in
 ``PlaneResult.host_s``.  With a recorder attached
 (``DevicePlane.open(..., recorder=rec)`` or ``attach_recorder``) the
@@ -95,7 +99,13 @@ class PlaneResult:
       ``replica_served`` [S] (per source shard), and per-line
       ``line_hits``/``line_whits`` [L] (ops served per line id; whits =
       write subset).  The per-line counters are bit-identical between a
-      flat plane and any shard count on the same op trace;
+      flat plane and any shard count on the same op trace.  Lazy: the
+      first read of a small counter copies all of them to the host in
+      one ``jax.device_get``, the first read of ``line_hits`` or
+      ``line_whits`` copies that pair (and remaps a sharded plane's
+      slots through the home directory the verb left).  Until then a
+      held result keeps the unread leaves on the device (two [L]
+      int32 counters and a few [S, S]); drop it to free them;
     * ``host_s``  — host seconds of each child span of the verb's
       bracket (``pad``, sharded only; ``dispatch``, ``sync``,
       ``telemetry``, ``results``).
@@ -209,31 +219,47 @@ class DevicePlane:
 
     # --------------------------------------------------------- telemetry
     def _telemetry(self, tele) -> PlaneTelemetry:
-        """Materialize a fused loop's telemetry counter dict into a
-        typed :class:`PlaneTelemetry`, remapping the physical-slot hit
-        counters to LINE ids.  Sharded counters come back in the
-        shard-major slab concatenation and route through the home
-        directory; the flat engine presents ops BY line id, so its
-        counters are line-indexed already (identity — the home perm
-        does not reorder them)."""
+        """Wrap a fused loop's telemetry counter dict, still on the
+        device, in a lazy :class:`PlaneTelemetry`; nothing is copied
+        here.  The first read of a small counter fetches every small
+        leaf in one ``jax.device_get`` (the [S, 3] ``events`` leaf
+        summed into the three protocol counters); the first read of a
+        per-line counter fetches both [L] leaves in one, and remaps the
+        physical-slot counters to LINE ids.  Sharded counters come back
+        in the shard-major slab concatenation and route through the
+        home directory as this verb left it: ``home`` is captured here,
+        an immutable array, so a later :meth:`rehome` cannot change an
+        earlier result.  The flat engine presents ops BY line id, so its
+        counters are line-indexed already (identity — the home perm does
+        not reorder them)."""
+        import jax
         from .engine import EVENT_KEYS
-        c = {k: np.asarray(v) for k, v in tele.items()}
-        hits = c.pop("slot_hits")
-        whits = c.pop("slot_whits")
-        # one [S, 3] leaf carries the protocol events, a row per home
-        c.update(zip(EVENT_KEYS, c.pop("events").sum(axis=0)))
-        if self.sharded:
-            l, s = self.n_lines, self.n_shards
-            perm = (np.asarray(self.state["home"])
-                    if "home" in self.state
-                    else np.arange(l, dtype=np.int64))
-            # slot p lives at row (p % S) * (L // S) + p // S of the
-            # shard-major concatenation the counters come back in
-            pos = (perm % s) * (l // s) + perm // s
-            hits, whits = hits[pos], whits[pos]
-        c["line_hits"] = hits
-        c["line_whits"] = whits
-        return PlaneTelemetry.from_counters(c)
+        small = {k: v for k, v in tele.items()
+                 if k not in ("slot_hits", "slot_whits")}
+        slots = (tele["slot_hits"], tele["slot_whits"])
+        sharded, l, s = self.sharded, self.n_lines, self.n_shards
+        # the record holds this state leaf: a verb that donates its
+        # state must copy or keep ``home`` undonated
+        home = self.state.get("home") if sharded else None
+
+        def counters():
+            c = jax.device_get(small)
+            # one [S, 3] leaf carries the protocol events, a row per home
+            c.update(zip(EVENT_KEYS, c.pop("events").sum(axis=0)))
+            return c
+
+        def lines():
+            hits, whits, perm = jax.device_get(slots + (home,))
+            if sharded:
+                if perm is None:
+                    perm = np.arange(l, dtype=np.int64)
+                # slot p lives at row (p % S) * (L // S) + p // S of the
+                # shard-major concatenation the counters come back in
+                pos = (perm % s) * (l // s) + perm // s
+                hits, whits = hits[pos], whits[pos]
+            return {"line_hits": hits, "line_whits": whits}
+
+        return PlaneTelemetry.lazy(counters, lines)
 
     @contextmanager
     def _span(self, verb: str, batch=()):
@@ -259,7 +285,8 @@ class DevicePlane:
     def _settle(self, sp: _Span, state, done, tele, failure: str) -> None:
         """The tail every fused verb shares: wait for the loop
         (``plane.sync``), raise ``failure`` if it hit its bound, adopt
-        the new state and copy the counters (``plane.telemetry``)."""
+        the new state and wrap the counters, uncopied
+        (``plane.telemetry``)."""
         with sp.phase("sync"):
             done = bool(done)
         if not done:

@@ -30,9 +30,10 @@ bracket feeds an attached recorder.  Neither enters a trace, so
 from .metrics import (Counter, EwmaHeat, Gauge, MetricsRegistry,
                       StreamingHistogram)
 from .recorder import FlightRecorder, Span
-from .telemetry import PlaneTelemetry
+from .telemetry import TELEMETRY_READS, PlaneTelemetry
 
 __all__ = [
     "Counter", "EwmaHeat", "FlightRecorder", "Gauge",
     "MetricsRegistry", "PlaneTelemetry", "Span", "StreamingHistogram",
+    "TELEMETRY_READS",
 ]

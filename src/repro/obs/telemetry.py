@@ -37,34 +37,90 @@ The record is also a read-only mapping (``tele["line_hits"]``,
 ``dict(tele)``) so counter-dict call sites port mechanically, and
 ``__add__`` accumulates across verbs/batches (``sum(teles,
 PlaneTelemetry.zeros(...))`` or plain ``a + b``).
+
+When the copy happens.  A verb's record (:meth:`PlaneTelemetry.lazy`)
+holds the fused loop's counter leaves as device arrays; the verb copies
+nothing to the host.  The first read of a field copies its GROUP, once
+(``DevicePlane`` fetches a group in one ``jax.device_get``):
+
+* the small counters (``occupancy``, ``deferred``, ``served_per_home``,
+  ``replica_served`` and the three protocol counters), read by
+  ``served``, ``deferred_total`` and ``n_shards`` too;
+* the per-line pair ``line_hits``/``line_whits`` ([L] int32 each on
+  the device, 4 MiB each at 2^20 lines), read by ``n_lines`` and
+  ``write_fraction`` too.
+
+Equality, ``+``, ``items()``/``as_dict()`` and ``repr`` read both.  A
+held record keeps each unread group's leaves on the device until it is
+dropped.  Values and types do not depend on when the copy happens
+(int64 numpy on access).  :data:`TELEMETRY_READS` counts the copies per
+group, so a test can show that a loop that reads nothing copies
+nothing.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, fields
-
 import numpy as np
 
-__all__ = ["PlaneTelemetry"]
+__all__ = ["PlaneTelemetry", "TELEMETRY_READS"]
 
+_COUNTER_KEYS = ("occupancy", "deferred", "served_per_home",
+                 "replica_served", "latch_requests", "latch_failed",
+                 "local_hits")
 _LINE_KEYS = ("line_hits", "line_whits")
+_KEYS = _COUNTER_KEYS[:4] + _LINE_KEYS + _COUNTER_KEYS[4:]
+_GROUPS = {"counters": _COUNTER_KEYS, "lines": _LINE_KEYS}
+
+# Host copies of a lazy record's groups, by group (process-wide, like
+# ``engine.TRACE_COUNTS``: tests compare before and after).
+TELEMETRY_READS: dict = {"counters": 0, "lines": 0}
 
 
-@dataclass(frozen=True)
+def _host(values, keys) -> dict:
+    return {k: np.asarray(values[k], np.int64) for k in keys}
+
+
+def _field(group: str, key: str) -> property:
+    return property(lambda self: self._group(group)[key])
+
+
 class PlaneTelemetry:
     """Congestion/serve counters of one fused dispatch (or a sum)."""
 
-    occupancy: np.ndarray        # [S, S] bucket entries sent
-    deferred: np.ndarray         # [S, S] bucket-overflow defers
-    served_per_home: np.ndarray  # [S] ops served at each home
-    replica_served: np.ndarray   # [S] replica-served reads per source
-    line_hits: np.ndarray        # [L] served ops per line
-    line_whits: np.ndarray       # [L] served writes per line
-    latch_requests: np.ndarray   # [] latch CAS/FAA issued
-    latch_failed: np.ndarray     # [] latch requests that failed
-    local_hits: np.ndarray       # [] slots served by a lazy-latch hit
+    __slots__ = ("_groups", "_fetch")
+
+    occupancy = _field("counters", "occupancy")        # [S, S] sent
+    deferred = _field("counters", "deferred")          # [S, S] defers
+    served_per_home = _field("counters", "served_per_home")  # [S]
+    replica_served = _field("counters", "replica_served")    # [S]
+    line_hits = _field("lines", "line_hits")           # [L] served ops
+    line_whits = _field("lines", "line_whits")         # [L] writes
+    latch_requests = _field("counters", "latch_requests")    # []
+    latch_failed = _field("counters", "latch_failed")        # []
+    local_hits = _field("counters", "local_hits")            # []
+
+    def __init__(self, **counters):
+        """A record of host values, one per field name (any integer
+        array-likes; stored as int64)."""
+        if set(counters) != set(_KEYS):
+            raise TypeError(f"PlaneTelemetry needs exactly {_KEYS}, "
+                            f"got {tuple(counters)}")
+        self._groups = {g: _host(counters, keys)
+                        for g, keys in _GROUPS.items()}
+        self._fetch = {}
 
     # ------------------------------------------------------ constructors
+    @classmethod
+    def lazy(cls, counters, lines) -> "PlaneTelemetry":
+        """A record copied on first read, group by group: ``counters()``
+        returns the small fields, ``lines()`` the per-line pair, each a
+        dict of host array-likes.  Each is called at most once, then
+        dropped with whatever device arrays it holds."""
+        tele = cls.__new__(cls)
+        tele._groups = {}
+        tele._fetch = {"counters": counters, "lines": lines}
+        return tele
+
     @classmethod
     def zeros(cls, n_shards: int, n_lines: int) -> "PlaneTelemetry":
         s, l = int(n_shards), int(n_lines)
@@ -80,10 +136,17 @@ class PlaneTelemetry:
 
     @classmethod
     def from_counters(cls, counters) -> "PlaneTelemetry":
-        """Adopt a device counter dict (the fused drivers' trailing
-        ``tele`` element, hit counters already remapped to LINE ids)."""
-        return cls(**{f.name: np.asarray(counters[f.name], np.int64)
-                      for f in fields(cls)})
+        """Adopt a host counter dict (hit counters already remapped to
+        LINE ids)."""
+        return cls(**{k: counters[k] for k in _KEYS})
+
+    def _group(self, group: str) -> dict:
+        values = self._groups.get(group)
+        if values is None:
+            TELEMETRY_READS[group] += 1
+            values = _host(self._fetch.pop(group)(), _GROUPS[group])
+            self._groups[group] = values
+        return values
 
     # --------------------------------------------------------- geometry
     @property
@@ -116,15 +179,14 @@ class PlaneTelemetry:
             return self
         if not isinstance(other, PlaneTelemetry):
             return NotImplemented
-        return PlaneTelemetry(**{
-            f.name: getattr(self, f.name) + getattr(other, f.name)
-            for f in fields(self)})
+        return PlaneTelemetry(**{k: getattr(self, k) + getattr(other, k)
+                                 for k in _KEYS})
 
     __radd__ = __add__
 
     # ------------------------------------------------- mapping protocol
     def keys(self):
-        return tuple(f.name for f in fields(self))
+        return _KEYS
 
     def __getitem__(self, key: str) -> np.ndarray:
         if key not in self.keys():
@@ -149,9 +211,8 @@ class PlaneTelemetry:
     def __eq__(self, other) -> bool:
         if not isinstance(other, PlaneTelemetry):
             return NotImplemented
-        return all(np.array_equal(getattr(self, f.name),
-                                  getattr(other, f.name))
-                   for f in fields(self))
+        return all(np.array_equal(getattr(self, k), getattr(other, k))
+                   for k in _KEYS)
 
     def __repr__(self) -> str:
         return (f"PlaneTelemetry(S={self.n_shards}, L={self.n_lines}, "
