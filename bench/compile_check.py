@@ -2,13 +2,15 @@
 
     JAX_PLATFORMS=cpu python3 -m bench.compile_check [--cells a,b]
 
-For every cell of ``BENCHMARK.json`` this builds the cell's state shapes
-(the set-up's jitted build) and the fused driver ``DevicePlane.ops``
-runs at the cell's size (``run_rounds`` on one chip, ``run_rounds_sharded``
-over a 2x2 mesh of four), compiles both for a ``v5e:2x2`` topology, and
-prints the bytes per device.  It also compiles the flat driver at the
-four-chip cell's record count on one chip, which has to be refused: that
-table does not fit one chip.  Nothing runs, so nothing here is a time.
+For every cell of ``BENCHMARK.json`` this takes from the cell's driver
+(:mod:`bench.drivers`) the program one batch runs, lowered at the
+cell's shapes (for the YCSB driver the fused loop of ``DevicePlane.ops``:
+``run_rounds`` on one chip, ``run_rounds_sharded`` over a 2x2 mesh of
+four) and, over several chips, the state build; compiles both for a
+``v5e:2x2`` topology, and prints the bytes per device.  It also compiles
+a four-chip cell's verb on one chip, which has to be refused: that
+table does not fit one chip.  A driver without the hooks is skipped
+with a message.  Nothing runs, so nothing here is a time.
 """
 
 from __future__ import annotations
@@ -34,49 +36,24 @@ def _mem(compiled) -> dict:
             "total_bytes": total, "fits": total <= HBM}
 
 
-def check(cfg: dict, chips: int, topo) -> dict:
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import (AxisType, Mesh, NamedSharding,
-                              SingleDeviceSharding)
-    from jax.sharding import PartitionSpec as P
-    from repro.core.rounds import (make_sharded_state, make_state,
-                                   run_rounds, run_rounds_sharded)
-
-    n, l, w = (int(cfg["n_nodes"]), int(cfg["recordcount"]),
-               int(cfg["payload_width"]))
-    r = n * int(cfg["clients_per_node"])
-    kw = dict(write_back=bool(cfg["write_back"]), payload_width=w)
-    if chips == 1:
-        one = SingleDeviceSharding(topo.devices[0])
-        shapes = jax.eval_shape(lambda: make_state(n, l, **kw))
-        state = {k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)
-                 for k, s in shapes.items()}
-        ops = [jax.ShapeDtypeStruct((r,), jnp.int32, sharding=one)] * 3
-        wd = jax.ShapeDtypeStruct((r, w), jnp.int32, sharding=one)
-        verb = jax.jit(run_rounds, static_argnames=("n_nodes",)).lower(
-            state, *ops, wd, n_nodes=n).compile()
-        return {"chips": 1, "verb": _mem(verb),
-                "has_custom_call": "tpu_custom_call" in verb.as_text()}
-    mesh = Mesh(np.array(topo.devices[:chips]), ("shards",),
-                axis_types=(AxisType.Auto,))
-    shapes = jax.eval_shape(lambda: make_sharded_state(n, l, mesh, **kw))
-    shard = {k: NamedSharding(mesh, P(*[
-        "shards" if d == s.shape.index(l) else None
-        for d in range(len(s.shape))])) for k, s in shapes.items()}
-    state = {k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=shard[k])
-             for k, s in shapes.items()}
-    rs = NamedSharding(mesh, P("shards"))
-    ops = [jax.ShapeDtypeStruct((r,), jnp.int32, sharding=rs)] * 3
-    wd = jax.ShapeDtypeStruct((r, w), jnp.int32,
-                              sharding=NamedSharding(mesh, P("shards")))
-    build = jax.jit(lambda: make_sharded_state(n, l, mesh, **kw),
-                    out_shardings=shard).lower().compile()
-    verb = run_rounds_sharded.lower(state, *ops, wd, mesh=mesh,
-                                    n_nodes=n).compile()
+def check(cell, chips: int, topo) -> dict | None:
+    """Compile the cell's verb (and, over several chips, its state
+    build) from its driver's hooks for ``chips`` devices of ``topo``;
+    None when the driver has no hook."""
+    lower_verb = getattr(cell.driver, "lower_verb", None)
+    if lower_verb is None:
+        return None
+    devices = topo.devices[:chips]
+    verb = lower_verb(cell.config, devices).compile()
     text = verb.as_text()
-    return {"chips": chips, "build": _mem(build), "verb": _mem(verb),
+    if chips == 1:
+        return {"chips": 1, "verb": _mem(verb),
+                "has_custom_call": "tpu_custom_call" in text}
+    lower_build = getattr(cell.driver, "lower_build", None)
+    build = lower_build(cell.config, devices) if lower_build else None
+    return {"chips": chips,
+            **({"build": _mem(build.compile())} if build else {}),
+            "verb": _mem(verb),
             "all_to_all": text.count("all-to-all("),
             "all_reduce": text.count("all-reduce(")}
 
@@ -98,13 +75,17 @@ def main(argv=None) -> int:
              or [w["name"] for w in man["workloads"]])
     for name in names:
         cell = manifest.cell(name, man)
+        res = check(cell, cell.chips, topo)
+        if res is None:
+            print(f"compile_check: {name}: its driver "
+                  f"{cell.driver.__name__} has no lower_verb; skipped",
+                  file=sys.stderr, flush=True)
+            continue
         print(json.dumps({"cell": name, "recordcount":
-                          cell.config["recordcount"],
-                          **check(cell.config, cell.chips, topo)}),
-              flush=True)
+                          cell.config["recordcount"], **res}), flush=True)
         if cell.chips > 1:
             try:
-                res = check(cell.config, 1, topo)
+                res = check(cell, 1, topo)
             except Exception as e:                 # the compiler refuses
                 res = {"refused": f"{type(e).__name__}: {str(e)[:300]}"}
             print(json.dumps({"cell": name, "on_one_chip": res}),

@@ -1,9 +1,11 @@
 """``BENCHMARK.json`` and the files it names, resolved by name.
 
 A cell names its configuration and its traffic mix; the configuration's
-entry names its file, the mix is ``bench/traffic/<traffic>.json``, and
-each per-layer metric is read by ``bench/metrics/<name>.py``.  Nothing
-here knows any cell, mix or metric by name.
+entry names its file, which may name its driver (``"driver"``, the
+module ``bench/drivers/<driver>.py``; ``ycsb`` without the key), the
+mix is ``bench/traffic/<traffic>.json``, and each per-layer metric is
+read by ``bench/metrics/<name>.py``.  Nothing here knows any cell, mix,
+driver or metric by name.
 """
 
 from __future__ import annotations
@@ -12,6 +14,9 @@ import json
 import re
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
+
+from .drivers import driver
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -27,6 +32,7 @@ class Cell:
     chips: int
     config: dict
     traffic: dict
+    driver: ModuleType
     end_to_end: list
     per_layer: list
 
@@ -55,5 +61,6 @@ def cell(name: str, manifest: dict | None = None) -> Cell:
     with open(BENCH_DIR / "traffic" / f"{w['traffic']}.json") as f:
         traffic = json.load(f)
     return Cell(name=name, chips=int(w["chips"]), config=config,
-                traffic=traffic, end_to_end=_for(man["end_to_end"], name),
+                traffic=traffic, driver=driver(config.get("driver")),
+                end_to_end=_for(man["end_to_end"], name),
                 per_layer=_for(man["per_layer"], name))

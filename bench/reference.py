@@ -44,6 +44,12 @@ class Served:
     rounds: int = 0
     failed: bool = False
 
+    @property
+    def ops(self) -> int:
+        """Ops the batch completed: its slots with a line, unless it
+        was refused."""
+        return 0 if self.failed else int((self.line >= 0).sum())
+
 
 @dataclass
 class Reference:

@@ -21,10 +21,10 @@ def least_bytes(n_ops: int, width: int) -> int:
     return int(n_ops) * op_bytes(width)
 
 
-def roofline_share(n_ops: int, width: int, busy_s: float,
-                   bytes_per_s: float, n_chips: int) -> float:
-    """Share (0..1) of the bandwidth roofline: the least time the ops
+def roofline_share(n_bytes: int, busy_s: float, bytes_per_s: float,
+                   n_chips: int) -> float:
+    """Share (0..1) of the bandwidth roofline: the least time ``n_bytes``
     take at ``n_chips`` chips' peak HBM bandwidth, over the device busy
     time (mean over those chips) they took."""
-    least_s = least_bytes(n_ops, width) / (bytes_per_s * n_chips)
+    least_s = n_bytes / (bytes_per_s * n_chips)
     return least_s / busy_s

@@ -17,8 +17,10 @@ Both functions are pure, so they are tested on a small trace recorded
 on a TPU v5e.  ``python3 -m bench.spans --workload <cell> --seed <n>
 --seconds <s> [--out DIR]`` runs one traced window of a cell through
 :func:`bench.harness.run` on the chip and writes the reduction, with
-the verb's compiled HLO, to ``DIR/spans_<cell>_<seed>.json`` (``DIR``
-defaults to ``spans_out/`` in the checkout).
+the verb's compiled HLO (from the cell driver's ``lower_verb``; a driver
+without it leaves the device time unscoped), to
+``DIR/spans_<cell>_<seed>.json`` (``DIR`` defaults to ``spans_out/`` in
+the checkout).
 """
 
 from __future__ import annotations
@@ -148,51 +150,6 @@ def reduce(trace: dict, scopes: dict | None = None) -> dict:
     }
 
 
-def verb_hlo(config: dict, devices: list) -> str:
-    """Compiled HLO text of the fused driver ``DevicePlane.ops`` runs
-    for a cell's configuration on ``devices`` (``run_rounds`` on one
-    chip, ``run_rounds_sharded`` striped over more), lowered from the
-    cell's shapes as the harness's plane holds them."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import (AxisType, Mesh, NamedSharding,
-                              SingleDeviceSharding)
-    from jax.sharding import PartitionSpec as P
-    from repro.core.rounds import (make_sharded_state, make_state,
-                                   run_rounds, run_rounds_sharded)
-
-    n, l, w = (int(config["n_nodes"]), int(config["recordcount"]),
-               int(config["payload_width"]))
-    r = n * int(config["clients_per_node"])
-    kw = dict(write_back=bool(config["write_back"]), payload_width=w)
-    if len(devices) == 1:
-        one = SingleDeviceSharding(devices[0])
-        shapes = jax.eval_shape(lambda: make_state(n, l, **kw))
-        shard = {k: one for k in shapes}
-        rs = one
-        mesh = None
-    else:
-        mesh = Mesh(np.array(devices), ("shards",),
-                    axis_types=(AxisType.Auto,))
-        shapes = jax.eval_shape(
-            lambda: make_sharded_state(n, l, mesh, **kw))
-        shard = {k: NamedSharding(mesh, P(*[
-            "shards" if d == s.shape.index(l) else None
-            for d in range(len(s.shape))])) for k, s in shapes.items()}
-        rs = NamedSharding(mesh, P("shards"))
-    state = {k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=shard[k])
-             for k, s in shapes.items()}
-    ops = [jax.ShapeDtypeStruct((r,), jnp.int32, sharding=rs)] * 3
-    wd = jax.ShapeDtypeStruct((r, w), jnp.int32, sharding=rs)
-    if mesh is None:
-        low = run_rounds.lower(state, *ops, wd, n_nodes=n)
-    else:
-        low = run_rounds_sharded.lower(state, *ops, wd, mesh=mesh,
-                                       n_nodes=n)
-    return low.compile().as_text()
-
-
 def main(argv=None) -> int:
     """One traced run of a cell (``bench.run`` with ``--trace 1``) that
     keeps the plain trace, reduces it here and writes the reduction,
@@ -200,6 +157,7 @@ def main(argv=None) -> int:
     import argparse
     import gzip
     import json
+    import sys
     from pathlib import Path
 
     from . import harness
@@ -243,16 +201,25 @@ def main(argv=None) -> int:
     if rc or "plain" not in kept:
         return rc or 1
     cell = kept["cell"]
-    text = verb_hlo(cell.config, harness.chips_for(cell.chips))
-    scopes = scopes_from_hlo(text)
+    lower_verb = getattr(cell.driver, "lower_verb", None)
+    if lower_verb is None:
+        print(f"bench.spans: the driver {cell.driver.__name__} has no "
+              "lower_verb; device time is left unscoped",
+              file=sys.stderr, flush=True)
+        text = None
+    else:
+        text = lower_verb(cell.config,
+                          harness.chips_for(cell.chips)).compile().as_text()
+    scopes = scopes_from_hlo(text or "")
     red = reduce(kept["plain"], scopes)
     red.pop("calls", None)
     doc = {"workload": args.workload, "seed": args.seed,
            "spans": red, "n_scoped_instructions": len(scopes),
            "result": kept["out"]}
     Path(str(stem) + ".json").write_text(json.dumps(doc, indent=1))
-    with gzip.open(str(stem) + ".hlo.txt.gz", "wt") as f:
-        f.write(text)
+    if text is not None:
+        with gzip.open(str(stem) + ".hlo.txt.gz", "wt") as f:
+            f.write(text)
     if args.keep_trace:
         with gzip.open(str(stem) + ".trace.json.gz", "wt") as f:
             json.dump(kept["plain"], f)

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from bench import harness, manifest
+from bench.drivers import ycsb
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2**32 + 17
@@ -54,11 +55,11 @@ class Faulty:
         return res
 
 
-def run(fault=None, **mix):
+def run(fault=None, workload="ycsb_a.l19", **mix):
     def factory(cfg, seed, devices):
-        plane = harness.open_plane(cfg, seed, devices)
+        plane = ycsb.open_plane(cfg, seed, devices)
         return plane if fault is None else Faulty(plane, fault)
-    cell = small("ycsb_a.l19", 1024)
+    cell = small(workload, 1024)
     cell = dataclasses.replace(cell, traffic=dict(cell.traffic, **mix))
     return harness.run(cell, seed=SEED, seconds=0.0,
                        trace=False, t0=time.perf_counter(),
@@ -89,6 +90,23 @@ def test_other_mixes_are_correct(mix):
                                    "answer_altered"])
 def test_fault_is_not_correct(fault):
     out = run(fault)
+    assert out["correct"] is False
+    assert out["checks"]["reply_mismatches"]["value"] > 0 \
+        or out["checks"]["readback_mismatches"]["value"] > 0
+
+
+def test_read_only_cell_sound_run_is_correct():
+    out = run(workload="ycsb_c.l19")
+    assert out["correct"] is True
+    assert out["attempted"] == 12 * 256 and out["failed"] == 0
+    assert all(c["value"] == 0 for c in out["checks"].values())
+
+
+@pytest.mark.parametrize("fault", ["half_batch", "answer_altered"])
+def test_read_only_cell_fault_is_not_correct(fault):
+    # the read-only cell cannot have a step that leaves the state
+    # unchanged: no read changes a record, so every answer stays right
+    out = run(fault, "ycsb_c.l19")
     assert out["correct"] is False
     assert out["checks"]["reply_mismatches"]["value"] > 0 \
         or out["checks"]["readback_mismatches"]["value"] > 0

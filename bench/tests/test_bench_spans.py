@@ -130,8 +130,8 @@ def _result(host_s=None, events=(0, 0, 0), served=0):
 
 def _ctx(results):
     return SimpleNamespace(traced_results=results, trace={}, rounds=[],
-                           traced_rounds=0, traced_ops=0, width=0,
-                           n_chips=1, peak=None)
+                           traced_rounds=0, traced_bytes=0, n_chips=1,
+                           peak=None)
 
 
 TIMES = [{"dispatch": 0.002, "sync": 0.08, "telemetry": 0.004,
