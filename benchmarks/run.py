@@ -27,8 +27,7 @@ def smoke() -> None:
     BENCH_*.json for the per-commit perf trajectory (gated by
     benchmarks.check_regression)."""
     from . import (bench_serving, fig7_rounds, fig9_rounds,
-                   fig10_btree_rounds, fig11_tpcc_rounds, fig_rounds,
-                   fig_rounds_data)
+                   fig10_btree_rounds, fig_rounds, fig_rounds_data)
     from .common import MicroConfig, emit, run_micro, timer, \
         write_bench_json
 
@@ -54,7 +53,6 @@ def smoke() -> None:
     fig_rounds_data.main(smoke=True)     # writes BENCH_rounds_data.json
     fig9_rounds.main(smoke=True)         # writes BENCH_rounds_skew.json
     fig10_btree_rounds.main(smoke=True)  # writes BENCH_btree_rounds.json
-    fig11_tpcc_rounds.main(smoke=True)     # writes BENCH_txn_rounds.json
     bench_serving.main(smoke=True)           # writes BENCH_serving.json
 
 
@@ -67,7 +65,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma list: fig7,fig7r,fig8,fig9,fig9r,"
                          "rounds_skew,fig10,btree_rounds,fig11,"
-                         "txn_rounds,fig12,rounds,rounds_data,serving,"
+                         "fig12,rounds,rounds_data,serving,"
                          "roofline")
     args = ap.parse_args()
 
@@ -84,7 +82,7 @@ def main() -> None:
     from . import (bench_serving, fig7_rounds, fig7_scalability,
                    fig8_locality, fig9_rounds, fig9_skew,
                    fig10_btree_rounds, fig10_ycsb_btree, fig11_tpcc,
-                   fig11_tpcc_rounds, fig12_2pc, fig_rounds,
+                   fig12_2pc, fig_rounds,
                    fig_rounds_data, roofline_report)
     figures = {
         "fig7": fig7_scalability.main,
@@ -96,7 +94,6 @@ def main() -> None:
         "fig10": fig10_ycsb_btree.main,
         "btree_rounds": fig10_btree_rounds.main,
         "fig11": fig11_tpcc.main,
-        "txn_rounds": fig11_tpcc_rounds.main,
         "fig12": fig12_2pc.main,
         "rounds": fig_rounds.main,
         "rounds_data": fig_rounds_data.main,

@@ -10,13 +10,14 @@ counter, per-tuple (rts, wts) headers — the device mirror of the host
 / TO execute entirely on device, aborts and retries included.
 
 The encoding is the bridge: :func:`encode_txns` turns host-style
-``(read_set, write_set)`` tuple-id pairs into the loop's canonical
+``(read_set, write_set)`` tuple-id pairs into the loop's
 ``(glines, rmask, wmask)`` arrays — per-txn GCL lines sorted ascending
-(the deadlock-freedom contract), with a deterministic cap policy when a
-txn touches more than ``max_group_lines`` GCLs: write lines win over
-read-only lines, lowest line first (a Fig. 11-style workload rarely
-trips it; the EFFECTIVE per-txn sets come back to the caller so a host
-oracle replays exactly what the device ran).
+(distinct lines, ``-1`` pads trailing).  A txn that touches more than
+``max_group_lines`` GCLs is refused, not trimmed: the loop presents a
+phase's lines all at once, so the cap is only the batch's static width
+(``effective`` still comes back, equal to the input sets, for the host
+oracle to replay).  TPC-C at its own schema is
+:mod:`repro.apps.tpcc_device`.
 
 :class:`DeviceTxnEngine` owns a :class:`DevicePlane` plus the shared
 :class:`TxnStats` (same dataclass as the host engine, so benches
@@ -42,7 +43,7 @@ from .txn import TxnStats
 class DeviceTxnConfig:
     algo: str = "2pl"                # 2pl | to
     tuples_per_gcl: int = 8
-    max_group_lines: int = 4         # G: per-txn GCL cap (trim policy)
+    max_group_lines: int = 4         # G: per-txn GCL slots (static)
 
 
 def encode_txns(txns, cfg: DeviceTxnConfig):
@@ -50,9 +51,9 @@ def encode_txns(txns, cfg: DeviceTxnConfig):
 
     ``txns`` is a list of ``(read_set, write_set)`` tuple-id
     collections.  Returns ``(glines [B, G], rmask [B, G, T],
-    wmask [B, G, T], effective)`` where ``effective`` is the per-txn
-    ``(read_set, write_set)`` actually encoded (after the G-cap trim) —
-    feed THAT to a host oracle, not the input."""
+    wmask [B, G, T], effective)`` where ``effective`` is each txn's
+    sorted ``(read_set, write_set)``.  Raises ``ValueError`` for a txn
+    that touches more than ``max_group_lines`` GCLs."""
     T = cfg.tuples_per_gcl
     G = cfg.max_group_lines
     B = len(txns)
@@ -63,20 +64,17 @@ def encode_txns(txns, cfg: DeviceTxnConfig):
     for i, (read_set, write_set) in enumerate(txns):
         wset = set(write_set)
         rset = set(read_set)
-        wg = sorted({t // T for t in wset})
-        rg = sorted({t // T for t in rset} - set(wg))
-        keep = (wg + rg)[:G]          # write lines win, lowest first
-        keep_s = sorted(keep)
-        eff_w = sorted(t for t in wset if t // T in keep)
-        eff_r = sorted(t for t in rset if t // T in keep)
-        effective.append((eff_r, eff_w))
-        col = {g: j for j, g in enumerate(keep_s)}
-        glines[i, :len(keep_s)] = keep_s
-        for t in eff_w:
+        keep = sorted({t // T for t in wset | rset})
+        if len(keep) > G:
+            raise ValueError(f"txn {i} touches {len(keep)} GCLs, more "
+                             f"than max_group_lines={G}")
+        effective.append((sorted(rset), sorted(wset)))
+        col = {g: j for j, g in enumerate(keep)}
+        glines[i, :len(keep)] = keep
+        for t in wset:
             wmask[i, col[t // T], t % T] = 1
-        for t in eff_r:
-            if t not in wset:
-                rmask[i, col[t // T], t % T] = 1
+        for t in rset - wset:
+            rmask[i, col[t // T], t % T] = 1
     return glines, rmask, wmask, effective
 
 

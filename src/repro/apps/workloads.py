@@ -150,14 +150,15 @@ def device_rounds_batches(cfg: DeviceRoundsConfig, seed: int = 0):
 
 @dataclass
 class TxnBatchConfig:
-    """Fig. 11-shaped transaction workload for the device txn loop
-    (``apps/txn_device.py``) AND the host ``TxnEngine`` oracle: each
-    batch is B txns mixing NewOrder-style (read 2 tuples, write a
-    district counter + order slot + items across several GCLs),
-    Payment-style (3 writes), and OrderStatus-style read-only shapes
-    over a small Zipf-skewed tuple space, plus shuffled TO timestamps
-    — clients assign their ts at txn BEGIN, so batch arrival order need
-    not match, which is what makes TO aborts real."""
+    """A generic tuple-set transaction stream for the device txn loop's
+    tuple-header programs (``apps/txn_device.py``) AND the host
+    ``TxnEngine`` oracle: each batch is B txns of three shapes (write
+    tuples on up to three GCLs and read one more; write two; read up to
+    three) over a small Zipf-skewed tuple space, plus shuffled TO
+    timestamps — clients assign their ts at txn BEGIN, so batch arrival
+    order need not match, which is what makes TO aborts real.  It backs
+    the 2PL/TO differentials; TPC-C at its own schema and mix is
+    ``apps/tpcc_device.py``."""
     n_gcls: int = 64
     tuples_per_gcl: int = 8
     batch: int = 16
@@ -199,16 +200,16 @@ def device_txn_batches(cfg: TxnBatchConfig, seed: int = 0):
         txns = []
         for _ in range(cfg.batch):
             shape = rng.random()
-            if shape < 0.5:                          # NewOrder-style
+            if shape < 0.5:                          # 3 write, 1 read
                 wg = pick_gcls(min(3, cfg.max_group_lines))
                 rg = pick_gcls(1)
                 writes = pick_tuples(wg, 2)
                 reads = pick_tuples(rg, 2)
-            elif shape < 0.85:                       # Payment-style
+            elif shape < 0.85:                       # writes only
                 wg = pick_gcls(min(2, cfg.max_group_lines))
                 writes = pick_tuples(wg, 2)[:3]
                 reads = []
-            else:                                    # OrderStatus-style
+            else:                                    # reads only
                 rg = pick_gcls(min(3, cfg.max_group_lines))
                 writes = []
                 reads = pick_tuples(rg, 2)

@@ -24,6 +24,7 @@ ONE result type:
     res = plane.rmw(node, line, modify=splice, operands=(tok,))
     res = plane.descent(node, key, root, transition=step)
     out = plane.txn(node, glines, rmask, wmask, ts, algo="2pl")
+    out = plane.txn(node, typ, inp, lines, mode, algo=program)
 
 Every verb mutates ``plane.state`` in place (the plane IS the memory),
 materializes its result arrays exactly once at the end (zero syncs
@@ -432,19 +433,23 @@ class DevicePlane:
         return PlaneResult(None, lanes, sp.rounds, stats=stats,
                            telemetry=sp.telemetry, host_s=sp.host_s)
 
-    def txn(self, node_id, glines, rmask, wmask, ts, *, algo: str,
-            max_iters: int | None = None,
+    def txn(self, node_id, *batch, algo, max_iters: int | None = None,
             max_rounds: int | None = None):
         """Run one transaction batch through the fused device CC loop
-        (:mod:`repro.core.rounds.txn`); returns a ``TxnBatchResult``."""
+        (:mod:`repro.core.rounds.txn`): ``algo`` is ``"2pl"`` or
+        ``"to"`` (``batch`` = ``glines, rmask, wmask, ts``) or a
+        ``TxnProgram`` (``batch`` = what its ``encode`` takes).  The
+        bracket's children ``plane.pad`` (encoding, sharded slot
+        padding), ``dispatch``, ``sync``, ``telemetry`` and ``results``
+        are timed into the returned ``TxnBatchResult.host_s``."""
         from .txn import run_txn_batch
-        with self._span("txn",
-                        batch=tuple(np.asarray(glines).shape)) as sp:
-            res = run_txn_batch(self, node_id, glines, rmask, wmask, ts,
-                                algo=algo, max_iters=max_iters,
-                                max_rounds=max_rounds)
+        shape = tuple(np.shape(batch[0])) if batch else ()
+        with self._span("txn", batch=shape) as sp:
+            res = run_txn_batch(self, node_id, *batch, algo=algo,
+                                max_iters=max_iters,
+                                max_rounds=max_rounds, span=sp)
             sp.rounds, sp.telemetry = res.rounds, res.telemetry
-            sp.attrs = {"algo": algo}
+            sp.attrs = {"algo": getattr(algo, "name", algo)}
         return res
 
     def evict(self, node_id, line) -> None:

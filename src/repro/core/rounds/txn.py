@@ -2,72 +2,79 @@
 
 The paper's Sec. 8.2 argument is that classic CC algorithms fall out of
 the SELCC abstraction almost for free: latches are cache-line states,
-tuple headers are payload bytes, and 2PL / TO need no server-side txn
-logic at all.  ``apps/txn.py`` shows that on the host DES; this module
-shows it on the device rounds plane — the whole batch of transactions
-(acquire, execute, validate, commit/abort, RETRY) runs inside a single
-jitted ``lax.while_loop``, with three coherence spins per scheduler
-iteration and zero host syncs.
+row locks are payload lanes, and 2PL / TO need no server-side txn logic
+at all.  ``apps/txn.py`` shows that on the host DES; this module shows
+it on the device rounds plane — the whole batch of transactions
+(acquire, execute, commit, no-wait restart) runs inside a single jitted
+``lax.while_loop`` with two coherence spins per scheduler iteration and
+zero host syncs.
 
-Line layout — each GCL packs a latch word plus ``T`` tuple headers into
-its payload lanes (``W = 2 + 2*T``):
+What a transaction does is a :class:`TxnProgram`: a static object with
+``entries`` line slots per transaction, each naming a line and a lock
+mode (``NONE`` — read without a lock, for rows nobody writes;
+``SHARED``; ``EXCL``), and two pure ``jnp`` functions:
 
-    lane 0           lock word: 0 = free, else holder's slot index + 1
-    lane 1           committed-writes counter (the 2PL workload effect)
-    lane 2+2t, 3+2t  tuple t's (read_ts, write_ts) header   (TO)
+* ``plan(phase, typ, inp, lines, mode, lanes)`` — the line set of the
+  NEXT phase, computed from the lanes held so far (a TPC-C NewOrder
+  inserts at the district's ``d_next_o_id``, a StockLevel reads the
+  district, then its last 20 orders, then their items' stock);
+* ``effect(typ, inp, lines, mode, lanes)`` — after the last phase: the
+  commit decision, the new lanes of every ``EXCL`` entry and the
+  transaction's outputs.
 
-A transaction batch is ``node [B]``, ``glines [B, G]`` (each txn's
-sorted ascending lines, ``-1``-padded at the END — canonical order is
-the caller's contract and is validated host-side), ``rmask/wmask
-[B, G, T]`` tuple touch masks, and ``ts [B]`` (TO timestamps, assigned
-by the client at txn begin — batch arrival order need not match, which
-is exactly what makes TO aborts real).
+Lane 0 of every transactional line is the lock word: ``0`` free, ``n >
+0`` held shared by ``n`` readers, ``-(slot + 1)`` held exclusive by
+batch slot ``slot``.  A batch is ``typ [B]``, ``inp [B, I]`` and the
+phase-0 entries ``lines [B, M]`` (``-1`` = unused) and ``mode [B, M]``.
 
-Per outer iteration, every live txn presents its NEXT line in canonical
-order (so any deadlock cycle would need an ascending-order cycle —
-impossible: deadlock-freedom by construction):
+Per scheduler iteration every live transaction presents ALL lines of
+its current phase at once (no-wait cannot deadlock: nobody waits), plus
+the lines it holds shared (their reader count may have to change):
 
-1. DEDUP — duplicate wanted lines keep only the lowest global slot
-   (the rounds engine coalesces duplicate (node, line) ops, so one
-   presenter per line per spin is a hard requirement, and the static
-   priority makes flat and sharded planes bit-identical);
-2. READ spin — winners read their line; ``lock == 0`` means acquired
-   (no-wait: a held line is an immediate abort+retry, not a wait — the
-   loser releases its whole held prefix and restarts from k = 0, the
-   defer/respin idiom generalized from sharded bucket overflow);
-3. ACQUIRE spin — acquired slots write the lanes back with the lock
-   word set.  The read lanes are CARRIED in the loop (a held line
-   cannot change under us, so the copy stays fresh by construction);
-4. txns that acquired their last line APPLY their algorithm on the
-   carried lanes (2PL: bump every write-line's counter, always commit;
-   TO: the host engine's exact per-GCL, per-sorted-tuple timestamp
-   checks — including its partial-update leak on abort — as a
-   statically unrolled scan with a running ``stopped`` flag);
-5. FINALIZE spin — completing txns write ALL their lines with new
-   lanes and ``lock = 0`` in one combined publish-and-release write;
-   no-wait losers write their held prefix back unchanged (releases).
-   Every line written here is held by exactly one finishing txn, so
-   the [B*G] slots never collide.
+1. READ spin — every presented entry reads its line (reads never
+   conflict, so no dedup is needed here); the read lanes are carried in
+   the loop (a held line cannot change under its holder);
+2. GRANT — per line, the lowest presenting entry (slot-major order)
+   decides: an exclusive request is granted iff it is that entry and
+   the word read 0; shared requests are granted together iff the lowest
+   request is shared and the word holds no exclusive holder.  A
+   transaction that is not granted EVERY lock of its phase is a no-wait
+   loser: it releases everything and restarts from phase 0 next
+   iteration; a winner computes its next phase (``plan``) or, when that
+   phase is empty or it ran its last, completes (``effect``);
+3. WRITE spin — one write per changed line (its lowest writing entry's
+   node): new lock words (readers added and released, exclusive grants),
+   completers' published lanes with the word cleared, losers' exclusive
+   lines released with their carried lanes.
 
-Commit/abort decisions and final memory images are bit-identical to
-the host ``TxnEngine`` replayed sequentially in device completion
-order ``(exec_step, slot)`` — txns completing in the same iteration
-hold disjoint line sets, so their effects commute and any interleaving
-of a tie is the same serial history.  ``tests/test_txn_device.py``
-asserts this differentially on flat and 4-shard planes.
+Serial order is ``(exec_step, slot)``: a transaction that completes at
+iteration ``i`` held every lock it needed through ``i``'s write spin,
+and a conflicting one got its lock only at a later read, so it
+completes later; transactions that complete together hold no
+conflicting modes (an exclusive grant excludes every other holder of
+the line), so their effects commute.  ``tests/test_txn_device.py`` and
+``tests/test_tpcc_device.py`` check the generic cases and TPC-C
+against plain references replayed in that order, on flat and 4-shard
+planes.
 
 The sharded mirror (:func:`run_txn_rounds_sharded`) runs the SAME
-scheduler inside one ``shard_map``: per-txn state stays put on its
-shard, the dedup sees everyone through one ``all_gather`` of wanted
-lines, each spin is the ``_route_round`` two-all_to_alls loop, and
-liveness is a psum — global slot order is preserved by the block
-distribution, so decisions match the flat plane exactly.
+iteration inside one ``shard_map``: per-transaction state stays on its
+shard, every grant and write decision is computed from an
+``all_gather`` of the batch's entry metadata (global slot order is
+preserved by the block distribution, so the decisions are the flat
+plane's), each spin is the ``_route_round`` two-all_to_alls loop, and
+liveness is a psum.
+
+The generic tuple-header cases (``algo="2pl"`` / ``"to"``, lane 1 a
+committed-writes counter, lanes ``2 + 2t, 3 + 2t`` tuple ``t``'s
+``(read_ts, write_ts)``) are two programs of one phase over the same
+loop; TPC-C is ``apps/tpcc_device.py``'s program.
 """
 
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
@@ -85,19 +92,58 @@ LOCK_LANE = 0
 WRITES_LANE = 1
 HDR_LANES = 2
 
+NONE, SHARED, EXCL = 0, 1, 2
+SPIN_SLOTS = 512             # slots a spin presents at once (per shard)
+
+# program counters of one batch, summed over its iterations
+COUNTER_KEYS = ("iters", "restarts", "phases", "s_grants", "x_grants",
+                "rollbacks")
+
 
 def txn_payload_width(tuples_per_line: int) -> int:
-    """Payload lanes a txn GCL needs: lock + writes + (rts, wts) per
-    tuple."""
+    """Payload lanes a tuple-header GCL needs: lock + writes + (rts, wts)
+    per tuple."""
     return HDR_LANES + 2 * tuples_per_line
 
 
-# ------------------------------------------------------ algorithm bodies
+class TxnProgram:
+    """What the transactions of one kind do (see the module docstring).
+
+    Subclasses set ``name``, ``entries`` (M), ``inputs`` (I),
+    ``outputs`` (O), ``depths`` (phases per transaction type) and
+    ``width`` (the payload lanes a line must have), and define
+    :meth:`plan`, :meth:`effect` and :meth:`encode`.  Instances are
+    static arguments of the jitted loops: keep one per configuration."""
+
+    name = "program"
+    entries = 1
+    inputs = 1
+    outputs = 1
+    depths = (1,)
+    width = 0
+
+    def plan(self, phase, typ, inp, lines, mode, lanes):
+        """Lines and modes with the entries of phase ``phase + 1`` filled
+        in (entries a transaction has not used yet), per transaction."""
+        return lines, mode
+
+    def effect(self, typ, inp, lines, mode, lanes):
+        """``(commit [B], lanes [B, M, W], out [B, O])`` after the last
+        phase: ``lanes`` holds the new lanes of every EXCL entry."""
+        raise NotImplementedError
+
+    def encode(self, *batch):
+        """Host arrays ``(typ [B], inp [B, I], lines [B, M], mode [B,
+        M])`` of one batch (numpy, validated)."""
+        raise NotImplementedError
+
+
+# ------------------------------------------------ the tuple-header cases
 
 def _apply_2pl(lanes, glines, rmask, wmask, ts):
-    """2PL no-wait commit effect: all locks are already held (the loop
-    IS the growing phase), so commit is unconditional; the workload
-    effect is one counter bump per write-line."""
+    """2PL no-wait commit effect: every lock is held, so commit is
+    unconditional; the workload effect is one counter bump per
+    write-line."""
     valid = glines >= 0
     has_write = jnp.logical_and(wmask.astype(bool).any(axis=2), valid)
     new = lanes.at[:, :, WRITES_LANE].add(has_write.astype(jnp.int32))
@@ -136,176 +182,383 @@ def _apply_to(lanes, glines, rmask, wmask, ts):
     return ~stopped, new
 
 
-_APPLY = {"2pl": _apply_2pl, "to": _apply_to}
+class TupleProgram(TxnProgram):
+    """One-phase transactions over tuple-header GCLs: ``glines [B, G]``
+    (each row strictly ascending, ``-1`` pads trailing), ``rmask/wmask
+    [B, G, T]`` tuple touch masks, ``ts [B]`` TO timestamps.  2PL locks
+    a line exclusive when it writes a tuple there and shared when it
+    only reads; TO latches every line exclusive (its checks write the
+    read timestamps)."""
+
+    depths = (1,)
+    outputs = 1
+
+    def __init__(self, algo: str, lines: int, tuples: int):
+        self.name, self.algo = algo, algo
+        self.entries, self.tuples = lines, tuples
+        self.inputs = 2 * lines * tuples + 1
+        self.width = txn_payload_width(tuples)
+        self._apply = {"2pl": _apply_2pl, "to": _apply_to}[algo]
+
+    def _split(self, inp):
+        B, G, T = inp.shape[0], self.entries, self.tuples
+        n = G * T
+        return (inp[:, :n].reshape(B, G, T), inp[:, n:2 * n]
+                .reshape(B, G, T), inp[:, 2 * n])
+
+    def effect(self, typ, inp, lines, mode, lanes):
+        rmask, wmask, ts = self._split(inp)
+        commit, new = self._apply(lanes, lines, rmask, wmask, ts)
+        return commit, new, jnp.zeros((lanes.shape[0], 1), jnp.int32)
+
+    def encode(self, glines, rmask, wmask, ts):
+        glines = np.asarray(glines, np.int32)
+        rmask = np.asarray(rmask, np.int32)
+        wmask = np.asarray(wmask, np.int32)
+        ts = np.asarray(ts, np.int32)
+        valid = glines >= 0
+        if (valid[:, 1:] & ~valid[:, :-1]).any():
+            raise ValueError("glines pads (-1) must trail the valid lines")
+        both = valid[:, 1:] & valid[:, :-1]
+        if (both & (glines[:, 1:] <= glines[:, :-1])).any():
+            raise ValueError("glines must be sorted strictly ascending "
+                             "per txn (distinct lines)")
+        B = glines.shape[0]
+        excl = wmask.astype(bool).any(axis=2) | (self.algo == "to")
+        mode = np.where(valid, np.where(excl, EXCL, SHARED), NONE)
+        inp = np.concatenate([rmask.reshape(B, -1), wmask.reshape(B, -1),
+                              ts[:, None]], axis=1)
+        return (np.zeros(B, np.int32), inp.astype(np.int32), glines,
+                mode.astype(np.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def tuple_program(algo: str, lines: int, tuples: int) -> TupleProgram:
+    """The (cached, so jit-stable) tuple program of ``algo``."""
+    if algo not in ("2pl", "to"):
+        raise ValueError(f"unknown txn algo {algo!r} (have ['2pl', 'to'])")
+    return TupleProgram(algo, lines, tuples)
+
+
+# --------------------------------------------------- one iteration's core
+
+def _grant(lines, mode, want, word, n_lines):
+    """Grant decisions over the WHOLE batch's presented entries ([B, M],
+    slot-major): ``(granted [B, M], ok [B])`` — ``ok`` a transaction got
+    every entry of its phase (NONE entries always do)."""
+    B, M = lines.shape
+    idx = jnp.arange(B * M, dtype=jnp.int32).reshape(B, M)
+    lock = want & (mode != NONE)
+    at = jnp.maximum(lines, 0)
+    first = jnp.full((n_lines,), B * M, jnp.int32).at[
+        jnp.where(lock, lines, n_lines)].min(idx, mode="drop")[at]
+    fmode = mode.reshape(-1)[jnp.minimum(first, B * M - 1)]
+    g = jnp.where(mode == EXCL, (first == idx) & (word == 0),
+                  (fmode == SHARED) & (word >= 0))
+    g = want & jnp.where(lock, g, True)
+    ok = ~jnp.any(want & ~g, axis=1)
+    return g & ok[:, None], ok
+
+
+def _writes(lines, mode, held, granted, complete, failed, word, n_lines):
+    """Write decisions over the whole batch: ``(rep [B, M], new_word [B,
+    M], counts)`` — ``rep`` marks the one entry that writes each changed
+    line, ``new_word`` its lock word."""
+    B, M = lines.shape
+    idx = jnp.arange(B * M, dtype=jnp.int32).reshape(B, M)
+    slot = jnp.arange(B, dtype=jnp.int32)[:, None]
+    lockg = granted & (mode != NONE)
+    rel = (complete[:, None] & (held | lockg)) | (failed[:, None] & held)
+    rel_s = rel & (mode == SHARED)
+    rel_x = rel & (mode == EXCL)
+    g_s = lockg & (mode == SHARED)
+    g_x = lockg & (mode == EXCL) & ~complete[:, None]
+    writer = g_s | rel_s | g_x | rel_x
+    drop = jnp.where(writer, lines, n_lines)
+    at = jnp.maximum(lines, 0)
+    d_s = jnp.zeros((n_lines,), jnp.int32).at[drop].add(
+        g_s.astype(jnp.int32) - rel_s.astype(jnp.int32), mode="drop")[at]
+    x_new = jnp.zeros((n_lines,), jnp.int32).at[
+        jnp.where(g_x, lines, n_lines)].max(
+        jnp.broadcast_to(slot + 1, (B, M)), mode="drop")[at]
+    x_rel = jnp.zeros((n_lines,), bool).at[
+        jnp.where(rel_x, lines, n_lines)].set(True, mode="drop")[at]
+    first = jnp.full((n_lines,), B * M, jnp.int32).at[drop].min(
+        idx, mode="drop")[at]
+    change = (d_s != 0) | (x_new > 0) | x_rel
+    rep = writer & (first == idx) & change
+    new_word = jnp.where(x_new > 0, -x_new,
+                         jnp.where(x_rel, 0, word + d_s))
+    counts = {"s_grants": jnp.sum(g_s.astype(jnp.int32)),
+              "x_grants": jnp.sum((lockg & (mode == EXCL))
+                                  .astype(jnp.int32))}
+    return rep, new_word, counts
+
+
+def _chunked(spin, chunk: int, tele0, tele_add, total=lambda x: x):
+    """``spin`` over only the live slots (``line >= 0``) of a spin's
+    slots, compacted to the front and presented ``chunk`` at a time, as
+    many chunks as the most-loaded shard needs (``total`` lifts a local
+    count to that maximum; ``tele0``/``tele_add`` the spin's telemetry
+    zero and sum).  Reads commute and a write spin writes each
+    line once, so chunks may run one after another; a spin the ``ref``
+    latch kernel serves costs a sequential step per presented slot, so
+    this keeps a round's cost to the lines in flight, not ``B * M``."""
+    def run(stt, nodes, lines, is_write, wdata):
+        n = lines.shape[0]
+        n_pad = -(-n // chunk) * chunk
+        valid = lines >= 0
+        order = jnp.argsort(~valid, stable=True)
+        pad = n_pad - n
+
+        def take(x, fill):
+            x = x[order]
+            return jnp.concatenate(
+                [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
+        lc, nc, wc = take(lines, -1), take(nodes, 0), take(is_write, 0)
+        dc = None if wdata is None else take(wdata, 0)
+        k_n = total(-(-jnp.sum(valid.astype(jnp.int32)) // chunk))
+        w = payload_width(stt)
+
+        def body(c):
+            stt, k, data, r, ok, tele = c
+            at = k * chunk
+
+            def sl(x):
+                return jax.lax.dynamic_slice_in_dim(x, at, chunk)
+            stt, d, r1, ok1, t1 = spin(
+                stt, sl(nc), sl(lc), sl(wc), None if dc is None else sl(dc))
+            data = jax.lax.dynamic_update_slice_in_dim(data, d, at, 0)
+            return stt, k + 1, data, r + r1, ok & ok1, tele_add(tele, t1)
+
+        init = (stt, jnp.int32(0), jnp.zeros((n_pad, w), jnp.int32),
+                jnp.int32(0), jnp.bool_(True), tele0)
+        stt, _, data, r, ok, tele = jax.lax.while_loop(
+            lambda c: c[1] < k_n, body, init)
+        out = jnp.zeros((n, w), jnp.int32).at[order].set(data[:n])
+        return stt, out, r, ok, tele
+    return run
+
+
+def _zero_counters():
+    return {k: jnp.int32(0) for k in COUNTER_KEYS}
+
+
+def _iteration(program, n_lines, carry, batch, *, spin, gather, mine,
+               spin_tele_add):
+    """One scheduler iteration on this shard's transactions (all of them
+    on a flat plane).  ``gather`` lifts a per-transaction array to the
+    whole batch (identity flat, ``all_gather`` sharded), ``mine`` slices
+    a whole-batch array back to this shard's block."""
+    (stt, phase, done, dec, estep, retr, lines, mode, eph, held, lanes,
+     out, it, ok_spins, rounds, tele, ctr) = carry
+    node, typ, inp, lines0, mode0 = batch
+    b, m = lines.shape
+    w = lanes.shape[2]
+    node_rep = jnp.repeat(node, m)
+    live = ~done
+    want = live[:, None] & (lines >= 0) & (eph == phase[:, None])
+    present = want | (live[:, None] & held & (mode == SHARED))
+
+    # 1. READ spin: every presented entry reads its line
+    rl = jnp.where(present, lines, -1).reshape(b * m)
+    stt, rdata, r1, ok1, t1 = spin(stt, node_rep, rl, jnp.zeros_like(rl),
+                                   None)
+    rdata = rdata.reshape(b, m, w)
+    lanes = jnp.where(present[:, :, None], rdata, lanes)
+    word = lanes[:, :, LOCK_LANE]
+
+    # 2. GRANT: decided over the whole batch, in global slot order
+    g_lines, g_mode, g_want, g_word = (gather(x) for x in
+                                       (lines, mode, want, word))
+    g_granted, g_ok = _grant(g_lines, g_mode, g_want, g_word, n_lines)
+    granted, ok = mine(g_granted), mine(g_ok)
+    ok = ok & live
+    failed = live & ~ok
+    lockg = granted & (mode != NONE)
+    held2 = held | lockg
+    nxt_lines, nxt_mode = program.plan(phase, typ, inp, lines, mode,
+                                       lanes)
+    new = (nxt_lines >= 0) & (lines < 0)
+    depth = jnp.asarray(program.depths, jnp.int32)[jnp.maximum(typ, 0)]
+    more = jnp.any(new, axis=1) & (phase + 1 < depth)
+    complete = ok & ~more
+    advance = ok & more
+    commit, fx_lanes, fx_out = program.effect(typ, inp, lines, mode,
+                                              lanes)
+
+    # 3. WRITE spin: new lock words, publications, releases
+    g_complete, g_failed, g_held = (gather(x) for x in
+                                    (complete, failed, held))
+    g_rep, g_new_word, gctr = _writes(g_lines, g_mode, g_held, g_granted,
+                                      g_complete, g_failed, g_word,
+                                      n_lines)
+    rep, new_word = mine(g_rep), mine(g_new_word)
+    publish = complete[:, None] & (mode == EXCL)
+    wdata = jnp.where(publish[:, :, None], fx_lanes, lanes)
+    wdata = wdata.at[:, :, LOCK_LANE].set(new_word)
+    wl = jnp.where(rep, lines, -1).reshape(b * m)
+    stt, _, r2, ok2, t2 = spin(stt, node_rep, wl, jnp.ones_like(wl),
+                               wdata.reshape(b * m, w))
+
+    # 4. bookkeeping: losers restart from phase 0 with released locks
+    adv2 = advance[:, None]
+    lines_n = jnp.where(adv2, nxt_lines, lines)
+    eph_n = jnp.where(adv2 & new, (phase + 1)[:, None], eph)
+    fail2 = failed[:, None]
+    ctr = {"iters": ctr["iters"] + 1,
+           "restarts": ctr["restarts"]
+           + jnp.sum(gather(failed).astype(jnp.int32)),
+           "phases": ctr["phases"] + jnp.sum(gather(ok).astype(jnp.int32)),
+           "s_grants": ctr["s_grants"] + gctr["s_grants"],
+           "x_grants": ctr["x_grants"] + gctr["x_grants"],
+           "rollbacks": ctr["rollbacks"] + jnp.sum(
+               gather(complete & ~commit).astype(jnp.int32))}
+    return (stt,
+            jnp.where(failed, 0, jnp.where(advance, phase + 1, phase)),
+            done | complete,
+            jnp.where(complete, commit, dec),
+            jnp.where(complete, it, estep),
+            retr + failed.astype(jnp.int32),
+            jnp.where(fail2, lines0, lines_n),
+            jnp.where(fail2, mode0, jnp.where(adv2, nxt_mode, mode)),
+            jnp.where(fail2, 0, eph_n),
+            jnp.where(fail2 | complete[:, None], False, held2),
+            lanes,
+            jnp.where(complete[:, None], fx_out, out),
+            it + 1, ok_spins & ok1 & ok2, rounds + r1 + r2,
+            spin_tele_add(tele, t1, t2), ctr)
+
+
+def _init_carry(program, state_l, batch, tele0, w):
+    node, typ, inp, lines0, mode0 = batch
+    b, m = lines0.shape
+    return (state_l, jnp.zeros(b, jnp.int32), typ < 0, jnp.zeros(b, bool),
+            jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32), lines0,
+            mode0, jnp.zeros((b, m), jnp.int32), jnp.zeros((b, m), bool),
+            jnp.zeros((b, m, w), jnp.int32),
+            jnp.zeros((b, program.outputs), jnp.int32), jnp.int32(0),
+            jnp.bool_(True), jnp.int32(0), tele0, _zero_counters())
+
+
+def _as_batch(node_id, typ, inp, lines, mode):
+    return (jnp.asarray(node_id, jnp.int32), jnp.asarray(typ, jnp.int32),
+            jnp.asarray(inp, jnp.int32), jnp.asarray(lines, jnp.int32),
+            jnp.asarray(mode, jnp.int32))
 
 
 # ------------------------------------------------------- the flat driver
 
 @functools.partial(jax.jit,
-                   static_argnames=("algo", "n_nodes", "max_rounds",
+                   static_argnames=("program", "n_nodes", "max_rounds",
                                     "max_iters", "backend"))
-def run_txn_rounds(state, node_id, glines, rmask, wmask, ts, *,
-                   algo: str, n_nodes: int, max_rounds: int = 64,
-                   max_iters: int = 64, backend: str = "ref"):
+def run_txn_rounds(state, node_id, typ, inp, lines, mode, *, program,
+                   n_nodes: int, max_rounds: int = 64, max_iters: int = 64,
+                   backend: str = "ref"):
     """Run a whole transaction batch to completion in ONE jit call.
 
-    Returns ``(state', decision[B], exec_step[B], retries[B], iters,
-    all_done, spins_ok, rounds, telemetry)`` — all device values.
-    ``decision`` is commit (True) / abort (False); ``exec_step`` the
-    iteration a txn completed at (its place in the serial order);
-    ``retries`` its no-wait restarts; ``spins_ok`` False means an inner
-    coherence spin hit ``max_rounds`` (results invalid — raise
-    host-side); ``telemetry`` is the flat counter dict summed over
-    every spin of the batch (``driver.zero_flat_tele`` keys)."""
+    Returns ``(state', decision[B], exec_step[B], retries[B], out[B, O],
+    lines[B, M], mode[B, M], all_done, spins_ok, rounds, telemetry,
+    counters)`` — all device values.  ``decision`` is commit (True) /
+    abort or rollback (False); ``exec_step`` the iteration a txn
+    completed at (its place in the serial order); ``retries`` its
+    no-wait restarts; ``lines``/``mode`` the entries each txn completed
+    with (every phase's); ``spins_ok`` False means an inner coherence
+    spin hit ``max_rounds`` (results invalid);
+    ``telemetry`` the flat counter dict summed over every spin;
+    ``counters`` the :data:`COUNTER_KEYS` program counters."""
     co.check_node_capacity(n_nodes)
-    node_id = jnp.asarray(node_id, jnp.int32)
-    glines = jnp.asarray(glines, jnp.int32)
-    rmask = jnp.asarray(rmask, jnp.int32)
-    wmask = jnp.asarray(wmask, jnp.int32)
-    ts = jnp.asarray(ts, jnp.int32)
-    B, G = glines.shape
-    T = rmask.shape[2]
+    batch = _as_batch(node_id, typ, inp, lines, mode)
+    B, M = batch[3].shape
     W = payload_width(state)
-    _note_trace(("txn", algo, B, G, T, n_nodes, max_rounds, max_iters,
-                 backend, "dirty" in state, W))
-    apply_fn = _APPLY[algo]
-    nv = jnp.sum((glines >= 0).astype(jnp.int32), axis=1)
-    slot = jnp.arange(B, dtype=jnp.int32)
-    node_rep = jnp.repeat(node_id, G)
-    g_idx = jnp.arange(G, dtype=jnp.int32)[None, :]
+    n_lines = state["words"].shape[0]
+    _note_trace(("txn", program.name, B, M, program.inputs, n_nodes,
+                 max_rounds, max_iters, backend, "dirty" in state, W))
 
-    def spin(stt, nodes, lines, is_write, wdata):
+    def raw_spin(stt, nodes, lns, is_write, wdata):
         stt, _, data, r, ok, tl = run_rounds(
-            stt, nodes, lines, is_write, wdata, n_nodes=n_nodes,
+            stt, nodes, lns, is_write, wdata, n_nodes=n_nodes,
             max_rounds=max_rounds, backend=backend)
         return stt, data, r, ok, tl
+    spin = _chunked(raw_spin, min(B * M, SPIN_SLOTS),
+                    zero_flat_tele(n_lines), add_tele)
 
     def cond(carry):
-        _, _, done, _, _, _, _, it, ok, _, _ = carry
-        return ~jnp.all(done) & (it < max_iters) & ok
+        return ~jnp.all(carry[2]) & (carry[12] < max_iters) & carry[13]
 
     def body(carry):
-        (stt, k, done, dec, estep, retr, lanes, it, ok, rounds,
-         tele) = carry
-        live = ~done
-        kc = jnp.minimum(k, G - 1)
-        has_next = live & (k < nv)
-        want = jnp.where(
-            has_next,
-            jnp.take_along_axis(glines, kc[:, None], axis=1)[:, 0], -1)
-        # dedup wanted lines: lowest slot presents, the rest retry
-        eq = (want[:, None] == want[None, :]) & (want[None, :] >= 0)
-        loser = jnp.any(eq & (slot[None, :] < slot[:, None]), axis=1)
-        winner = has_next & ~loser
-        # READ spin: lock word == 0 at read time means acquired
-        lines_r = jnp.where(winner, want, -1)
-        stt, rdata, r1, ok1, t1 = spin(stt, node_id, lines_r,
-                                       jnp.zeros_like(lines_r), None)
-        got = winner & (rdata[:, LOCK_LANE] == 0)
-        failed = has_next & ~got
-        # carry the freshly-read lanes at position k (immutable while
-        # the lock is held)
-        onehot = (g_idx == kc[:, None]) & got[:, None]
-        lanes = jnp.where(onehot[:, :, None], rdata[:, None, :], lanes)
-        # ACQUIRE spin: publish the lock word
-        wlock = rdata.at[:, LOCK_LANE].set(slot + 1)
-        lines_a = jnp.where(got, want, -1)
-        stt, _, r2, ok2, t2 = spin(stt, node_id, lines_a,
-                                   jnp.ones_like(lines_a), wlock)
-        k2 = k + got.astype(jnp.int32)
-        complete = live & (k2 >= nv)
-        decision_new, new_lanes = apply_fn(lanes, glines, rmask,
-                                           wmask, ts)
-        # FINALIZE spin: completers publish+release all lines, no-wait
-        # losers release their held prefix (lanes carried unchanged)
-        fin_c = complete[:, None] & (glines >= 0)
-        fin_f = failed[:, None] & (g_idx < k[:, None])
-        fdata = jnp.where(fin_c[:, :, None], new_lanes, lanes)
-        fdata = fdata.at[:, :, LOCK_LANE].set(0)
-        flines = jnp.where(fin_c | fin_f, glines, -1).reshape(B * G)
-        stt, _, r3, ok3, t3 = spin(stt, node_rep, flines,
-                                   jnp.ones_like(flines),
-                                   fdata.reshape(B * G, W))
-        return (stt, jnp.where(failed, 0, k2), done | complete,
-                jnp.where(complete, decision_new, dec),
-                jnp.where(complete, it, estep),
-                retr + failed.astype(jnp.int32), lanes, it + 1,
-                ok & ok1 & ok2 & ok3, rounds + r1 + r2 + r3,
-                add_tele(tele, add_tele(t1, add_tele(t2, t3))))
+        return _iteration(
+            program, n_lines, carry, batch, spin=spin,
+            gather=lambda x: x, mine=lambda x: x,
+            spin_tele_add=lambda t, a, b: add_tele(t, add_tele(a, b)))
 
-    init = (state, jnp.zeros(B, jnp.int32), nv < 0,
-            jnp.zeros(B, bool), jnp.zeros(B, jnp.int32),
-            jnp.zeros(B, jnp.int32), jnp.zeros((B, G, W), jnp.int32),
-            jnp.int32(0), jnp.bool_(True), jnp.int32(0),
-            zero_flat_tele(state["words"].shape[0]))
-    state, _, done, dec, estep, retr, _, it, ok, rounds, tele = \
-        jax.lax.while_loop(cond, body, init)
-    return (state, dec, estep, retr, it, jnp.all(done), ok, rounds,
-            tele)
+    out = jax.lax.while_loop(
+        cond, body, _init_carry(program, state, batch,
+                                zero_flat_tele(n_lines), W))
+    (state, _, done, dec, estep, retr, lns, mds, _, _, _, outs, _, ok,
+     rounds, tele, ctr) = out
+    return (state, dec, estep, retr, outs, lns, mds, jnp.all(done), ok,
+            rounds, tele, ctr)
 
 
 # ---------------------------------------------------- the sharded driver
 
 @functools.partial(
-    jax.jit, static_argnames=("algo", "mesh", "axis", "n_nodes",
+    jax.jit, static_argnames=("program", "mesh", "axis", "n_nodes",
                               "max_rounds", "max_iters", "bucket_cap",
                               "backend"))
-def run_txn_rounds_sharded(state, node_id, glines, rmask, wmask, ts, *,
-                           algo: str, mesh, axis: str = "shards",
+def run_txn_rounds_sharded(state, node_id, typ, inp, lines, mode, *,
+                           program, mesh, axis: str = "shards",
                            n_nodes: int, max_rounds: int = 64,
                            max_iters: int = 64,
                            bucket_cap: int | None = None,
                            backend: str = "ref"):
-    """Mesh mirror of :func:`run_txn_rounds`: the SAME scheduler inside
+    """Mesh mirror of :func:`run_txn_rounds`: the SAME iteration inside
     one ``shard_map``.  Txn slots are block-distributed over the mesh
-    (B divisible by the shard count; pad with ``glines = -1`` rows),
-    dedup goes through an ``all_gather`` of wanted lines in GLOBAL slot
-    order, every spin is the two-all_to_alls ``_route_round`` loop, and
-    liveness is a psum — the flat return contract plus a trailing
-    congestion-telemetry dict (same keys as
-    :func:`run_rounds_sharded`, summed over all three spins of every
-    scheduler iteration); decisions stay bit-identical."""
+    (B divisible by the shard count; pad with ``typ = -1`` rows), every
+    grant and write decision is computed from an ``all_gather`` of the
+    batch's entries in GLOBAL slot order, every spin is the
+    two-all_to_alls ``_route_round`` loop, and liveness is a psum — the
+    flat return contract, with the congestion-telemetry dict of
+    :func:`run_rounds_sharded`'s keys; decisions stay bit-identical."""
     co.check_node_capacity(n_nodes)
     n_shards = mesh.shape[axis]
-    node_id = jnp.asarray(node_id, jnp.int32)
-    glines = jnp.asarray(glines, jnp.int32)
-    rmask = jnp.asarray(rmask, jnp.int32)
-    wmask = jnp.asarray(wmask, jnp.int32)
-    ts = jnp.asarray(ts, jnp.int32)
-    B, G = glines.shape
-    T = rmask.shape[2]
+    batch = _as_batch(node_id, typ, inp, lines, mode)
+    B, M = batch[3].shape
     W = payload_width(state)
+    n_lines = state["words"].shape[0]
     if B % n_shards:
         raise ValueError(f"B={B} not divisible by n_shards={n_shards}")
     bl = B // n_shards
-    _note_trace(("txn_sharded", algo, n_shards, B, G, T, n_nodes,
-                 max_rounds, max_iters, bucket_cap, backend,
-                 "dirty" in state, W, "home" in state,
-                 "replica" in state))
-    apply_fn = _APPLY[algo]
+    _note_trace(("txn_sharded", program.name, n_shards, B, M,
+                 program.inputs, n_nodes, max_rounds, max_iters,
+                 bucket_cap, backend, "dirty" in state, W,
+                 "home" in state, "replica" in state))
     specs = _state_specs(state, axis)
-    l_local = state["words"].shape[0] // n_shards
-    g_idx = jnp.arange(G, dtype=jnp.int32)[None, :]
+    l_local = n_lines // n_shards
 
-    def spmd(state_l, node_l, glines_l, rmask_l, wmask_l, ts_l):
+    def spmd(state_l, *batch_l):
         ai = jax.lax.axis_index(axis)
-        gslot = ai * bl + jnp.arange(bl, dtype=jnp.int32)
-        nv = jnp.sum((glines_l >= 0).astype(jnp.int32), axis=1)
-        node_rep = jnp.repeat(node_l, G)
 
-        def spin(stt_l, nodes, lines, is_write, wdata):
+        def gather(x):
+            return jax.lax.all_gather(x, axis, tiled=True)
+
+        def mine(x):
+            return jax.lax.dynamic_slice_in_dim(x, ai * bl, bl)
+
+        def raw_spin(stt_l, nodes, lns, is_write, wdata):
             # run_rounds composed from _route_round INSIDE this spmd
             # (shard_map can't nest) — the run_rounds_sharded loop body
-            cap = (bucket_cap if bucket_cap is not None
-                   else lines.shape[0])
+            cap = bucket_cap if bucket_cap is not None else lns.shape[0]
+            if wdata is None:
+                wdata = jnp.zeros((lns.shape[0], W), jnp.int32)
 
             def n_pending(p):
                 return jax.lax.psum(
                     jnp.sum((p >= 0).astype(jnp.int32)), axis)
 
             def s_cond(c):
-                _, _, _, r, _, done = c
-                return ~done & (r < max_rounds)
+                return ~c[5] & (c[3] < max_rounds)
 
             def s_body(c):
                 stt, pending, data, r, tele, _ = c
@@ -318,95 +571,52 @@ def run_txn_rounds_sharded(state, node_id, glines, rmask, wmask, ts, *,
                 return (stt, pending, data, r + 1,
                         _add_tele(tele, dtele), n_pending(pending) == 0)
 
-            init = (stt_l, lines,
-                    jnp.zeros((lines.shape[0], W), jnp.int32),
+            init = (stt_l, lns, jnp.zeros((lns.shape[0], W), jnp.int32),
                     jnp.int32(0), _zero_tele(n_shards, l_local),
-                    n_pending(lines) == 0)
-            stt_l, pending, data, r, tele, done = jax.lax.while_loop(
+                    n_pending(lns) == 0)
+            stt_l, _, data, r, tele, done = jax.lax.while_loop(
                 s_cond, s_body, init)
             return stt_l, data, r, done, tele
+        spin = _chunked(raw_spin, min(bl * M, SPIN_SLOTS),
+                        _zero_tele(n_shards, l_local), _add_tele,
+                        total=lambda x: jax.lax.pmax(x, axis))
 
         def n_live(done):
-            return jax.lax.psum(
-                jnp.sum((~done).astype(jnp.int32)), axis)
+            return jax.lax.psum(jnp.sum((~done).astype(jnp.int32)), axis)
 
-        def cond(carry):
-            _, _, _, _, _, _, _, it, ok, _, _, alldone = carry
-            return ~alldone & (it < max_iters) & ok
+        def cond(c):
+            carry, alldone = c
+            return ~alldone & (carry[12] < max_iters) & carry[13]
 
-        def body(carry):
-            (stt, k, done, dec, estep, retr, lanes, it, ok, rounds,
-             tele, _) = carry
-            live = ~done
-            kc = jnp.minimum(k, G - 1)
-            has_next = live & (k < nv)
-            want = jnp.where(
-                has_next,
-                jnp.take_along_axis(glines_l, kc[:, None],
-                                    axis=1)[:, 0], -1)
-            # global dedup in GLOBAL slot order (block distribution
-            # keeps gathered order == slot order)
-            want_g = jax.lax.all_gather(want, axis).reshape(B)
-            eq = (want_g[:, None] == want_g[None, :]) \
-                & (want_g[None, :] >= 0)
-            sg = jnp.arange(B, dtype=jnp.int32)
-            loser_g = jnp.any(eq & (sg[None, :] < sg[:, None]), axis=1)
-            loser = jax.lax.dynamic_slice_in_dim(loser_g, ai * bl, bl)
-            winner = has_next & ~loser
-            lines_r = jnp.where(winner, want, -1)
-            stt, rdata, r1, ok1, t1 = spin(
-                stt, node_l, lines_r, jnp.zeros_like(lines_r),
-                jnp.zeros((bl, W), jnp.int32))
-            got = winner & (rdata[:, LOCK_LANE] == 0)
-            failed = has_next & ~got
-            onehot = (g_idx == kc[:, None]) & got[:, None]
-            lanes = jnp.where(onehot[:, :, None], rdata[:, None, :],
-                              lanes)
-            wlock = rdata.at[:, LOCK_LANE].set(gslot + 1)
-            lines_a = jnp.where(got, want, -1)
-            stt, _, r2, ok2, t2 = spin(stt, node_l, lines_a,
-                                       jnp.ones_like(lines_a), wlock)
-            k2 = k + got.astype(jnp.int32)
-            complete = live & (k2 >= nv)
-            decision_new, new_lanes = apply_fn(lanes, glines_l,
-                                               rmask_l, wmask_l, ts_l)
-            fin_c = complete[:, None] & (glines_l >= 0)
-            fin_f = failed[:, None] & (g_idx < k[:, None])
-            fdata = jnp.where(fin_c[:, :, None], new_lanes, lanes)
-            fdata = fdata.at[:, :, LOCK_LANE].set(0)
-            flines = jnp.where(fin_c | fin_f, glines_l,
-                               -1).reshape(bl * G)
-            stt, _, r3, ok3, t3 = spin(stt, node_rep, flines,
-                                       jnp.ones_like(flines),
-                                       fdata.reshape(bl * G, W))
-            done2 = done | complete
-            return (stt, jnp.where(failed, 0, k2), done2,
-                    jnp.where(complete, decision_new, dec),
-                    jnp.where(complete, it, estep),
-                    retr + failed.astype(jnp.int32), lanes, it + 1,
-                    ok & ok1 & ok2 & ok3, rounds + r1 + r2 + r3,
-                    _add_tele(_add_tele(tele, _add_tele(t1, t2)), t3),
-                    n_live(done2) == 0)
+        def body(c):
+            carry = _iteration(
+                program, n_lines, c[0], batch_l, spin=spin,
+                gather=gather, mine=mine,
+                spin_tele_add=lambda t, a, b: _add_tele(_add_tele(t, a),
+                                                        b))
+            return carry, n_live(carry[2]) == 0
 
-        init = (state_l, jnp.zeros(bl, jnp.int32), nv < 0,
-                jnp.zeros(bl, bool), jnp.zeros(bl, jnp.int32),
-                jnp.zeros(bl, jnp.int32),
-                jnp.zeros((bl, G, W), jnp.int32), jnp.int32(0),
-                jnp.bool_(True), jnp.int32(0),
-                _zero_tele(n_shards, l_local), n_live(nv < 0) == 0)
-        (state_l, _, done, dec, estep, retr, _, it, ok, rounds,
-         tele, alldone) = jax.lax.while_loop(cond, body, init)
-        return (state_l, dec, estep, retr, it, alldone, ok,
-                rounds) + _tele_rows(tele)
+        init = _init_carry(program, state_l, batch_l,
+                           _zero_tele(n_shards, l_local), W)
+        carry, alldone = jax.lax.while_loop(
+            cond, body, (init, n_live(init[2]) == 0))
+        (state_l, _, _, dec, estep, retr, lns, mds, _, _, _, outs, _, ok,
+         rounds, tele, ctr) = carry
+        return ((state_l, dec, estep, retr, outs, lns, mds, alldone, ok,
+                 rounds)
+                + _tele_rows(tele) + tuple(ctr[k] for k in COUNTER_KEYS))
 
+    nk = len(COUNTER_KEYS)
     out = jax.shard_map(
         spmd, mesh=auto_mesh(mesh),
-        in_specs=(specs, P(axis), P(axis), P(axis), P(axis), P(axis)),
-        out_specs=(specs, P(axis), P(axis), P(axis), P(), P(), P(),
-                   P()) + _tele_specs(axis),
+        in_specs=(specs,) + (P(axis),) * 5,
+        out_specs=(specs,) + (P(axis),) * 6 + (P(), P(), P())
+        + _tele_specs(axis) + (P(),) * nk,
         check_vma=False,
-    )(state, node_id, glines, rmask, wmask, ts)
-    return out[:8] + (dict(zip(TELE_KEYS, out[8:])),)
+    )(state, *batch)
+    nt = len(TELE_KEYS)
+    return (out[:10] + (dict(zip(TELE_KEYS, out[10:10 + nt])),)
+            + (dict(zip(COUNTER_KEYS, out[10 + nt:])),))
 
 
 # ------------------------------------------------------ host-facing API
@@ -415,15 +625,19 @@ def run_txn_rounds_sharded(state, node_id, glines, rmask, wmask, ts, *,
 class TxnBatchResult:
     """Host-side result of one fused txn batch.
 
-    ``decision`` bool [B] (commit/abort), ``exec_step`` int [B] (the
-    scheduler iteration each txn completed at — its position in the
-    serial order), ``retries`` int [B] (no-wait restarts), ``iters``
-    total scheduler iterations, ``rounds`` total coherence rounds
-    across all spins.  ``telemetry`` is the unified
-    :class:`~repro.obs.PlaneTelemetry` record summed over every spin
-    of the batch — populated on flat AND sharded planes (the host
-    reference :func:`run_txn_batch_host` leaves it None; its per-phase
-    ``plane.ops`` dispatches each carry their own)."""
+    ``decision`` bool [B] (commit / abort or rollback), ``exec_step``
+    int [B] (the scheduler iteration each txn completed at — its
+    position in the serial order), ``retries`` int [B] (no-wait
+    restarts), ``outputs`` int [B, O] (what each transaction's program
+    returned), ``lines`` / ``modes`` int [B, M] (the entries each txn
+    completed with, every phase's), ``iters`` scheduler iterations,
+    ``rounds`` coherence rounds across all spins, ``counters`` the :data:`COUNTER_KEYS`
+    program counters (``restarts``, ``phases`` granted, ``s_grants``,
+    ``x_grants``, ``rollbacks``) plus ``attempts`` (transactions started,
+    restarts included).  ``telemetry`` is the
+    :class:`~repro.obs.PlaneTelemetry` record summed over every spin;
+    ``host_s`` the host seconds of the verb's child spans (``pad``,
+    ``dispatch``, ``sync``, ``telemetry``, ``results``)."""
 
     decision: np.ndarray
     exec_step: np.ndarray
@@ -431,188 +645,84 @@ class TxnBatchResult:
     iters: int
     rounds: int
     telemetry: "PlaneTelemetry | None" = None
+    outputs: np.ndarray | None = None
+    lines: np.ndarray | None = None
+    modes: np.ndarray | None = None
+    counters: dict = field(default_factory=dict)
+    host_s: dict = field(default_factory=dict)
 
 
-def run_txn_batch(plane, node_id, glines, rmask, wmask, ts, *,
-                  algo: str, max_iters: int | None = None,
-                  max_rounds: int | None = None) -> TxnBatchResult:
+def resolve_program(algo, glines=None, rmask=None) -> TxnProgram:
+    """``algo`` as a program: a :class:`TxnProgram` as it is, ``"2pl"``
+    or ``"to"`` the tuple program of the batch's ``[B, G]`` lines and
+    ``[B, G, T]`` masks."""
+    if isinstance(algo, TxnProgram):
+        return algo
+    return tuple_program(algo, int(np.shape(glines)[1]),
+                         int(np.shape(rmask)[2]))
+
+
+def run_txn_batch(plane, node_id, *batch, algo, max_iters=None,
+                  max_rounds=None, span=None) -> TxnBatchResult:
     """Drive one txn batch through ``plane`` (DevicePlane, flat or
-    sharded) and normalize the result; the canonical-order contract
-    (each row of ``glines`` sorted ascending, ``-1`` pads at the end)
-    is validated here, where it's cheap."""
-    if algo not in _APPLY:
-        raise ValueError(f"unknown txn algo {algo!r} "
-                         f"(have {sorted(_APPLY)})")
-    glines = np.asarray(glines, np.int32)
-    node_id = np.asarray(node_id, np.int32)
-    rmask = np.asarray(rmask, np.int32)
-    wmask = np.asarray(wmask, np.int32)
-    ts = np.asarray(ts, np.int32)
-    B, G = glines.shape
-    T = rmask.shape[2]
-    need = txn_payload_width(T)
-    if plane.payload_width != need:
-        raise ValueError(
-            f"plane payload_width={plane.payload_width} but "
-            f"T={T} tuple headers need {need} lanes")
-    valid = glines >= 0
-    if (valid[:, 1:] & ~valid[:, :-1]).any():
-        raise ValueError("glines pads (-1) must trail the valid lines")
-    both = valid[:, 1:] & valid[:, :-1]
-    if (both & (glines[:, 1:] <= glines[:, :-1])).any():
-        raise ValueError("glines must be sorted strictly ascending "
-                         "per txn (canonical latch order)")
-    mr = plane.max_rounds if max_rounds is None else max_rounds
-    mi = 4 * B + 16 if max_iters is None else max_iters
-    if plane.sharded:
-        pad = (-B) % plane.n_shards
-        if pad:
-            node_id = np.concatenate([node_id,
-                                      np.zeros(pad, np.int32)])
-            glines = np.concatenate(
-                [glines, np.full((pad, G), -1, np.int32)])
-            rmask = np.concatenate(
-                [rmask, np.zeros((pad, G, T), np.int32)])
-            wmask = np.concatenate(
-                [wmask, np.zeros((pad, G, T), np.int32)])
-            ts = np.concatenate([ts, np.zeros(pad, np.int32)])
-        state, dec, estep, retr, it, alldone, ok, rounds, tele = \
-            run_txn_rounds_sharded(
-                plane.state, node_id, glines, rmask, wmask, ts,
-                algo=algo, mesh=plane.mesh, axis=plane.axis,
+    sharded): ``batch`` is what the program's ``encode`` takes (for
+    ``"2pl"``/``"to"``: ``glines, rmask, wmask, ts``).  ``span`` is the
+    verb's bracket (its child phases are timed into ``host_s``)."""
+    from .plane import _Span
+    sp = _Span() if span is None else span
+    with sp.phase("pad"):
+        program = resolve_program(algo, *batch[:2])
+        typ, inp, lines, mode = program.encode(*batch)
+        if plane.payload_width != program.width:
+            raise ValueError(
+                f"plane payload_width={plane.payload_width} but program "
+                f"{program.name!r} needs {program.width} lanes")
+        B, M = lines.shape
+        node_id = np.broadcast_to(np.asarray(node_id, np.int32),
+                                  (B,)).astype(np.int32)
+        mr = plane.max_rounds if max_rounds is None else max_rounds
+        mi = 4 * B + 16 if max_iters is None else max_iters
+        if plane.sharded:
+            pad = (-B) % plane.n_shards
+            if pad:
+                node_id = np.concatenate([node_id, np.zeros(pad, np.int32)])
+                typ = np.concatenate([typ, np.full(pad, -1, np.int32)])
+                inp = np.concatenate([inp, np.zeros((pad,) + inp.shape[1:],
+                                                    np.int32)])
+                lines = np.concatenate([lines, np.full((pad, M), -1,
+                                                       np.int32)])
+                mode = np.concatenate([mode, np.zeros((pad, M), np.int32)])
+    with sp.phase("dispatch"):
+        if plane.sharded:
+            out = run_txn_rounds_sharded(
+                plane.state, node_id, typ, inp, lines, mode,
+                program=program, mesh=plane.mesh, axis=plane.axis,
                 n_nodes=plane.n_nodes, max_rounds=mr, max_iters=mi,
                 bucket_cap=plane.bucket_cap, backend=plane.backend)
-    else:
-        state, dec, estep, retr, it, alldone, ok, rounds, tele = \
-            run_txn_rounds(
-                plane.state, node_id, glines, rmask, wmask, ts,
-                algo=algo, n_nodes=plane.n_nodes, max_rounds=mr,
+        else:
+            out = run_txn_rounds(
+                plane.state, node_id, typ, inp, lines, mode,
+                program=program, n_nodes=plane.n_nodes, max_rounds=mr,
                 max_iters=mi, backend=plane.backend)
-    telemetry = plane._telemetry(tele)
-    if not bool(ok):
-        raise RuntimeError(
-            f"txn coherence spin hit max_rounds={mr}")
-    if not bool(alldone):
+    (state, dec, estep, retr, outs, lns, mds, alldone, ok, rounds, tele,
+     ctr) = out
+    with sp.phase("sync"):
+        spins_ok, all_done = bool(ok), bool(alldone)
+    if not spins_ok:
+        raise RuntimeError(f"txn coherence spin hit max_rounds={mr}")
+    if not all_done:
         raise RuntimeError(
             f"txn batch not done after {mi} scheduler iterations "
             f"(livelock? raise max_iters)")
     plane.state = state
-    return TxnBatchResult(np.asarray(dec)[:B], np.asarray(estep)[:B],
-                          np.asarray(retr)[:B], int(it), int(rounds),
-                          telemetry)
-
-
-def _apply_host_one(algo, lanes, glines, rmask, wmask, ts):
-    """Python mirror of ``_APPLY[algo]`` for ONE txn's carried lanes —
-    the host-driven reference scheduler applies per completing txn."""
-    G, W = lanes.shape
-    T = (W - HDR_LANES) // 2
-    new = lanes.copy()
-    if algo == "2pl":
-        for g in range(G):
-            if glines[g] >= 0 and wmask[g].any():
-                new[g, WRITES_LANE] += 1
-        return True, new
-    for g in range(G):
-        if glines[g] < 0:
-            continue
-        for t in range(T):
-            r, w = bool(rmask[g, t]), bool(wmask[g, t])
-            if not (r or w):
-                continue
-            rts = new[g, HDR_LANES + 2 * t]
-            wts = new[g, HDR_LANES + 2 * t + 1]
-            if w:
-                if ts < rts or ts < wts:
-                    return False, new
-                new[g, HDR_LANES + 2 * t + 1] = ts
-            else:
-                if ts < wts:
-                    return False, new
-                new[g, HDR_LANES + 2 * t] = max(rts, ts)
-    return True, new
-
-
-def run_txn_batch_host(plane, node_id, glines, rmask, wmask, ts, *,
-                       algo: str,
-                       max_iters: int | None = None) -> TxnBatchResult:
-    """The PRE-FUSE reference: the same txn scheduler, driven from the
-    host — one ``plane.ops`` dispatch (with a host sync) per phase per
-    iteration, dedup/apply/bookkeeping in numpy between dispatches.
-    Bit-identical decisions, exec order, retries and memory image to
-    :func:`run_txn_batch`; exists as the fused loop's differential
-    oracle on the device plane and as the ``txn_fused_speedup``
-    baseline in benchmarks/fig11_tpcc_rounds.py (the fig10 ``host``
-    driver, for transactions)."""
-    if algo not in _APPLY:
-        raise ValueError(f"unknown txn algo {algo!r}")
-    glines = np.asarray(glines, np.int32)
-    rmask = np.asarray(rmask, np.int32)
-    wmask = np.asarray(wmask, np.int32)
-    ts = np.asarray(ts, np.int32)
-    B, G = glines.shape
-    W = plane.payload_width
-    node_id = np.broadcast_to(np.asarray(node_id, np.int32),
-                              (B,)).astype(np.int32)
-    nv = (glines >= 0).sum(axis=1)
-    mi = 4 * B + 16 if max_iters is None else max_iters
-    g_idx = np.arange(G)
-    k = np.zeros(B, np.int64)
-    done = nv == 0
-    dec = np.zeros(B, bool)
-    estep = np.zeros(B, np.int64)
-    retr = np.zeros(B, np.int64)
-    lanes = np.zeros((B, G, W), np.int32)
-    rounds = it = 0
-    while not done.all():
-        if it >= mi:
-            raise RuntimeError(
-                f"txn batch not done after {mi} scheduler iterations "
-                f"(livelock? raise max_iters)")
-        live = ~done
-        kc = np.minimum(k, G - 1)
-        has_next = live & (k < nv)
-        want = np.where(has_next, glines[np.arange(B), kc], -1)
-        winner = np.zeros(B, bool)
-        seen: set = set()
-        for i in range(B):              # lowest slot wins, like device
-            if want[i] >= 0 and want[i] not in seen:
-                seen.add(int(want[i]))
-                winner[i] = True
-        res = plane.ops(node_id,
-                        np.where(winner, want, -1).astype(np.int32),
-                        np.zeros(B, np.int32))
-        rounds += res.rounds
-        rdata = np.asarray(res.data)
-        got = winner & (rdata[:, LOCK_LANE] == 0)
-        failed = has_next & ~got
-        lanes[got, kc[got]] = rdata[got]
-        wlock = rdata.copy()
-        wlock[:, LOCK_LANE] = np.arange(B) + 1
-        res = plane.ops(node_id,
-                        np.where(got, want, -1).astype(np.int32),
-                        np.ones(B, np.int32), wlock)
-        rounds += res.rounds
-        k2 = k + got
-        complete = live & (k2 >= nv)
-        fdata = lanes.copy()
-        for i in np.flatnonzero(complete):
-            dec[i], fdata[i] = _apply_host_one(
-                algo, lanes[i], glines[i], rmask[i], wmask[i],
-                int(ts[i]))
-        fdata[:, :, LOCK_LANE] = 0
-        fin = (complete[:, None] & (glines >= 0)) \
-            | (failed[:, None] & (g_idx[None, :] < k[:, None]))
-        res = plane.ops(np.repeat(node_id, G),
-                        np.where(fin, glines, -1).reshape(B * G)
-                        .astype(np.int32),
-                        np.ones(B * G, np.int32),
-                        fdata.reshape(B * G, W))
-        rounds += res.rounds
-        estep[complete] = it
-        done = done | complete
-        retr += failed
-        k = np.where(failed, 0, k2)
-        it += 1
-    return TxnBatchResult(dec, estep.astype(np.int64),
-                          retr.astype(np.int64), it, int(rounds))
+    with sp.phase("telemetry"):
+        telemetry = plane._telemetry(tele)
+    with sp.phase("results"):
+        counters = {k: int(v) for k, v in jax.device_get(ctr).items()}
+        counters["attempts"] = B + counters["restarts"]
+        res = TxnBatchResult(
+            np.asarray(dec)[:B], np.asarray(estep)[:B],
+            np.asarray(retr)[:B], counters["iters"], int(rounds),
+            telemetry, np.asarray(outs)[:B], np.asarray(lns)[:B],
+            np.asarray(mds)[:B], counters, sp.host_s)
+    return res

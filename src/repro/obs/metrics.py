@@ -12,7 +12,7 @@ Design points:
   keeps p50/p99 within a few percent of ``numpy.percentile`` on the
   full sample, which the unit tests assert on a fixed draw).  This is
   what replaces the hand-rolled sorted-sample percentiles in
-  ``benchmarks/bench_serving.py`` / ``fig11_tpcc_rounds.py`` and the
+  ``benchmarks/bench_serving.py`` and the
   unbounded ``TxnStats.latencies`` list.
 * :class:`EwmaHeat` is the per-line/per-home exponential moving average
   the placement policies consume (``heat = (1-a)*heat + a*counts`` per

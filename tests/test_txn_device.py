@@ -135,45 +135,21 @@ def test_txn_stats_reasons_and_percentiles():
 
 
 def test_encode_txns_trim_policy():
+    """No silent trim: a txn over more GCLs than the batch's static
+    width is refused; one that fits comes back whole."""
     cfg = DeviceTxnConfig(tuples_per_gcl=4, max_group_lines=2)
-    # 3 write gcls (0, 2, 5) + read gcl 7: writes win, lowest first
-    glines, rmask, wmask, eff = encode_txns(
-        [([28, 1], [0, 8, 20, 1])], cfg)
-    assert glines.tolist() == [[0, 2]]
-    eff_r, eff_w = eff[0]
-    assert eff_w == [0, 1, 8] and eff_r == [1]      # gcl 5, 7 trimmed
-    assert wmask[0, 0].tolist() == [1, 1, 0, 0]     # tuples 0, 1
-    assert wmask[0, 1].tolist() == [1, 0, 0, 0]     # tuple 8
-    assert rmask.sum() == 0   # read 1 is in the write set: wmask wins
-    # untrimmed txn: read/write masks disjoint, reads kept
+    # 3 write gcls (0, 2, 5) + read gcl 7: four lines, two slots
+    with pytest.raises(ValueError, match="max_group_lines"):
+        encode_txns([([28, 1], [0, 8, 20, 1])], cfg)
     glines, rmask, wmask, eff = encode_txns([([4, 5], [9])], cfg)
     assert glines.tolist() == [[1, 2]]
     assert eff[0] == ([4, 5], [9])
     assert rmask[0, 0].tolist() == [1, 1, 0, 0]
     assert wmask[0, 1].tolist() == [0, 1, 0, 0]
-
-
-def test_host_driven_scheduler_matches_fused():
-    """``run_txn_batch_host`` (the pre-fuse benchmark baseline) IS the
-    fused loop driven from the host: bit-identical result fields and
-    final plane state, both algos."""
-    for algo in ("2pl", "to"):
-        fused = _device_engine(algo)
-        host = _device_engine(algo)
-        txns, node, ts = device_txn_batches(CFG, seed=5)[0]
-        rf, _ = fused.run_batch(node, txns, ts=ts)
-        glines, rmask, wmask, _ = encode_txns(txns, host.cfg)
-        rh = rp.run_txn_batch_host(host.plane, node, glines, rmask,
-                                   wmask, np.asarray(ts, np.int32),
-                                   algo=algo)
-        for fld in ("decision", "exec_step", "retries"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(rf, fld)),
-                np.asarray(getattr(rh, fld)), err_msg=f"{algo}:{fld}")
-        for k, v in fused.plane.state.items():
-            np.testing.assert_array_equal(
-                np.asarray(v), np.asarray(host.plane.state[k]),
-                err_msg=f"{algo}:{k}")
+    # a read of a tuple the txn also writes: wmask wins
+    glines, rmask, wmask, eff = encode_txns([([1], [0, 1])], cfg)
+    assert glines.tolist() == [[0, -1]]
+    assert wmask[0, 0].tolist() == [1, 1, 0, 0] and rmask.sum() == 0
 
 
 def test_txn_loop_compiles_once_per_shape():
