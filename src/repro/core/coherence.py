@@ -197,8 +197,9 @@ def directory_from_state(cache_state):
     """Rebuild the per-line latch words from MSI cache states [N, L]:
     writer byte from the (unique) M holder, reader bits from S holders.
 
-    The round engine calls this at every round boundary, so the word and
-    the cache-state array cannot drift — the construction IS the paper's
+    The round engine calls this at every round boundary on the [N, k]
+    columns of the lines the round named, so the word and the
+    cache-state array cannot drift — the construction IS the paper's
     'the latch word is the directory' invariant.  Summation is exact
     because each node contributes one distinct bit."""
     import jax.numpy as jnp

@@ -25,9 +25,12 @@ reshaped into deterministic ROUNDS (DESIGN.md Sec. 2).  One round:
      boundary transitions follow coherence.MSI_ON_PEER — the same table
      the DES handlers consume.
 
-After the boundary the latch words are REBUILT from the cache states
-(`coherence.directory_from_state`), so word and directory cannot drift
-and failed readers' transient bits vanish without a second kernel pass.
+The boundary works only on the lines the round's slots named: every
+invalidation, downgrade and flush lands on such a line, and the latch
+phase changes cache states nowhere else.  It then REBUILDS those lines'
+latch words from their cache states (`coherence.directory_from_state`),
+so word and directory cannot drift and failed readers' transient bits
+vanish without a second kernel pass.
 
 Data plane: write-through by default (memory version current once the
 latch moves).  A state built with ``make_state(..., write_back=True)``
@@ -237,47 +240,62 @@ def _round_impl(state, node_id, line, is_write, wdata=None, *,
                     gdata, mode="drop")
 
     # ------------- 3/4. round-boundary invalidations ----------------------
+    # Runs on the [N, R] columns of the lines the round named (module
+    # docstring) and scatters them back.  Slots naming one line compute
+    # the same column, since every aggregate below is per line, so
+    # duplicate scatters write equal values; invalid slots go to line
+    # n_lines and are dropped.
     with jax.named_scope("round/boundary"):
         fail_w = jnp.logical_and(jnp.logical_or(upgrade, fresh_w), ~ok)
         fail_r = jnp.logical_and(read_miss, ~no_writer)
-        wr_cnt = jnp.zeros((n_lines,), jnp.int32).at[
-            jnp.where(fail_w, idx, n_lines)].add(1, mode="drop")
-        rd_fail = jnp.zeros((n_lines,), bool).at[
-            jnp.where(fail_r, idx, n_lines)].set(True, mode="drop")
-        self_wr_fail = jnp.zeros((n_nodes, n_lines), jnp.int32).at[
-            jnp.where(fail_w, node_id, n_nodes), idx].set(1, mode="drop")
+        same_line = jnp.logical_and(
+            idx[:, None] == idx[None, :],
+            jnp.logical_and(valid[:, None], valid[None, :]))
+        peer_w = jnp.logical_and(same_line, fail_w[None, :])
+        wr_cnt = jnp.sum(peer_w, axis=1).astype(jnp.int32)
+        rd_fail = jnp.any(jnp.logical_and(same_line, fail_r[None, :]),
+                          axis=1)
+        # a node fails at most one write per line (one representative per
+        # (node, line)), so one failed writer's id tells each node whether
+        # its own write is among wr_cnt
+        w_node = jnp.max(jnp.where(peer_w, node_id[None, :], -1), axis=1)
+        nodes = jnp.arange(n_nodes, dtype=w_node.dtype)[:, None]
         # PeerWr/PeerUpgr from any OTHER node kills a holder (upgraders
         # never kill themselves; two racing upgraders kill each other and
         # fall back to fresh acquisition — Algorithm 2's release+reacquire)
-        other_fail = (wr_cnt[None, :] - self_wr_fail) > 0
-        holder = cstate >= S
-        kill = jnp.logical_and(other_fail, holder)
+        other_fail = (wr_cnt[None, :]
+                      - (nodes == w_node[None, :]).astype(jnp.int32)) > 0
+        col = jnp.where(valid, idx, n_lines)
+        cs = cstate[:, idx]                                    # [N, R]
+        kill = jnp.logical_and(other_fail, cs >= S)
         # PeerRd with no competing writer: the M holder downgrades
-        m_mask = cstate == M
+        m_mask = cs == M
         dg_line = jnp.logical_and(jnp.logical_and(rd_fail, wr_cnt == 0),
                                   jnp.any(m_mask, axis=0))
         dg_mask = jnp.logical_and(dg_line[None, :], m_mask)
         if write_back:
-            # a dirty M holder leaving M (killed or downgraded) writes back
-            flush = jnp.logical_and(jnp.logical_or(kill, dg_mask),
-                                    jnp.logical_and(m_mask, dirty))
-            flush_ver = jnp.max(jnp.where(flush, cver, 0), axis=0)
-            mver = jnp.where(jnp.any(flush, axis=0), flush_ver, mver)
+            # a dirty M holder leaving M (killed or downgraded) writes
+            # back its version and, on a payload plane, its bytes: its
+            # cache_data row is the flush source of truth (at most one M
+            # holder per line)
+            leave = jnp.logical_or(kill, dg_mask)
+            dcol = dirty[:, idx]
+            flush = jnp.logical_and(leave, jnp.logical_and(m_mask, dcol))
+            flushed = jnp.where(jnp.any(flush, axis=0), col, n_lines)
+            holder = jnp.argmax(flush, axis=0)
+            mver = mver.at[flushed].set(cver[holder, idx], mode="drop")
             if width:
-                # dirty-flush-with-bytes: the holder's cache_data IS the
-                # flush source of truth (at most one M holder per line, so
-                # the masked sum selects exactly its row)
-                flush_data = jnp.sum(
-                    jnp.where(flush[:, :, None], cdata, 0), axis=0)
-                mdata = jnp.where(jnp.any(flush, axis=0)[:, None],
-                                  flush_data, mdata)
-            dirty = jnp.logical_and(dirty, ~jnp.logical_or(kill, dg_mask))
-        cstate = jnp.where(kill, jnp.int8(I), cstate)
-        cstate = jnp.where(dg_mask, jnp.int8(S), cstate)
-        # the word IS the directory: rebuild it from the post-boundary
-        # states (also clears failed readers' transient bits without a
-        # second pass)
-        words = co.directory_from_state(cstate)
+                mdata = mdata.at[flushed].set(cdata[holder, idx],
+                                              mode="drop")
+            dirty = dirty.at[:, col].set(jnp.logical_and(dcol, ~leave),
+                                         mode="drop")
+        cs = jnp.where(kill, jnp.int8(I), cs)
+        cs = jnp.where(dg_mask, jnp.int8(S), cs)
+        cstate = cstate.at[:, col].set(cs, mode="drop")
+        # the word IS the directory: rebuild the named lines' words from
+        # their post-boundary states (also clears failed readers'
+        # transient bits without a second pass)
+        words = words.at[col].set(co.directory_from_state(cs), mode="drop")
 
     # ------------- per-slot replies (coalesced groups fan back out) -------
     with jax.named_scope("round/reply"):

@@ -32,10 +32,10 @@ per-(node, line) coalescing, eviction — across a ``shard_map`` mesh:
   (sharded state, pending lines, versions, a psum'd done flag) never
   leaves the devices — zero host<->device syncs per round, and
   ``engine.TRACE_COUNTS`` proves one trace per shape;
-* at every round boundary each home rebuilds its latch-word slab from
-  its local MSI states (``coherence.directory_from_state`` inside
-  ``_round_impl``), so the PR-2 word<->directory invariant holds PER
-  SHARD by construction;
+* at every round boundary each home rebuilds the latch words of the
+  lines its round named from their local MSI states
+  (``coherence.directory_from_state`` inside ``_round_impl``), so the
+  word<->directory invariant holds PER SHARD by construction;
 * a request that overflows its (source, home) bucket — ``bucket_cap``
   models the NIC queue depth; the default ``cap = r`` can never
   overflow — is NOT dropped and NOT punted to the caller: it stays

@@ -2,6 +2,7 @@
 the fused spin loop (trace-count proof: no per-round retrace), eviction
 write-back, and the capacity guards — under both latch backends."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +10,7 @@ import pytest
 from repro.core import coherence as co
 from repro.core import rounds as rp
 from repro.core.rounds import engine
+from repro.kernels.latch_ops.ops import OP_CAS, OP_FAA, apply_batch
 
 
 def _ops(node, line, isw):
@@ -437,3 +439,260 @@ def test_run_rmw_atomic_against_outside_holders():
         np.zeros(1, np.int32), np.zeros((1, 2), np.int32), n_nodes=4)
     assert d[0].tolist() == [7, 7]
     rp.check_invariants(state)
+
+
+# ------------------------------------ sparse boundary vs dense reference
+
+def _dense_round(state, node_id, line, is_write, wdata, *, n_nodes):
+    """Reference round: the engine's round body with its boundary as a
+    DENSE pass over every line ([N, L] masks, an [N, L, W] flush sum,
+    the whole directory rebuilt).  The engine must match it bit for
+    bit while touching only the lines the round named."""
+    write_back = "dirty" in state
+    words = state["words"]
+    cstate = state["cache_state"]
+    cver = state["cache_version"]
+    mver = state["mem_version"]
+    dirty = state.get("dirty")
+    mdata = state.get("mem_data")
+    cdata = state.get("cache_data")
+    width = mdata.shape[1] if mdata is not None else 0
+    n_lines = words.shape[0]
+    r = line.shape[0]
+    if wdata is None:
+        wdata = jnp.zeros((r, width), jnp.int32)
+    I, S, M = co.I, co.S, co.M
+    # coalesce
+    valid = line >= 0
+    idx = jnp.maximum(line, 0)
+    is_w = jnp.logical_and(is_write.astype(bool), valid)
+    key = node_id * n_lines + idx
+    eq = jnp.logical_and(key[:, None] == key[None, :],
+                         jnp.logical_and(valid[:, None], valid[None, :]))
+    first = jnp.argmax(eq, axis=1)
+    is_rep = jnp.logical_and(valid, first == jnp.arange(r))
+    grp_write = jnp.any(jnp.logical_and(eq, is_w[None, :]), axis=1)
+    lower = jnp.tril(jnp.ones((r, r), bool), k=-1)
+    in_grp_w = jnp.logical_and(eq, is_w[None, :])
+    w_rank = jnp.sum(jnp.logical_and(in_grp_w, lower), axis=1) \
+        .astype(jnp.int32)
+    n_w_grp = jnp.sum(in_grp_w, axis=1).astype(jnp.int32)
+    last_w = jnp.maximum(
+        jnp.max(jnp.where(in_grp_w, jnp.arange(r), -1), axis=1), 0)
+    # latch
+    st = cstate[node_id, idx]
+    hit_read = jnp.logical_and(~grp_write, st >= S)
+    hit_write = jnp.logical_and(grp_write, st == M)
+    hit = jnp.logical_and(is_rep, jnp.logical_or(hit_read, hit_write))
+    miss = jnp.logical_and(is_rep, ~hit)
+    upgrade = jnp.logical_and(miss, jnp.logical_and(grp_write, st == S))
+    fresh_w = jnp.logical_and(miss, jnp.logical_and(grp_write, st != S))
+    read_miss = jnp.logical_and(miss, ~grp_write)
+    bit_hi, bit_lo = co.bit_lanes(node_id)
+    wf = co.writer_field_hi(node_id)
+    req = {
+        "line": jnp.where(miss, line, -1).astype(jnp.int32),
+        "op": jnp.where(grp_write, OP_CAS, OP_FAA).astype(jnp.int32),
+        "arg_hi": jnp.where(grp_write, wf, bit_hi).astype(jnp.int32),
+        "arg_lo": jnp.where(grp_write, 0, bit_lo).astype(jnp.int32),
+        "cmp_hi": jnp.where(upgrade, bit_hi, 0).astype(jnp.int32),
+        "cmp_lo": jnp.where(upgrade, bit_lo, 0).astype(jnp.int32),
+    }
+    _, old_hi, _, ok = apply_batch(words, req, backend="ref")
+    ok = ok.astype(bool)
+    no_writer = co.writer_of_hi(old_hi) < 0
+    read_grant = jnp.logical_and(read_miss, no_writer)
+    write_grant = jnp.logical_and(jnp.logical_or(upgrade, fresh_w), ok)
+    granted = jnp.logical_or(read_grant, write_grant)
+    served_rep = jnp.logical_or(hit, granted)
+    start = jnp.where(hit, cver[node_id, idx], mver[idx])
+    k = jnp.where(jnp.logical_and(served_rep, grp_write), n_w_grp, 0)
+    cstate = cstate.at[jnp.where(granted, node_id, n_nodes), idx].set(
+        jnp.where(read_grant, jnp.int8(S), jnp.int8(M)), mode="drop")
+    cver = cver.at[jnp.where(served_rep, node_id, n_nodes), idx].set(
+        start + k, mode="drop")
+    wrote = jnp.logical_and(served_rep, grp_write)
+    if write_back:
+        dirty = dirty.at[jnp.where(wrote, node_id, n_nodes), idx].set(
+            True, mode="drop")
+    else:
+        mver = mver.at[jnp.where(wrote, idx, n_lines)].add(k, mode="drop")
+    # fetch
+    if width:
+        base = jnp.where(hit[:, None], cdata[node_id, idx], mdata[idx])
+        gdata = jnp.where(grp_write[:, None], wdata[last_w], base)
+        cdata = cdata.at[jnp.where(served_rep, node_id, n_nodes),
+                         idx].set(gdata, mode="drop")
+        if not write_back:
+            mdata = mdata.at[jnp.where(wrote, idx, n_lines)].set(
+                gdata, mode="drop")
+    # boundary, dense
+    fail_w = jnp.logical_and(jnp.logical_or(upgrade, fresh_w), ~ok)
+    fail_r = jnp.logical_and(read_miss, ~no_writer)
+    wr_cnt = jnp.zeros((n_lines,), jnp.int32).at[
+        jnp.where(fail_w, idx, n_lines)].add(1, mode="drop")
+    rd_fail = jnp.zeros((n_lines,), bool).at[
+        jnp.where(fail_r, idx, n_lines)].set(True, mode="drop")
+    self_wr_fail = jnp.zeros((n_nodes, n_lines), jnp.int32).at[
+        jnp.where(fail_w, node_id, n_nodes), idx].set(1, mode="drop")
+    other_fail = (wr_cnt[None, :] - self_wr_fail) > 0
+    kill = jnp.logical_and(other_fail, cstate >= S)
+    m_mask = cstate == M
+    dg_line = jnp.logical_and(jnp.logical_and(rd_fail, wr_cnt == 0),
+                              jnp.any(m_mask, axis=0))
+    dg_mask = jnp.logical_and(dg_line[None, :], m_mask)
+    if write_back:
+        flush = jnp.logical_and(jnp.logical_or(kill, dg_mask),
+                                jnp.logical_and(m_mask, dirty))
+        flush_ver = jnp.max(jnp.where(flush, cver, 0), axis=0)
+        mver = jnp.where(jnp.any(flush, axis=0), flush_ver, mver)
+        if width:
+            flush_data = jnp.sum(
+                jnp.where(flush[:, :, None], cdata, 0), axis=0)
+            mdata = jnp.where(jnp.any(flush, axis=0)[:, None],
+                              flush_data, mdata)
+        dirty = jnp.logical_and(dirty, ~jnp.logical_or(kill, dg_mask))
+    cstate = jnp.where(kill, jnp.int8(I), cstate)
+    cstate = jnp.where(dg_mask, jnp.int8(S), cstate)
+    words = co.directory_from_state(cstate)
+    # replies
+    served = jnp.where(valid, served_rep[first], False)
+    slot_start = start[first]
+    version = jnp.where(
+        served, jnp.where(is_w, slot_start + w_rank + 1,
+                          slot_start + n_w_grp), 0).astype(jnp.int32)
+    data = (jnp.where(served[:, None], gdata[first], 0) if width
+            else jnp.zeros((r, 0), jnp.int32))
+    new_state = dict(state)
+    new_state.update({"words": words, "cache_state": cstate,
+                      "cache_version": cver, "mem_version": mver})
+    if write_back:
+        new_state["dirty"] = dirty
+    if width:
+        new_state["mem_data"] = mdata
+        new_state["cache_data"] = cdata
+    events = jnp.stack([
+        jnp.sum(miss.astype(jnp.int32)),
+        jnp.sum(jnp.logical_or(fail_w, fail_r).astype(jnp.int32)),
+        jnp.sum(jnp.logical_and(valid, hit[first]).astype(jnp.int32))])
+    return new_state, served, version, data, events
+
+
+_dense_round_jit = jax.jit(_dense_round, static_argnames="n_nodes")
+
+# Scripted rounds of (node, line, is_write) slots, padded with line = -1
+# slots, on 4 nodes x 8 lines.  Each round names the boundary case it
+# drives.
+_BOUNDARY_SCRIPT = [
+    [(0, 2, 0), (1, 2, 0), (2, 2, 0), (3, 5, 0)],   # S copies
+    # racing upgraders kill each other and node 2's copy; node 3's
+    # sole-S upgrade wins
+    [(0, 2, 1), (1, 2, 1), (3, 5, 1)],
+    # a read-fail downgrade of a dirty M holder; three nodes (and a
+    # duplicate slot) name line 5
+    [(3, 5, 1), (2, 5, 0), (1, 5, 0), (2, 5, 0)],
+    [(0, 5, 0), (1, 5, 0)],
+    # two failing writers on line 5, an upgrader and a fresh writer,
+    # kill its three S holders
+    [(0, 5, 1), (2, 5, 1), (2, 2, 1)],
+    [(0, 6, 1), (0, 6, 1), (3, 7, 1)],
+    # a failed writer kills a dirty M holder while a failed reader of
+    # the same line waits: no downgrade
+    [(1, 6, 1), (2, 6, 0), (3, 7, 0)],
+    [(1, 6, 1), (2, 6, 0), (0, 7, 1), (1, 7, 0)],
+]
+
+
+def _boundary_rounds(scenario, n_nodes, r, width):
+    """The (node, line, is_write, wdata) operands of each round."""
+    rng = np.random.default_rng(17)
+    if scenario == "scripted":
+        batches = [(np.asarray([s[0] for s in b] + [0] * (r - len(b))),
+                    np.asarray([s[1] for s in b] + [-1] * (r - len(b))),
+                    np.asarray([s[2] for s in b] + [0] * (r - len(b))))
+                   for b in _BOUNDARY_SCRIPT]
+    else:
+        # contended: few hot lines, half writes, a quarter empty slots
+        batches = [(rng.integers(0, n_nodes, r),
+                    np.where(rng.random(r) < 0.25, -1,
+                             rng.integers(0, 4, r)),
+                    rng.integers(0, 2, r)) for _ in range(40)]
+    for node, line, isw in batches:
+        wd = (jnp.asarray(rng.integers(1, 1 << 20, (r, width)), jnp.int32)
+              if width else None)
+        yield (jnp.asarray(node, jnp.int32), jnp.asarray(line, jnp.int32),
+               jnp.asarray(isw, jnp.int32), wd)
+
+
+@pytest.mark.parametrize("scenario", ["scripted", "random"])
+@pytest.mark.parametrize("write_back,width",
+                         [(False, 0), (False, 4), (True, 0), (True, 4)])
+def test_sparse_boundary_matches_dense_reference(scenario, write_back,
+                                                 width):
+    """Round by round, the engine's sparse boundary gives the same
+    state leaves, replies and events as the dense reference, and every
+    word equals its directory."""
+    n_nodes, n_lines, r = 4, 8, 12
+    state = rp.make_state(n_nodes, n_lines, write_back=write_back,
+                          payload_width=width)
+    ref = state
+    for ops in _boundary_rounds(scenario, n_nodes, r, width):
+        out = engine._round_jit(state, *ops, n_nodes=n_nodes)
+        want = _dense_round_jit(ref, *ops, n_nodes=n_nodes)
+        for got_x, want_x in zip(out[1:], want[1:]):
+            np.testing.assert_array_equal(np.asarray(got_x),
+                                          np.asarray(want_x))
+        state, ref = out[0], want[0]
+        assert set(state) == set(ref)
+        for key in ref:
+            np.testing.assert_array_equal(np.asarray(state[key]),
+                                          np.asarray(ref[key]),
+                                          err_msg=key)
+        rp.check_invariants(state)
+    if scenario == "scripted" and write_back:
+        # the dirty holders of lines 5 and 6 flushed at the boundary
+        mv = np.asarray(state["mem_version"])
+        assert mv[5] > 0 and mv[6] > 0
+
+
+_INDEXING = ("gather", "scatter", "dynamic_slice", "dynamic_update_slice")
+
+
+def _boundary_eqns(jaxpr, inside=False):
+    """(primitive, operand shapes) of every equation in the
+    ``round/boundary`` scope, sub-jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        here = inside or "round/boundary" in str(eqn.source_info.name_stack)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _boundary_eqns(sub, here)
+        if here:
+            out.append((eqn.primitive.name,
+                        [tuple(getattr(v.aval, "shape", ()))
+                         for v in eqn.invars]))
+    return out
+
+
+def test_round_boundary_touches_only_named_lines():
+    """No equation of the boundary passes over a whole [N, L] or
+    [N, L, W] leaf: its work scales with the round's R slots, not with
+    the plane's L lines.  Gathers and scatters are the boundary's way in
+    and out of the carried leaves, so they are left out; one-axis [L]
+    vectors are allowed."""
+    n_nodes, n_lines, width, r = 8, 4096, 8, 32
+    state = rp.make_state(n_nodes, n_lines, write_back=True,
+                          payload_width=width)
+    slots = jnp.zeros((r,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda st, node, line, isw, wd: engine._round_impl(
+            st, node, line, isw, wd, n_nodes=n_nodes))(
+        state, slots, slots, slots, jnp.zeros((r, width), jnp.int32))
+    eqns = _boundary_eqns(jaxpr.jaxpr)
+    assert len(eqns) > 10
+    dense = [(prim, shapes) for prim, shapes in eqns
+             if not prim.startswith(_INDEXING)
+             and any(len(s) >= 2 and n_lines in s for s in shapes)]
+    assert dense == []

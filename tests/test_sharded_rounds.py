@@ -470,3 +470,64 @@ def test_multi_shard_parity_subprocess():
     out = subprocess.run([sys.executable, "-c", code], cwd=".",
                          capture_output=True, text=True, timeout=600)
     assert "SHARDED_PARITY_OK" in out.stdout, out.stderr[-3000:]
+
+
+def test_failing_writers_on_one_local_line_subprocess():
+    """Two nodes fail writes on the same line in one round, on a slab
+    where that line's LOCAL index differs from its global id (line 6 is
+    local line 1 of shard 2, line 2 local line 0): the homes' boundaries
+    kill, flush and rebuild words exactly as the flat engine does.  One
+    slot per source shard keeps the homes' slot order the flat order, so
+    the latch contention resolves the same way on both planes."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import sys
+        sys.path.insert(0, "src")
+        import jax, numpy as np
+        from repro.core import rounds as rp
+
+        mesh = jax.make_mesh((4,), ("shards",))
+        TRACE = [
+            [(0, 6, 0), (1, 6, 0), (2, 6, 0), (3, 2, 0)],
+            # racing upgraders on line 6 kill each other and node 2's
+            # copy, while node 3 upgrades line 2 on the same slab
+            [(0, 6, 1), (1, 6, 1), (3, 2, 1), (2, 3, 0)],
+            # a failed write and a failed read on each of lines 6 and 2
+            # kill their (dirty) M holders
+            [(2, 6, 0), (3, 6, 1), (1, 2, 1), (0, 2, 0)],
+        ]
+        for write_back in (False, True):
+            for width in (0, 2):
+                flat = rp.DevicePlane.open(rp.make_state(
+                    4, 8, write_back=write_back, payload_width=width))
+                shd = rp.DevicePlane.open(rp.make_sharded_state(
+                    4, 8, mesh, write_back=write_back,
+                    payload_width=width), mesh)
+                for b, batch in enumerate(TRACE):
+                    node, line, isw = (np.asarray(col, np.int32)
+                                       for col in zip(*batch))
+                    wd = (np.stack([isw * (10 * b + np.arange(4) + 1),
+                                    node], axis=1).astype(np.int32)
+                          if width else None)
+                    r1 = flat.ops(node, line, isw, wd)
+                    r2 = shd.ops(node, line, isw, wd)
+                    assert r1.version.tolist() == r2.version.tolist(), (
+                        write_back, width, b)
+                    if width:
+                        assert r1.data.tolist() == r2.data.tolist()
+                    assert (int(r1.telemetry.latch_failed)
+                            == int(r2.telemetry.latch_failed))
+                    if b == 1:
+                        assert int(r2.telemetry.latch_failed) >= 2
+                    shd.check()
+                g = shd.flat_state()
+                for k in flat.state:
+                    np.testing.assert_array_equal(
+                        np.asarray(flat.state[k]), np.asarray(g[k]),
+                        err_msg=k)
+        print("LOCAL_LINE_PARITY_OK")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], cwd=".",
+                         capture_output=True, text=True, timeout=600)
+    assert "LOCAL_LINE_PARITY_OK" in out.stdout, out.stderr[-3000:]
