@@ -54,8 +54,8 @@ from .sharded import (coherence_round_sharded, evict_lines_sharded,
                       run_rounds_sharded, shard_state, unshard_state)
 from .state import (check_invariants, is_write_back, make_state,
                     payload_width, stripe_state, unstripe_state)
-from .txn import (TxnBatchResult, TxnProgram, run_txn_batch,
-                  run_txn_rounds, run_txn_rounds_sharded)
+from .txn import (TxnBatchResult, TxnProgram, run_txn_rounds,
+                  run_txn_rounds_sharded)
 
 __all__ = [
     "I", "S", "M", "DevicePlane", "FlightRecorder", "PlaneResult",
@@ -66,7 +66,7 @@ __all__ = [
     "payload_width", "plan_rehome", "plan_replication",
     "rehome_exchange", "run_descent", "run_descent_sharded", "run_rmw",
     "run_rmw_sharded", "run_rounds",
-    "run_rounds_sharded", "run_txn_batch", "run_txn_rounds",
+    "run_rounds_sharded", "run_txn_rounds",
     "run_txn_rounds_sharded",
     "shard_state", "stripe_state", "unshard_state", "unstripe_state",
 ]

@@ -48,54 +48,35 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .driver import _tele_round, zero_flat_tele
-from .engine import _note_trace, _round_impl
+from .driver import (_add_tele, _same, _tele_rows, _zero_tele, flat_step,
+                     tele_dict)
+from .engine import _note_trace
 from .state import payload_width
 
-@functools.partial(jax.jit,
-                   static_argnames=("transition", "n_nodes", "max_steps",
-                                    "backend", "path_cap"))
-def run_descent(state, node_id, key, root, *, transition, n_nodes: int,
-                max_steps: int = 64, backend: str = "ref",
-                path_cap: int = 16):
-    """Drive descent slots (node_id, key, start line) int32 [B] to
-    their leaves in ONE jit call.  ``root[i] = -1`` marks an inactive
-    pad slot.  Requires a payload-plane state (the transition decodes
-    real node bytes).
 
-    Returns ``(state', line[B], lanes[B, W], levels[B], hops[B],
-    paths[B, path_cap], path_len[B], steps_used, all_done,
-    telemetry)`` — all device values: each slot's final line and its
-    node lanes, how many levels it descended and right links it hopped,
-    the internal lines it descended through, whether every slot settled
-    within ``max_steps`` outer iterations (each costs one coherence
-    round), and the flat telemetry counter dict accumulated in the
-    carry (``driver.zero_flat_tele`` keys; descents are pure reads, so
-    ``slot_whits`` stays zero)."""
-    node_id = jnp.asarray(node_id, jnp.int32)
-    key = jnp.asarray(key, jnp.int32)
-    root = jnp.asarray(root, jnp.int32)
+def descend(step, count, state, node, key, root, *, transition,
+            max_steps: int, path_cap: int, tele0):
+    """Walk descent slots (node, key, start line) [B] to their leaves:
+    each iteration presents every undone slot's S-latch read in one
+    round, decodes the served lanes with ``transition`` and advances the
+    slot (the module docstring).  Returns ``(state', line[B], lanes[B, W],
+    levels[B], hops[B], paths[B, path_cap], path_len[B], steps,
+    all_done, tele)``."""
     b = root.shape[0]
     width = payload_width(state)
-    n_lines = state["words"].shape[0]
-    write_back = "dirty" in state
-    _note_trace(("descent", transition, n_nodes, b, max_steps, backend,
-                 write_back, width, path_cap))
     no_write = jnp.zeros((b,), jnp.int32)
     no_bytes = jnp.zeros((b, width), jnp.int32)
 
-    def cond(carry):
-        _, _, done, _, _, _, _, _, steps, _ = carry
-        return jnp.logical_and(jnp.any(~done), steps < max_steps)
+    def left(done):
+        return count(jnp.sum((~done).astype(jnp.int32)))
 
-    def body(carry):
-        st, cur, done, lanes, levels, hops, paths, plen, steps, tele \
-            = carry
+    def cond(c):
+        return jnp.logical_and(c[10] > 0, c[8] < max_steps)
+
+    def body(c):
+        st, cur, done, lanes, levels, hops, paths, plen, steps, tele, _ = c
         line = jnp.where(done, jnp.int32(-1), cur)
-        st, served, _, d, events = _round_impl(
-            st, node_id, line, no_write, no_bytes, n_nodes=n_nodes,
-            backend=backend)
-        tele = _tele_round(tele, line, served, no_write, events, n_lines)
+        st, served, _, d, dtele = step(st, node, line, no_write, no_bytes)
         at_leaf, hop, nxt = transition(d, key)
         move = jnp.logical_and(served, ~done)
         hop = jnp.logical_and(move, hop)
@@ -116,16 +97,48 @@ def run_descent(state, node_id, key, root, *, transition, n_nodes: int,
         advance = jnp.logical_and(move, ~at_leaf)
         cur = jnp.where(advance, nxt, cur)
         return (st, cur, done, lanes, levels, hops, paths, plen,
-                steps + 1, tele)
+                steps + 1, _add_tele(tele, dtele), left(done))
 
-    init = (state, root, root < 0,
+    done0 = root < 0
+    init = (state, root, done0,
             jnp.zeros((b, width), jnp.int32),
             jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
             jnp.full((b, path_cap), -1, jnp.int32),
-            jnp.zeros((b,), jnp.int32), jnp.int32(0),
-            zero_flat_tele(n_lines))
-    state, cur, done, lanes, levels, hops, paths, plen, steps, tele = \
-        jax.lax.while_loop(cond, body, init)
-    return (state, cur, lanes, levels, hops, paths, plen, steps,
-            jnp.all(done), tele)
+            jnp.zeros((b,), jnp.int32), jnp.int32(0), tele0, left(done0))
+    (state, cur, _, lanes, levels, hops, paths, plen, steps, tele,
+     n) = jax.lax.while_loop(cond, body, init)
+    return (state, cur, lanes, levels, hops, paths, plen, steps, n == 0,
+            tele)
 
+
+@functools.partial(jax.jit,
+                   static_argnames=("transition", "n_nodes", "max_steps",
+                                    "backend", "path_cap"))
+def run_descent(state, node_id, key, root, *, transition, n_nodes: int,
+                max_steps: int = 64, backend: str = "ref",
+                path_cap: int = 16):
+    """Drive descent slots (node_id, key, start line) int32 [B] to
+    their leaves in ONE jit call.  ``root[i] = -1`` marks an inactive
+    pad slot.  Requires a payload-plane state (the transition decodes
+    real node bytes).
+
+    Returns ``(state', line[B], lanes[B, W], levels[B], hops[B],
+    paths[B, path_cap], path_len[B], steps_used, all_done,
+    telemetry)`` — all device values: each slot's final line and its
+    node lanes, how many levels it descended and right links it hopped,
+    the internal lines it descended through, whether every slot settled
+    within ``max_steps`` outer iterations (each costs one coherence
+    round), and the telemetry dict (``driver.TELE_KEYS``) accumulated in
+    the carry (descents are pure reads, so ``slot_whits`` stays
+    zero)."""
+    node_id = jnp.asarray(node_id, jnp.int32)
+    key = jnp.asarray(key, jnp.int32)
+    root = jnp.asarray(root, jnp.int32)
+    _note_trace(("descent", transition, n_nodes, root.shape[0], max_steps,
+                 backend, "dirty" in state, payload_width(state),
+                 path_cap))
+    *out, tele = descend(
+        flat_step(n_nodes, backend), _same, state, node_id, key, root,
+        transition=transition, max_steps=max_steps, path_cap=path_cap,
+        tele0=_zero_tele(1, state["words"].shape[0]))
+    return (*out, tele_dict(_tele_rows(tele)))

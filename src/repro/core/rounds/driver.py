@@ -1,4 +1,4 @@
-"""The fused on-device spin loop: run ops to completion in ONE jit call.
+"""The fused on-device loops: run a verb to completion in ONE jit call.
 
 Pre-refactor, ``run_ops_to_completion`` was a host-side Python loop that
 synced served/pending masks back to the host after EVERY round — exactly
@@ -9,6 +9,27 @@ round counter) never leaves the device: unserved ops re-present
 themselves round after round (the protocol's spin) with zero host↔device
 syncs inside the loop, and the while_loop body traces the round engine
 exactly once per shape (engine.TRACE_COUNTS proves it).
+
+Each loop is written once, over a round STEP::
+
+    step(state, node, pending, is_write, wdata)
+        -> (state', served, version, data, tele_delta)
+
+and a COUNT lift that turns a local count into the loop's global one.
+One chip plugs in :func:`flat_step` (one engine round plus the flat
+telemetry fold) and the identity; a mesh plugs in
+``sharded._route_round`` (the round routed to each line's home shard)
+and a ``psum`` over the mesh axis, inside its ``shard_map``.  The spin
+(:func:`spin`) serves ``run_rounds``, ``run_rounds_sharded`` and both
+transaction spins; the descent (``descent.descend``) serves ``run_descent``
+and ``run_descent_sharded``.
+
+Telemetry has one layout: the tuple of :data:`TELE_KEYS`, a delta per
+round summed in the carry (:func:`_zero_tele`, :func:`_add_tele`).  A
+jitted entry point turns it into the public dict where it returns
+(:func:`_tele_rows` then :func:`tele_dict`): ``occupancy`` / ``deferred``
+[S, S], ``served_per_home`` / ``replica_served`` [S], ``slot_hits`` /
+``slot_whits`` [L] and ``events`` [S, 3], with S = 1 on one chip.
 """
 
 from __future__ import annotations
@@ -21,50 +42,120 @@ import jax.numpy as jnp
 from .engine import _note_trace, _round_jit
 from .state import payload_width
 
-
-def zero_flat_tele(n_lines: int) -> dict:
-    """Zeroed FLAT telemetry accumulator — the same counter keys the
-    sharded drivers return, shaped for S = 1 home (``occupancy`` /
-    ``deferred`` [1, 1], ``served_per_home`` / ``replica_served`` [1],
-    per-line ``slot_hits`` / ``slot_whits`` [L]; the flat engine is
-    line-major, so slot == line; ``events`` [1, 3], the round body's
-    protocol events in ``engine.EVENT_KEYS`` order).  Rides the fused
-    loops' carries — accumulating costs two scatter-adds per round,
-    zero host syncs."""
-    z1 = jnp.zeros((1,), jnp.int32)
-    return {"occupancy": jnp.zeros((1, 1), jnp.int32),
-            "deferred": jnp.zeros((1, 1), jnp.int32),
-            "served_per_home": z1, "replica_served": z1,
-            "slot_hits": jnp.zeros((n_lines,), jnp.int32),
-            "slot_whits": jnp.zeros((n_lines,), jnp.int32),
-            "events": jnp.zeros((1, 3), jnp.int32)}
+TELE_KEYS = ("occupancy", "deferred", "served_per_home", "replica_served",
+             "slot_hits", "slot_whits", "events")
 
 
-def add_tele(a: dict, b: dict) -> dict:
-    """Key-wise telemetry-dict sum (accumulation across phases/spins)."""
-    return {k: a[k] + b[k] for k in a}
+def _zero_tele(n_shards: int, l_local: int):
+    """Zeroed telemetry accumulator of one shard (the whole plane on one
+    chip): (occupancy[S], deferred[S], served_at_home, replica_served,
+    slot_hits[L_local], slot_whits[L_local], events[3]), ``events`` in
+    ``engine.EVENT_KEYS`` order."""
+    z = jnp.zeros((n_shards,), jnp.int32)
+    zl = jnp.zeros((l_local,), jnp.int32)
+    return (z, z, jnp.int32(0), jnp.int32(0), zl, zl,
+            jnp.zeros((3,), jnp.int32))
 
 
-@jax.named_scope("round/telemetry")
-def _tele_round(tele: dict, pending, served, is_write, events,
-                n_lines: int) -> dict:
-    """Fold one round's serve results and protocol ``events`` into a
-    flat telemetry carry: ``pending`` is the PRE-round line per slot
-    (-1 = done/pad)."""
-    valid = pending >= 0
-    hit = jnp.logical_and(served, valid)
-    hit_line = jnp.where(hit, pending, n_lines)      # n_lines = dropped
-    occ = tele["occupancy"] + jnp.sum(valid.astype(jnp.int32))
-    srv = tele["served_per_home"] + jnp.sum(hit.astype(jnp.int32))
-    hits = tele["slot_hits"].at[hit_line].add(1, mode="drop")
-    whits = tele["slot_whits"].at[
-        jnp.where(is_write.astype(bool), hit_line, n_lines)].add(
-        1, mode="drop")
-    return {"occupancy": occ, "deferred": tele["deferred"],
-            "served_per_home": srv,
-            "replica_served": tele["replica_served"],
-            "slot_hits": hits, "slot_whits": whits,
-            "events": tele["events"] + events[None, :]}
+def _add_tele(a, b):
+    return tuple(x + y for x, y in zip(a, b))
+
+
+def _tele_rows(tele):
+    """A shard's accumulated telemetry as one row per home of every
+    scalar and [S]-vector counter, the slab's per-slot counters as they
+    are (``sharded._tele_specs`` stacks the rows across the mesh)."""
+    occ, dfr, srv, rsrv, hits, whits, events = tele
+    return (occ[None, :], dfr[None, :], srv[None], rsrv[None], hits,
+            whits, events[None, :])
+
+
+def tele_dict(rows) -> dict:
+    """The public telemetry dict of :func:`_tele_rows`' rows."""
+    return dict(zip(TELE_KEYS, rows))
+
+
+def _same(x):
+    """The count lift of one chip: a local count is the global one."""
+    return x
+
+
+def flat_step(n_nodes: int, backend: str):
+    """The one-chip round step: one engine round, with its telemetry
+    delta folded from the PRE-round line per slot (``pending``, -1 =
+    done/pad).  The flat engine presents ops by line id, so its slot
+    counters are line-indexed, and its occupancy is the count of valid
+    pending slots."""
+    def step(state, node, pending, is_write, wdata):
+        state, served, ver, data, events = _round_jit(
+            state, node, pending, is_write, wdata, n_nodes=n_nodes,
+            backend=backend)
+        with jax.named_scope("round/telemetry"):
+            n_lines = state["words"].shape[0]
+            valid = pending >= 0
+            hit = jnp.logical_and(served, valid)
+            hit_line = jnp.where(hit, pending, n_lines)   # n_lines = drop
+            zl = jnp.zeros((n_lines,), jnp.int32)
+            tele = (jnp.sum(valid.astype(jnp.int32))[None],
+                    jnp.zeros((1,), jnp.int32),
+                    jnp.sum(hit.astype(jnp.int32)), jnp.int32(0),
+                    zl.at[hit_line].add(1, mode="drop"),
+                    zl.at[jnp.where(is_write.astype(bool), hit_line,
+                                    n_lines)].add(1, mode="drop"),
+                    events)
+        return state, served, ver, data, tele
+    return step
+
+
+def spin(step, count, state, node, line, is_write, wdata, *,
+         max_rounds: int, tele0):
+    """Serve op slots [R] round after round until none is pending (the
+    protocol's spin) or ``max_rounds`` is hit.  Returns ``(state',
+    versions[R], data[R, W], rounds, all_served, tele)``."""
+    def left(pending):
+        return count(jnp.sum((pending >= 0).astype(jnp.int32)))
+
+    def cond(c):
+        return jnp.logical_and(c[6] > 0, c[4] < max_rounds)
+
+    def body(c):
+        st, pending, versions, data, rounds, tele, _ = c
+        st, served, ver, rdata, dtele = step(st, node, pending, is_write,
+                                             wdata)
+        versions = jnp.where(served, ver, versions)
+        data = jnp.where(served[:, None], rdata, data)
+        pending = jnp.where(served, jnp.int32(-1), pending)
+        return (st, pending, versions, data, rounds + 1,
+                _add_tele(tele, dtele), left(pending))
+
+    init = (state, line, jnp.zeros_like(line),
+            jnp.zeros((line.shape[0], wdata.shape[1]), jnp.int32),
+            jnp.int32(0), tele0, left(line))
+    state, _, versions, data, rounds, tele, n = jax.lax.while_loop(
+        cond, body, init)
+    return state, versions, data, rounds, n == 0, tele
+
+
+def op_arrays(state, node_id, line, is_write, wdata):
+    """Op slots as int32 arrays; ``wdata`` all-zero [R, W] when None."""
+    line = jnp.asarray(line, jnp.int32)
+    wdata = (jnp.zeros((line.shape[0], payload_width(state)), jnp.int32)
+             if wdata is None else jnp.asarray(wdata, jnp.int32))
+    return (jnp.asarray(node_id, jnp.int32), line,
+            jnp.asarray(is_write, jnp.int32), wdata)
+
+
+def two_phase(run, state, node_id, line, operands, modify):
+    """The coherent read-modify-write over an ops driver ``run(state,
+    node, line, is_write, wdata)``: a read phase, ``modify`` on the
+    read bytes, a write phase of the new bytes (see :func:`run_rmw`)."""
+    state, _, data, r1, ok1, t1 = run(state, node_id, line,
+                                      jnp.zeros_like(line), None)
+    new_data = jnp.asarray(modify(data, line, *operands), jnp.int32)
+    state, versions, data2, r2, ok2, t2 = run(
+        state, node_id, line, jnp.ones_like(line), new_data)
+    return (state, versions, data2, r1 + r2, jnp.logical_and(ok1, ok2),
+            {k: t1[k] + t2[k] for k in t1})
 
 
 @functools.partial(jax.jit,
@@ -81,47 +172,18 @@ def run_rounds(state, node_id, line, is_write, wdata=None, *,
     whatever the CALLER materializes.  ``data`` holds each op's read
     payload (its group's final bytes; W = 0 on version-only states),
     produced INSIDE the fused loop — no extra host round trip buys the
-    bytes.  ``telemetry`` is the flat counter dict (same keys as the
-    sharded drivers', S = 1 — see :func:`zero_flat_tele`), accumulated
-    in the loop carry; its per-line hit counters are bit-identical to a
-    sharded plane's on the same op trace.  ``max_rounds`` bounds the
-    loop (static); ``all_served`` is False if the bound was hit with
-    ops still pending."""
-    node_id = jnp.asarray(node_id, jnp.int32)
-    line = jnp.asarray(line, jnp.int32)
-    is_write = jnp.asarray(is_write, jnp.int32)
-    width = payload_width(state)
-    n_lines = state["words"].shape[0]
-    if wdata is None:
-        wdata = jnp.zeros((line.shape[0], width), jnp.int32)
-    else:
-        wdata = jnp.asarray(wdata, jnp.int32)
-    write_back = "dirty" in state
-    _note_trace(("driver", n_nodes, line.shape[0], max_rounds, backend,
-                 write_back, width))
-
-    def cond(carry):
-        _, pending, _, _, rounds, _ = carry
-        return jnp.logical_and(jnp.any(pending >= 0), rounds < max_rounds)
-
-    def body(carry):
-        st, pending, versions, data, rounds, tele = carry
-        st, served, ver, rdata, events = _round_jit(
-            st, node_id, pending, is_write, wdata, n_nodes=n_nodes,
-            backend=backend)
-        tele = _tele_round(tele, pending, served, is_write, events,
-                           n_lines)
-        versions = jnp.where(served, ver, versions)
-        data = jnp.where(served[:, None], rdata, data)
-        pending = jnp.where(served, jnp.int32(-1), pending)
-        return st, pending, versions, data, rounds + 1, tele
-
-    init = (state, line, jnp.zeros_like(line),
-            jnp.zeros((line.shape[0], width), jnp.int32), jnp.int32(0),
-            zero_flat_tele(n_lines))
-    state, pending, versions, data, rounds, tele = jax.lax.while_loop(
-        cond, body, init)
-    return state, versions, data, rounds, jnp.all(pending < 0), tele
+    bytes.  ``telemetry`` is the counter dict of the module docstring
+    at S = 1, accumulated in the loop carry; its per-line hit counters
+    are bit-identical to a sharded plane's on the same op trace.
+    ``max_rounds`` bounds the loop (static); ``all_served`` is False if
+    the bound was hit with ops still pending."""
+    ops = op_arrays(state, node_id, line, is_write, wdata)
+    _note_trace(("driver", n_nodes, ops[1].shape[0], max_rounds, backend,
+                 "dirty" in state, payload_width(state)))
+    *out, tele = spin(flat_step(n_nodes, backend), _same, state, *ops,
+                      max_rounds=max_rounds,
+                      tele0=_zero_tele(1, state["words"].shape[0]))
+    return (*out, tele_dict(_tele_rows(tele)))
 
 
 @functools.partial(jax.jit,
@@ -168,19 +230,14 @@ def run_rmw(state, node_id, line, operands=(), *, modify, n_nodes: int,
     Returns ``(state', versions[R], data[R, W], rounds_used,
     all_served, telemetry)`` where ``versions``/``data`` are the WRITE
     phase's replies (the bytes the final versions name) and
-    ``telemetry`` sums both phases' flat counter dicts."""
+    ``telemetry`` sums both phases' counter dicts."""
     node_id = jnp.asarray(node_id, jnp.int32)
     line = jnp.asarray(line, jnp.int32)
     # modify is a static arg: a fresh callable per call retraces, so it
     # belongs in the trace key or the TRACE_COUNTS guard tests go blind
     _note_trace(("rmw", modify, n_nodes, line.shape[0], max_rounds,
                  backend, "dirty" in state, payload_width(state)))
-    state, _, data, r1, ok1, t1 = run_rounds(
-        state, node_id, line, jnp.zeros_like(line), None,
-        n_nodes=n_nodes, max_rounds=max_rounds, backend=backend)
-    new_data = jnp.asarray(modify(data, line, *operands), jnp.int32)
-    state, versions, data2, r2, ok2, t2 = run_rounds(
-        state, node_id, line, jnp.ones_like(line), new_data,
-        n_nodes=n_nodes, max_rounds=max_rounds, backend=backend)
-    return (state, versions, data2, r1 + r2,
-            jnp.logical_and(ok1, ok2), add_tele(t1, t2))
+    return two_phase(
+        functools.partial(run_rounds, n_nodes=n_nodes,
+                          max_rounds=max_rounds, backend=backend),
+        state, node_id, line, operands, modify)

@@ -1,21 +1,11 @@
 """`DevicePlane` — ONE facade over the device coherence plane.
 
-Pre-facade, driving the rounds engine meant choosing among six
-entrypoints (``run_rounds`` / ``run_rmw`` / ``run_descent`` and their
-``*_sharded`` mirrors) plus three host-facing ``run_*_to_completion``
-dispatchers, each with its own tuple arity (``run_ops_to_completion``
-widens to a 4-tuple when ``wdata`` is passed, the RMW wrapper always
-returns 4, descent returns 8) — and every APPLICATION re-implemented
-the same ``mesh is None`` branch, slot padding, operand zero-padding
-and bound-hit check (``index/tree.py``, ``dsm/kvpool.py``,
-``serve/loop.py`` each carried a copy).  That is exactly the
-programmability gap the layered-abstraction line of work (MIND; "Memory
-Disaggregation: Advances and Open Challenges") says a disaggregated
-memory plane must close.
-
-:class:`DevicePlane` owns the whole bundle — ``state + mesh + n_nodes +
-write_back`` — and exposes the three verbs with ONE keyword surface and
-ONE result type:
+Applications (``index/tree.py``, ``dsm/kvpool.py``, ``serve/loop.py``)
+drive the plane through one object and never branch on the geometry —
+the programmability layer MIND and "Memory Disaggregation: Advances and
+Open Challenges" ask of a disaggregated memory plane.
+:class:`DevicePlane` owns ``state + mesh + n_nodes + write_back`` and
+exposes the verbs with ONE keyword surface and ONE result type:
 
     plane = DevicePlane.open(state, mesh=None, n_nodes=4)    # or
     plane = layer.as_plane(payload_width=W, mesh=mesh)       # from DES
@@ -29,11 +19,10 @@ ONE result type:
 Every verb mutates ``plane.state`` in place (the plane IS the memory),
 materializes its result arrays exactly once at the end (zero syncs
 inside the fused loops), raises ``RuntimeError`` if the round/step
-bound was hit, and returns a :class:`PlaneResult` — ``version``, ``data``, ``rounds``,
-``stats`` — instead of a positional tuple whose arity the caller must
-memorize.  Sharded planes route through the very same calls: the mesh
-dispatch, ``pad_ops`` slot padding and result re-slicing all live HERE,
-once.
+bound was hit, and returns a :class:`PlaneResult` (``version``,
+``data``, ``rounds``, ``stats``).  Sharded planes route through the
+very same calls: the plane picks its drivers (flat or mesh) once, when
+it is built, and the slot padding and result re-slicing live HERE.
 
 Every verb — flat AND sharded — also surfaces the telemetry the fused
 loops accumulate in their carries as a typed
@@ -56,11 +45,11 @@ Every verb runs inside ONE bracket, :meth:`DevicePlane._span`: a
 ``jax.profiler.TraceAnnotation`` named ``plane.<verb>`` on the
 profiler's clock, so a device trace shows each dispatch beside the
 device work it caused.  ``ops``, ``rmw`` and ``descent`` nest fixed
-child annotations in it — ``plane.pad`` (sharded slot and operand
-padding), ``plane.dispatch`` (the call into the jitted loop),
-``plane.sync`` (the blocking ``bool(done)``), ``plane.telemetry``
-(wrapping the counters, uncopied) and ``plane.results`` (the result
-copies) — and report each child's host seconds in
+child annotations in it — ``plane.pad`` (slot and operand padding,
+on a mesh of more than one shard), ``plane.dispatch`` (the call into
+the jitted loop), ``plane.sync`` (the blocking ``bool(done)``),
+``plane.telemetry`` (wrapping the counters, uncopied) and
+``plane.results`` (the result copies) — and report each child's host seconds in
 ``PlaneResult.host_s``.  With a recorder attached
 (``DevicePlane.open(..., recorder=rec)`` or ``attach_recorder``) the
 bracket also appends one span — wall time, rounds, serve totals,
@@ -73,10 +62,23 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...obs import PlaneTelemetry
+from .. import coherence as co
+from .descent import run_descent
+from .driver import run_rmw, run_rounds
+from .engine import EVENT_KEYS, TRACE_COUNTS, evict_lines
+from .sharded import (auto_mesh, evict_lines_sharded, rehome_exchange,
+                      run_descent_sharded, run_rmw_sharded,
+                      run_rounds_sharded, unshard_state)
+from .state import check_invariants, payload_width
+from .txn import (TxnBatchResult, resolve_program, run_txn_rounds,
+                  run_txn_rounds_sharded)
 
 
 @dataclass(frozen=True)
@@ -108,8 +110,8 @@ class PlaneResult:
       held result keeps the unread leaves on the device (two [L]
       int32 counters and a few [S, S]); drop it to free them;
     * ``host_s``  — host seconds of each child span of the verb's
-      bracket (``pad``, sharded only; ``dispatch``, ``sync``,
-      ``telemetry``, ``results``).
+      bracket (``pad``, on more than one shard only; ``dispatch``,
+      ``sync``, ``telemetry``, ``results``).
     """
 
     version: np.ndarray | None
@@ -164,6 +166,20 @@ class DevicePlane:
         self.max_rounds = int(max_rounds)
         self.bucket_cap = bucket_cap
         self.recorder = recorder
+        # the geometry, chosen once: each verb's fused driver and the
+        # keywords only a mesh takes
+        if mesh is None:
+            self._run = {"ops": run_rounds, "rmw": run_rmw,
+                         "descent": run_descent, "txn": run_txn_rounds,
+                         "evict": evict_lines}
+            self._geo = {}
+        else:
+            self._run = {"ops": run_rounds_sharded, "rmw": run_rmw_sharded,
+                         "descent": run_descent_sharded,
+                         "txn": run_txn_rounds_sharded,
+                         "evict": evict_lines_sharded}
+            self._geo = {"mesh": mesh, "axis": axis,
+                         "bucket_cap": bucket_cap}
 
     @classmethod
     def open(cls, state, mesh=None, *, axis: str = "shards",
@@ -198,7 +214,6 @@ class DevicePlane:
 
     @property
     def payload_width(self) -> int:
-        from .state import payload_width
         return payload_width(self.state)
 
     @property
@@ -209,13 +224,11 @@ class DevicePlane:
         """Host-side snapshot in FLAT (line-major) layout — unstripes a
         sharded state; use for invariants and image checks."""
         if self.sharded:
-            from .sharded import unshard_state
             return unshard_state(self.state, self.mesh, self.axis)
         return self.state
 
     def check(self) -> None:
         """Protocol invariants over the (unsharded) state."""
-        from .state import check_invariants
         check_invariants(self.flat_state())
 
     # --------------------------------------------------------- telemetry
@@ -233,8 +246,6 @@ class DevicePlane:
         earlier result.  The flat engine presents ops BY line id, so its
         counters are line-indexed already (identity — the home perm does
         not reorder them)."""
-        import jax
-        from .engine import EVENT_KEYS
         small = {k: v for k, v in tele.items()
                  if k not in ("slot_hits", "slot_whits")}
         slots = (tele["slot_hits"], tele["slot_whits"])
@@ -269,7 +280,6 @@ class DevicePlane:
         (compile events = the TRACE_COUNTS delta over the dispatch).
         Yields a :class:`_Span` the verb fills; a verb that raises
         records no span."""
-        from .engine import TRACE_COUNTS
         rec = self.recorder
         if rec is not None:
             t0, c0 = time.perf_counter(), sum(TRACE_COUNTS.values())
@@ -283,18 +293,42 @@ class DevicePlane:
                        compiled=sum(TRACE_COUNTS.values()) - c0,
                        attrs=span.attrs)
 
-    def _settle(self, sp: _Span, state, done, tele, failure: str) -> None:
+    def _settle(self, sp: _Span, state, tele, *checks) -> None:
         """The tail every fused verb shares: wait for the loop
-        (``plane.sync``), raise ``failure`` if it hit its bound, adopt
-        the new state and wrap the counters, uncopied
-        (``plane.telemetry``)."""
+        (``plane.sync``), raise the failure of the first ``(flag,
+        failure)`` check whose flag is False, adopt the new state and
+        wrap the counters, uncopied (``plane.telemetry``)."""
         with sp.phase("sync"):
-            done = bool(done)
-        if not done:
-            raise RuntimeError(failure)
+            flags = [bool(flag) for flag, _ in checks]
+        for ok, (_, failure) in zip(flags, checks):
+            if not ok:
+                raise RuntimeError(failure)
         self.state = state
         with sp.phase("telemetry"):
             sp.telemetry = self._telemetry(tele)
+
+    def _rows(self, n: int, *cols):
+        """``(array, fill)`` columns of ``n`` rows as host arrays, padded
+        with ``fill`` rows to a multiple of the shard count (``None``
+        passes)."""
+        pad = (-n) % self.n_shards
+        out = []
+        for c, fill in cols:
+            if c is not None:
+                c = np.asarray(c)
+                if pad:
+                    c = np.concatenate(
+                        [c, np.full((pad,) + c.shape[1:], fill, c.dtype)])
+            out.append(c)
+        return out
+
+    def _pad(self, sp: _Span, n: int, *cols):
+        """:meth:`_rows` under the ``plane.pad`` child; one shard pads
+        nothing, so there the columns pass with no child."""
+        if self.n_shards == 1:
+            return [c for c, _ in cols]
+        with sp.phase("pad"):
+            return self._rows(n, *cols)
 
     # ------------------------------------------------------------- verbs
     def ops(self, node_id, line, is_write, wdata=None, *,
@@ -304,33 +338,16 @@ class DevicePlane:
         mr = self.max_rounds if max_rounds is None else max_rounds
         r = np.asarray(line).shape[0]
         with self._span("ops", batch=(r,)) as sp:
-            if self.sharded:
-                from .sharded import pad_ops, run_rounds_sharded
-                with sp.phase("pad"):
-                    if wdata is None:
-                        node_id, line, is_write = pad_ops(
-                            node_id, line, is_write, self.n_shards)
-                    else:
-                        node_id, line, is_write, wdata = pad_ops(
-                            node_id, line, is_write, self.n_shards,
-                            wdata)
-                with sp.phase("dispatch"):
-                    state, versions, data, rounds, done, tele = \
-                        run_rounds_sharded(
-                            self.state, node_id, line, is_write, wdata,
-                            mesh=self.mesh, axis=self.axis,
-                            n_nodes=self.n_nodes, max_rounds=mr,
-                            bucket_cap=self.bucket_cap,
-                            backend=self.backend)
-            else:
-                from .driver import run_rounds
-                with sp.phase("dispatch"):
-                    state, versions, data, rounds, done, tele = \
-                        run_rounds(self.state, node_id, line, is_write,
-                                   wdata, n_nodes=self.n_nodes,
-                                   max_rounds=mr, backend=self.backend)
-            self._settle(sp, state, done, tele,
-                         f"ops not served after {mr} rounds")
+            node_id, line, is_write, wdata = self._pad(
+                sp, r, (node_id, 0), (line, -1), (is_write, 0), (wdata, 0))
+            with sp.phase("dispatch"):
+                state, versions, data, rounds, done, tele = \
+                    self._run["ops"](self.state, node_id, line, is_write,
+                                     wdata, n_nodes=self.n_nodes,
+                                     max_rounds=mr, backend=self.backend,
+                                     **self._geo)
+            self._settle(sp, state, tele,
+                         (done, f"ops not served after {mr} rounds"))
             with sp.phase("results"):
                 sp.rounds = int(rounds)
                 versions = np.asarray(versions)[:r]
@@ -349,37 +366,17 @@ class DevicePlane:
         mr = self.max_rounds if max_rounds is None else max_rounds
         r = np.asarray(line).shape[0]
         with self._span("rmw", batch=(r,)) as sp:
-            if self.sharded:
-                from .sharded import pad_ops, run_rmw_sharded
-                with sp.phase("pad"):
-                    node_id, line, _ = pad_ops(node_id, line,
-                                               np.zeros(r, np.int32),
-                                               self.n_shards)
-                    pad = np.asarray(line).shape[0] - r
-                    if pad:
-                        operands = tuple(
-                            np.concatenate(
-                                [np.asarray(op),
-                                 np.zeros((pad,) + np.asarray(op).shape[1:],
-                                          np.asarray(op).dtype)])
-                            for op in operands)
-                with sp.phase("dispatch"):
-                    state, versions, data, rounds, done, tele = \
-                        run_rmw_sharded(
-                            self.state, node_id, line, tuple(operands),
-                            modify=modify, mesh=self.mesh, axis=self.axis,
-                            n_nodes=self.n_nodes, max_rounds=mr,
-                            bucket_cap=self.bucket_cap,
-                            backend=self.backend)
-            else:
-                from .driver import run_rmw
-                with sp.phase("dispatch"):
-                    state, versions, data, rounds, done, tele = run_rmw(
-                        self.state, node_id, line, tuple(operands),
-                        modify=modify, n_nodes=self.n_nodes,
-                        max_rounds=mr, backend=self.backend)
-            self._settle(sp, state, done, tele,
-                         f"RMW ops not served after {mr} rounds per phase")
+            node_id, line, *operands = self._pad(
+                sp, r, (node_id, 0), (line, -1),
+                *((op, 0) for op in operands))
+            with sp.phase("dispatch"):
+                state, versions, data, rounds, done, tele = \
+                    self._run["rmw"](self.state, node_id, line,
+                                     tuple(operands), modify=modify,
+                                     n_nodes=self.n_nodes, max_rounds=mr,
+                                     backend=self.backend, **self._geo)
+            self._settle(sp, state, tele, (
+                done, f"RMW ops not served after {mr} rounds per phase"))
             with sp.phase("results"):
                 sp.rounds = int(rounds)
                 versions = np.asarray(versions)[:r]
@@ -397,31 +394,17 @@ class DevicePlane:
         ms = self.max_rounds if max_steps is None else max_steps
         r = np.asarray(root).shape[0]
         with self._span("descent", batch=(r,)) as sp:
-            if self.sharded:
-                from .sharded import pad_ops, run_descent_sharded
-                with sp.phase("pad"):
-                    node_id, root, key = pad_ops(node_id, root, key,
-                                                 self.n_shards)
-                with sp.phase("dispatch"):
-                    (state, line, lanes, levels, hops, paths, plen, steps,
-                     done, tele) = run_descent_sharded(
-                            self.state, node_id, key, root,
-                            transition=transition, mesh=self.mesh,
-                            axis=self.axis, n_nodes=self.n_nodes,
-                            max_steps=ms, bucket_cap=self.bucket_cap,
-                            backend=self.backend, path_cap=path_cap)
-            else:
-                from .descent import run_descent
-                with sp.phase("dispatch"):
-                    (state, line, lanes, levels, hops, paths, plen, steps,
-                     done, tele) = run_descent(
-                            self.state, node_id, key, root,
-                            transition=transition, n_nodes=self.n_nodes,
-                            max_steps=ms, backend=self.backend,
-                            path_cap=path_cap)
-            self._settle(sp, state, done, tele,
-                         f"descent did not settle after {ms} steps "
-                         f"(broken links?)")
+            node_id, key, root = self._pad(sp, r, (node_id, 0), (key, 0),
+                                           (root, -1))
+            with sp.phase("dispatch"):
+                (state, line, lanes, levels, hops, paths, plen, steps,
+                 done, tele) = self._run["descent"](
+                    self.state, node_id, key, root, transition=transition,
+                    n_nodes=self.n_nodes, max_steps=ms,
+                    backend=self.backend, path_cap=path_cap, **self._geo)
+            self._settle(sp, state, tele, (
+                done, f"descent did not settle after {ms} steps "
+                      f"(broken links?)"))
             with sp.phase("results"):
                 sp.rounds = int(steps)
                 stats = {"line": np.asarray(line)[:r],
@@ -434,39 +417,66 @@ class DevicePlane:
                            telemetry=sp.telemetry, host_s=sp.host_s)
 
     def txn(self, node_id, *batch, algo, max_iters: int | None = None,
-            max_rounds: int | None = None):
+            max_rounds: int | None = None) -> TxnBatchResult:
         """Run one transaction batch through the fused device CC loop
         (:mod:`repro.core.rounds.txn`): ``algo`` is ``"2pl"`` or
         ``"to"`` (``batch`` = ``glines, rmask, wmask, ts``) or a
         ``TxnProgram`` (``batch`` = what its ``encode`` takes).  The
-        bracket's children ``plane.pad`` (encoding, sharded slot
-        padding), ``dispatch``, ``sync``, ``telemetry`` and ``results``
-        are timed into the returned ``TxnBatchResult.host_s``."""
-        from .txn import run_txn_batch
+        bracket's children ``plane.pad`` (encoding, and slot padding on
+        more than one shard), ``dispatch``, ``sync``, ``telemetry`` and
+        ``results`` are timed into the returned
+        ``TxnBatchResult.host_s``."""
+        mr = self.max_rounds if max_rounds is None else max_rounds
         shape = tuple(np.shape(batch[0])) if batch else ()
         with self._span("txn", batch=shape) as sp:
-            res = run_txn_batch(self, node_id, *batch, algo=algo,
-                                max_iters=max_iters,
-                                max_rounds=max_rounds, span=sp)
-            sp.rounds, sp.telemetry = res.rounds, res.telemetry
-            sp.attrs = {"algo": getattr(algo, "name", algo)}
+            with sp.phase("pad"):
+                program = resolve_program(algo, *batch[:2])
+                typ, inp, lines, mode = program.encode(*batch)
+                if self.payload_width != program.width:
+                    raise ValueError(
+                        f"plane payload_width={self.payload_width} but "
+                        f"program {program.name!r} needs {program.width} "
+                        f"lanes")
+                b = lines.shape[0]
+                node_id = np.broadcast_to(np.asarray(node_id, np.int32),
+                                          (b,)).astype(np.int32)
+                mi = 4 * b + 16 if max_iters is None else max_iters
+                node_id, typ, inp, lines, mode = self._rows(
+                    b, (node_id, 0), (typ, -1), (inp, 0), (lines, -1),
+                    (mode, 0))
+            with sp.phase("dispatch"):
+                (state, dec, estep, retr, outs, lns, mds, alldone, ok,
+                 rounds, tele, ctr) = self._run["txn"](
+                    self.state, node_id, typ, inp, lines, mode,
+                    program=program, n_nodes=self.n_nodes, max_rounds=mr,
+                    max_iters=mi, backend=self.backend, **self._geo)
+            self._settle(
+                sp, state, tele,
+                (ok, f"txn coherence spin hit max_rounds={mr}"),
+                (alldone, f"txn batch not done after {mi} scheduler "
+                          f"iterations (livelock? raise max_iters)"))
+            with sp.phase("results"):
+                counters = {k: int(v) for k, v in
+                            jax.device_get(ctr).items()}
+                counters["attempts"] = b + counters["restarts"]
+                sp.rounds = int(rounds)
+                res = TxnBatchResult(
+                    np.asarray(dec)[:b], np.asarray(estep)[:b],
+                    np.asarray(retr)[:b], counters["iters"], sp.rounds,
+                    sp.telemetry, np.asarray(outs)[:b],
+                    np.asarray(lns)[:b], np.asarray(mds)[:b], counters,
+                    sp.host_s)
+            sp.attrs = {"algo": program.name}
         return res
 
     def evict(self, node_id, line) -> None:
         """Evict (node, line) pairs: release holder latches, flushing
         dirty write-back copies first."""
         r = np.asarray(line).shape[0]
-        with self._span("evict", batch=(r,)):
-            if self.sharded:
-                from .sharded import evict_lines_sharded, pad_ops
-                node_id, line, _ = pad_ops(
-                    node_id, line, np.zeros(r, np.int32), self.n_shards)
-                self.state = evict_lines_sharded(
-                    self.state, node_id, line, mesh=self.mesh,
-                    axis=self.axis, bucket_cap=self.bucket_cap)
-            else:
-                from .engine import evict_lines
-                self.state = evict_lines(self.state, node_id, line)
+        with self._span("evict", batch=(r,)) as sp:
+            node_id, line = self._pad(sp, r, (node_id, 0), (line, -1))
+            self.state = self._run["evict"](self.state, node_id, line,
+                                            **self._geo)
 
     # -------------------------------------------------------- placement
     def rehome(self, lines, new_homes, victims=None) -> int:
@@ -525,7 +535,6 @@ class DevicePlane:
         if not src:
             return 0
         if self.sharded:
-            from .sharded import rehome_exchange
             # pad the move list to a power of two: one compiled
             # exchange shape serves many migration sizes
             m = 1
@@ -537,7 +546,6 @@ class DevicePlane:
                 self.state, src, dst, perm.astype(np.int32),
                 mesh=self.mesh, axis=self.axis)
         else:
-            import jax.numpy as jnp
             self.state = dict(self.state)
             self.state["home"] = jnp.asarray(perm, jnp.int32)
         return len(taken) // 2
@@ -556,9 +564,6 @@ class DevicePlane:
             raise ValueError(
                 "replicate needs a replica-plane state "
                 "(make_state(..., replicas=True))")
-        import jax
-        import jax.numpy as jnp
-        from .. import coherence as co
         lines = np.asarray(lines, np.int64).reshape(-1)
         l = self.n_lines
         if lines.size and (lines.min() < 0 or lines.max() >= l):
@@ -578,9 +583,6 @@ class DevicePlane:
                 flat["replica_data"]).astype(np.int32)
         self.state = dict(self.state)
         if self.sharded:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from .sharded import auto_mesh
             mesh = auto_mesh(self.mesh)
             for k, v in leaves.items():
                 self.state[k] = jax.device_put(

@@ -1,10 +1,8 @@
 """Mesh-sharded device rounds: the FULL MSI engine, striped across shards.
 
-`core/distributed_rounds.py` shards only the bare latch plane — one
-latch-kernel application per round, overflow deferral punted to the
-caller.  This module scales the complete PR-2 rounds engine — S->X
-upgrade via CAS, structural write-back with dirty-bit flush,
-per-(node, line) coalescing, eviction — across a ``shard_map`` mesh:
+This module scales the complete rounds engine — S->X upgrade via CAS,
+structural write-back with dirty-bit flush, per-(node, line)
+coalescing, eviction — across a ``shard_map`` mesh:
 
 * every line-indexed leaf of the round state lives in PHYSICAL-SLOT
   layout: line ``l`` occupies slot ``p`` — ``p = state["home"][l]``
@@ -28,9 +26,11 @@ per-(node, line) coalescing, eviction — across a ``shard_map`` mesh:
   line, isw) to carry a [W] ``wdata`` lane and the reply routes the
   read bytes back — the data plane rides the SAME two collectives as
   the latch traffic, no separate host-mediated copy channel;
-* the whole spin lives in ONE jitted ``lax.while_loop``: the carry
-  (sharded state, pending lines, versions, a psum'd done flag) never
-  leaves the devices — zero host<->device syncs per round, and
+* the whole spin lives in ONE jitted ``lax.while_loop`` — the one
+  ``driver.spin`` (and ``descent.descend``) the flat plane runs, with
+  :func:`_route_round` as its step and a psum as its count: the carry
+  (sharded state, pending lines, versions, the psum'd pending count)
+  never leaves the devices — zero host<->device syncs per round, and
   ``engine.TRACE_COUNTS`` proves one trace per shape;
 * at every round boundary each home rebuilds the latch words of the
   lines its round named from their local MSI states
@@ -45,7 +45,7 @@ per-(node, line) coalescing, eviction — across a ``shard_map`` mesh:
   home) bucket occupancy and defer counts, per-home served ops,
   per-slot hit counters, replica-served counts — surfaced as the last
   element of every fused driver's return tuple and, host-side, through
-  ``PlaneResult.stats``.  The placement policy
+  ``PlaneResult.telemetry``.  The placement policy
   (:mod:`repro.core.rounds.placement`) turns it into re-homing and
   replication decisions;
 * a state with a read-replica plane (``make_state(...,
@@ -71,8 +71,10 @@ import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .. import coherence as co
-from ..distributed_rounds import _bucket
 from . import state as st
+from .descent import descend
+from .driver import (_tele_rows, _zero_tele, op_arrays, spin,
+                     tele_dict, two_phase)
 from .engine import _evict_impl, _note_trace, _round_impl
 
 OP_FIELDS = ("node", "line", "isw")
@@ -176,35 +178,50 @@ def pad_ops(node_id, line, is_write, n_shards: int, wdata=None):
 
 # ------------------------------------------------------------ one round
 
-TELE_KEYS = ("occupancy", "deferred", "served_per_home", "replica_served",
-             "slot_hits", "slot_whits", "events")
+def _bucket(requests, n_shards: int, cap: int, fields, home=None):
+    """Sort each shard's local requests into per-home buckets [S, cap].
 
-
-def _zero_tele(n_shards: int, l_local: int):
-    """Zeroed telemetry accumulator — matches `_route_round`'s
-    per-round deltas: (occupancy[S], deferred[S], served_at_home,
-    replica_served, slot_hits[L_local], slot_whits[L_local],
-    events[3]), ``events`` in ``engine.EVENT_KEYS`` order."""
-    z = jnp.zeros((n_shards,), jnp.int32)
-    zl = jnp.zeros((l_local,), jnp.int32)
-    return (z, z, jnp.int32(0), jnp.int32(0), zl, zl,
-            jnp.zeros((3,), jnp.int32))
-
-
-def _add_tele(a, b):
-    return tuple(x + y for x, y in zip(a, b))
-
-
-def _tele_rows(tele):
-    """A shard's accumulated telemetry tuple as ``shard_map`` outputs
-    under :func:`_tele_specs`: a per-home row of every scalar and
-    [S]-vector counter, the slab's per-slot counters as they are."""
-    occ, dfr, srv, rsrv, hits, whits, events = tele
-    return (occ[None, :], dfr[None, :], srv[None], rsrv[None], hits,
-            whits, events[None, :])
+    ``fields`` selects which request leaves ride along (the round routes
+    (node, line, isw) plus, on payload-plane states, a [R, W] ``wdata``
+    lane — any field may carry trailing dimensions and buckets to [S,
+    cap, \\*rest]).  ``home`` is the per-slot destination shard ([R]
+    int32, ``n_shards`` = pad/no-send); when omitted it defaults to the
+    static stripe placement ``home = line % n_shards`` derived from
+    ``requests["line"]``.  Requests past a bucket's capacity are NOT
+    silently sent: they show up in the returned ``keep`` mask (False in
+    sorted order; ``keep[argsort(order)]`` is the per-original-slot sent
+    mask) and the ``dropped`` count, so callers respin them."""
+    line = requests["line"]
+    if home is None:
+        home = jnp.where(line >= 0, line % n_shards, n_shards)  # pad bucket
+    order = jnp.argsort(home)                                # stable
+    sorted_reqs = {k: requests[k][order] for k in fields}
+    home_sorted = home[order]
+    # slot within bucket
+    onehot = jax.nn.one_hot(home_sorted, n_shards + 1, dtype=jnp.int32)
+    slot = jnp.take_along_axis(jnp.cumsum(onehot, 0) - 1,
+                               home_sorted[:, None], 1)[:, 0]
+    keep = jnp.logical_and(home_sorted < n_shards, slot < cap)
+    # non-kept slots (pads, overflow) scatter OUT OF BOUNDS and drop —
+    # routing them to a real bucket cell would let a pad/overflow slot
+    # clobber a legitimate request whenever its bucket was exactly full
+    # (scatter order is unspecified)
+    b_idx = jnp.where(keep, home_sorted, n_shards)
+    s_idx = jnp.where(keep, slot, 0)
+    out = {}
+    for k in fields:
+        v = sorted_reqs[k]
+        init = jnp.full((n_shards, cap) + v.shape[1:],
+                        -1 if k == "line" else 0, jnp.int32)
+        out[k] = init.at[b_idx, s_idx].set(v, mode="drop")
+    dropped = jnp.sum(jnp.logical_and(home_sorted < n_shards,
+                                      ~keep).astype(jnp.int32))
+    return out, order, keep, (b_idx, s_idx), dropped
 
 
 def _tele_specs(axis: str):
+    """``shard_map`` out_specs of ``driver._tele_rows``: the rows of
+    every home stacked, the slabs' slot counters concatenated."""
     return (P(axis, None), P(axis, None), P(axis), P(axis), P(axis),
             P(axis), P(axis, None))
 
@@ -255,7 +272,7 @@ def _route_round(state_l, node_l, pending_l, isw_l, wdata_l, *,
     each served slot's read payload back the same way.  Returns
     (state_l', served[r] bool, version[r], data[r, W], tele) in local
     slot order — ``tele`` is this round's telemetry delta (see
-    :func:`_zero_tele`); a slot that overflowed its bucket simply comes
+    ``driver._zero_tele``); a slot that overflowed its bucket simply comes
     back unserved (its payload re-presents with it next round)."""
     width = wdata_l.shape[1]
     l_local = state_l["words"].shape[0]
@@ -362,6 +379,26 @@ def _route_round(state_l, node_l, pending_l, isw_l, wdata_l, *,
     return state_l, served, version, r_data, tele
 
 
+def _split(mesh, axis: str, n: int, bucket_cap, what: str = "R"):
+    """``(n_shards, cap)`` for ``n`` global slots over ``mesh[axis]``:
+    each shard presents ``n / S`` of them, and a bucket holds
+    ``bucket_cap`` (by default a shard's every slot: none overflows)."""
+    n_shards = mesh.shape[axis]
+    if n % n_shards:
+        raise ValueError(f"{what}={n} not divisible by "
+                         f"n_shards={n_shards} (use pad_ops)")
+    return n_shards, n // n_shards if bucket_cap is None else bucket_cap
+
+
+def _router(n_shards: int, axis: str, n_nodes: int, cap: int,
+            backend: str):
+    """The mesh's round step (``driver.spin``'s contract) and its count
+    lift, for use inside ``shard_map``."""
+    return (functools.partial(_route_round, n_shards=n_shards, axis=axis,
+                              n_nodes=n_nodes, cap=cap, backend=backend),
+            functools.partial(jax.lax.psum, axis_name=axis))
+
+
 @functools.partial(
     jax.jit, static_argnames=("mesh", "axis", "n_nodes", "bucket_cap",
                               "backend"))
@@ -378,43 +415,24 @@ def coherence_round_sharded(state, node_id, line, is_write, wdata=None,
     the fused driver against.  Overflowed slots return unserved (the
     caller respins them, payload included)."""
     co.check_node_capacity(n_nodes)
-    n_shards = mesh.shape[axis]
-    node_id = jnp.asarray(node_id, jnp.int32)
-    line = jnp.asarray(line, jnp.int32)
-    is_write = jnp.asarray(is_write, jnp.int32)
-    r_total = line.shape[0]
-    if r_total % n_shards:
-        raise ValueError(f"R={r_total} not divisible by "
-                         f"n_shards={n_shards} (use pad_ops)")
-    r = r_total // n_shards
-    cap = bucket_cap if bucket_cap is not None else r
-    width = st.payload_width(state)
-    if wdata is None:
-        wdata = jnp.zeros((r_total, width), jnp.int32)
-    else:
-        wdata = jnp.asarray(wdata, jnp.int32)
-    write_back = "dirty" in state
+    ops = op_arrays(state, node_id, line, is_write, wdata)
+    r_total = ops[1].shape[0]
+    n_shards, cap = _split(mesh, axis, r_total, bucket_cap)
     _note_trace(("sharded_round", n_shards, n_nodes,
                  state["words"].shape[0], r_total, cap, backend,
-                 write_back, width, "home" in state, "replica" in state))
+                 "dirty" in state, st.payload_width(state),
+                 "home" in state, "replica" in state))
     specs = _state_specs(state, axis)
-
-    def spmd(state_l, node_l, line_l, isw_l, wdata_l):
-        state_l, served, ver, data, _ = _route_round(
-            state_l, node_l, line_l, isw_l, wdata_l,
-            n_shards=n_shards, axis=axis, n_nodes=n_nodes,
-            cap=cap, backend=backend)
-        return state_l, served, ver, data
-
+    step, _ = _router(n_shards, axis, n_nodes, cap, backend)
     return jax.shard_map(
-        spmd, mesh=auto_mesh(mesh),
-        in_specs=(specs, P(axis), P(axis), P(axis), P(axis)),
+        lambda *a: step(*a)[:4], mesh=auto_mesh(mesh),
+        in_specs=(specs,) + (P(axis),) * 4,
         out_specs=(specs, P(axis), P(axis), P(axis)),
         check_vma=False,
-    )(state, node_id, line, is_write, wdata)
+    )(state, *ops)
 
 
-# ------------------------------------------------------- the fused driver
+# ------------------------------------------------------ the fused drivers
 
 @functools.partial(
     jax.jit, static_argnames=("mesh", "axis", "n_nodes", "max_rounds",
@@ -425,7 +443,8 @@ def run_rounds_sharded(state, node_id, line, is_write, wdata=None, *,
                        bucket_cap: int | None = None,
                        backend: str = "ref"):
     """Drive GLOBAL op slots [R] to completion across the mesh in ONE
-    jit call — the sharded mirror of :func:`driver.run_rounds`.
+    jit call — :func:`driver.run_rounds`' spin with the routed round as
+    its step.
 
     ``wdata`` [R, W] carries per-op write payloads on a payload-plane
     state; returns ``(state', versions[R], data[R, W], rounds_used,
@@ -441,67 +460,33 @@ def run_rounds_sharded(state, node_id, line, is_write, wdata=None, *,
     body's protocol events at each home (``engine.EVENT_KEYS``).
     Unserved slots (latch contention
     OR bucket overflow) re-present themselves — bytes included — round
-    after round inside the fused ``lax.while_loop``; the done flag is a
-    psum across shards, so the loop runs lockstep until every shard's
-    slots are served or ``max_rounds`` is hit."""
+    after round inside the fused ``lax.while_loop``; the pending count
+    is a psum across shards, so the loop runs lockstep until every
+    shard's slots are served or ``max_rounds`` is hit."""
     co.check_node_capacity(n_nodes)
-    n_shards = mesh.shape[axis]
-    node_id = jnp.asarray(node_id, jnp.int32)
-    line = jnp.asarray(line, jnp.int32)
-    is_write = jnp.asarray(is_write, jnp.int32)
-    r_total = line.shape[0]
-    if r_total % n_shards:
-        raise ValueError(f"R={r_total} not divisible by "
-                         f"n_shards={n_shards} (use pad_ops)")
-    r = r_total // n_shards
-    cap = bucket_cap if bucket_cap is not None else r
-    width = st.payload_width(state)
-    if wdata is None:
-        wdata = jnp.zeros((r_total, width), jnp.int32)
-    else:
-        wdata = jnp.asarray(wdata, jnp.int32)
-    write_back = "dirty" in state
+    ops = op_arrays(state, node_id, line, is_write, wdata)
+    r_total = ops[1].shape[0]
+    n_shards, cap = _split(mesh, axis, r_total, bucket_cap)
     _note_trace(("sharded", n_shards, n_nodes, state["words"].shape[0],
-                 r_total, cap, max_rounds, backend, write_back, width,
-                 "home" in state, "replica" in state))
+                 r_total, cap, max_rounds, backend, "dirty" in state,
+                 st.payload_width(state), "home" in state,
+                 "replica" in state))
     specs = _state_specs(state, axis)
     l_local = state["words"].shape[0] // n_shards
 
-    def spmd(state_l, node_l, line_l, isw_l, wdata_l):
-        def n_pending(pending):
-            return jax.lax.psum(
-                jnp.sum((pending >= 0).astype(jnp.int32)), axis)
-
-        def cond(carry):
-            _, pending, _, _, rounds, _, done = carry
-            return jnp.logical_and(~done, rounds < max_rounds)
-
-        def body(carry):
-            stt, pending, versions, data, rounds, tele, _ = carry
-            stt, served, ver, rdata, dtele = _route_round(
-                stt, node_l, pending, isw_l, wdata_l, n_shards=n_shards,
-                axis=axis, n_nodes=n_nodes, cap=cap, backend=backend)
-            versions = jnp.where(served, ver, versions)
-            data = jnp.where(served[:, None], rdata, data)
-            pending = jnp.where(served, jnp.int32(-1), pending)
-            return (stt, pending, versions, data, rounds + 1,
-                    _add_tele(tele, dtele), n_pending(pending) == 0)
-
-        init = (state_l, line_l, jnp.zeros_like(line_l),
-                jnp.zeros((line_l.shape[0], width), jnp.int32),
-                jnp.int32(0), _zero_tele(n_shards, l_local),
-                n_pending(line_l) == 0)
-        state_l, pending, versions, data, rounds, tele, done = \
-            jax.lax.while_loop(cond, body, init)
-        return (state_l, versions, data, rounds, done) + _tele_rows(tele)
+    def spmd(state_l, *ops_l):
+        *out, tele = spin(*_router(n_shards, axis, n_nodes, cap, backend),
+                          state_l, *ops_l, max_rounds=max_rounds,
+                          tele0=_zero_tele(n_shards, l_local))
+        return (*out, *_tele_rows(tele))
 
     out = jax.shard_map(
         spmd, mesh=auto_mesh(mesh),
-        in_specs=(specs, P(axis), P(axis), P(axis), P(axis)),
+        in_specs=(specs,) + (P(axis),) * 4,
         out_specs=(specs, P(axis), P(axis), P(), P()) + _tele_specs(axis),
         check_vma=False,
-    )(state, node_id, line, is_write, wdata)
-    return out[:5] + (dict(zip(TELE_KEYS, out[5:])),)
+    )(state, *ops)
+    return out[:5] + (tele_dict(out[5:]),)
 
 
 @functools.partial(
@@ -511,31 +496,24 @@ def run_rmw_sharded(state, node_id, line, operands=(), *, modify, mesh,
                     axis: str = "shards", n_nodes: int,
                     max_rounds: int = 64, bucket_cap: int | None = None,
                     backend: str = "ref"):
-    """Sharded mirror of :func:`repro.core.rounds.driver.run_rmw`: the
-    coherent read-modify-write's two fused spin loops (S-grant read,
-    ``modify``, S->X upgrade write) run back to back inside ONE jit
-    call, each crossing the mesh through the usual two all_to_alls per
-    round.  ``modify(data, line, *operands)`` runs replicated between
-    the phases on the gathered ``[R, W]`` reply bytes.  Same return
-    contract as :func:`run_rounds_sharded` (telemetry summed over both
-    phases), with the write phase's versions/bytes."""
+    """Sharded form of :func:`repro.core.rounds.driver.run_rmw`: the
+    same two phases over :func:`run_rounds_sharded`, each crossing the
+    mesh through the usual two all_to_alls per round.  ``modify(data,
+    line, *operands)`` runs replicated between the phases on the
+    gathered ``[R, W]`` reply bytes.  Same return contract as
+    :func:`run_rounds_sharded` (telemetry summed over both phases),
+    with the write phase's versions/bytes."""
     node_id = jnp.asarray(node_id, jnp.int32)
     line = jnp.asarray(line, jnp.int32)
     _note_trace(("rmw_sharded", modify, mesh.shape[axis], n_nodes,
                  state["words"].shape[0], line.shape[0], bucket_cap,
                  backend, "dirty" in state, st.payload_width(state),
                  "home" in state, "replica" in state))
-    state, _, data, r1, ok1, t1 = run_rounds_sharded(
-        state, node_id, line, jnp.zeros_like(line), None, mesh=mesh,
-        axis=axis, n_nodes=n_nodes, max_rounds=max_rounds,
-        bucket_cap=bucket_cap, backend=backend)
-    new_data = jnp.asarray(modify(data, line, *operands), jnp.int32)
-    state, versions, data2, r2, ok2, t2 = run_rounds_sharded(
-        state, node_id, line, jnp.ones_like(line), new_data, mesh=mesh,
-        axis=axis, n_nodes=n_nodes, max_rounds=max_rounds,
-        bucket_cap=bucket_cap, backend=backend)
-    return (state, versions, data2, r1 + r2,
-            jnp.logical_and(ok1, ok2), {k: t1[k] + t2[k] for k in t1})
+    return two_phase(
+        functools.partial(run_rounds_sharded, mesh=mesh, axis=axis,
+                          n_nodes=n_nodes, max_rounds=max_rounds,
+                          bucket_cap=bucket_cap, backend=backend),
+        state, node_id, line, operands, modify)
 
 
 @functools.partial(
@@ -547,104 +525,52 @@ def run_descent_sharded(state, node_id, key, root, *, transition, mesh,
                         max_steps: int = 64,
                         bucket_cap: int | None = None,
                         backend: str = "ref", path_cap: int = 16):
-    """Sharded mirror of :func:`repro.core.rounds.descent.run_descent`:
-    the whole root-to-leaf wavefront runs inside ONE jit call on the
-    mesh.  Each outer iteration routes every undone slot's S-latch read
-    to its line's home shard through the usual two all_to_alls
+    """Sharded form of :func:`repro.core.rounds.descent.run_descent`:
+    the same wavefront loop (``descent.descend``) inside ONE jit call on
+    the mesh.  Each outer iteration routes every undone slot's S-latch
+    read to its line's home shard through the usual two all_to_alls
     (`_route_round`), then applies the caller's ``transition`` to the
     replies LOCALLY on the slot's own shard — slots never migrate, only
     their requests do, so the per-slot carry (current line, path
-    buffer, level/hop counters) stays put and the done flag is the one
-    psum.  A slot whose read lost a latch race OR overflowed its
+    buffer, level/hop counters) stays put and the undone count is the
+    one psum.  A slot whose read lost a latch race OR overflowed its
     routing bucket simply re-presents next iteration.  Same return
     contract as ``run_descent`` (slots in global order, ``steps`` and
-    ``all_done`` replicated) plus a trailing telemetry dict (the
-    :func:`run_rounds_sharded` congestion counters, accumulated over
-    every descent step)."""
+    ``all_done`` replicated), with the :func:`run_rounds_sharded`
+    congestion counters accumulated over every descent step."""
     co.check_node_capacity(n_nodes)
-    n_shards = mesh.shape[axis]
     node_id = jnp.asarray(node_id, jnp.int32)
     key = jnp.asarray(key, jnp.int32)
     root = jnp.asarray(root, jnp.int32)
     r_total = root.shape[0]
-    if r_total % n_shards:
-        raise ValueError(f"B={r_total} not divisible by "
-                         f"n_shards={n_shards} (use pad_ops)")
-    r = r_total // n_shards
-    cap = bucket_cap if bucket_cap is not None else r
+    n_shards, cap = _split(mesh, axis, r_total, bucket_cap, "B")
     width = st.payload_width(state)
     if not width:
         raise ValueError("run_descent_sharded needs a payload-plane "
                          "state (the transition decodes node bytes)")
-    write_back = "dirty" in state
     _note_trace(("descent_sharded", transition, n_shards, n_nodes,
                  state["words"].shape[0], r_total, cap, max_steps,
-                 backend, write_back, width, path_cap,
+                 backend, "dirty" in state, width, path_cap,
                  "home" in state, "replica" in state))
     specs = _state_specs(state, axis)
     l_local = state["words"].shape[0] // n_shards
 
     def spmd(state_l, node_l, key_l, root_l):
-        b = root_l.shape[0]
-        no_write = jnp.zeros((b,), jnp.int32)
-        no_bytes = jnp.zeros((b, width), jnp.int32)
-
-        def n_undone(done):
-            return jax.lax.psum(jnp.sum((~done).astype(jnp.int32)),
-                                axis)
-
-        def cond(carry):
-            _, _, _, _, _, _, _, _, steps, _, gdone = carry
-            return jnp.logical_and(~gdone, steps < max_steps)
-
-        def body(carry):
-            (stt, cur, done, lanes, levels, hops, paths, plen, steps,
-             tele, _) = carry
-            line = jnp.where(done, jnp.int32(-1), cur)
-            stt, served, _, d, dtele = _route_round(
-                stt, node_l, line, no_write, no_bytes,
-                n_shards=n_shards, axis=axis, n_nodes=n_nodes, cap=cap,
-                backend=backend)
-            at_leaf, hop, nxt = transition(d, key_l)
-            move = jnp.logical_and(served, ~done)
-            hop = jnp.logical_and(move, hop)
-            at_leaf = jnp.logical_and(move, at_leaf)
-            desc = jnp.logical_and(
-                move, jnp.logical_and(~hop, ~at_leaf))
-            lanes = jnp.where(at_leaf[:, None], d, lanes)
-            row = jnp.where(desc, jnp.arange(b), b)
-            paths = paths.at[row, jnp.minimum(plen, path_cap - 1)].set(
-                cur, mode="drop")
-            plen = plen + desc.astype(jnp.int32)
-            levels = levels + desc.astype(jnp.int32)
-            hops = hops + hop.astype(jnp.int32)
-            done = jnp.logical_or(done, at_leaf)
-            advance = jnp.logical_and(move, ~at_leaf)
-            cur = jnp.where(advance, nxt, cur)
-            return (stt, cur, done, lanes, levels, hops, paths, plen,
-                    steps + 1, _add_tele(tele, dtele),
-                    n_undone(done) == 0)
-
-        done0 = root_l < 0
-        init = (state_l, root_l, done0,
-                jnp.zeros((b, width), jnp.int32),
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.full((b, path_cap), -1, jnp.int32),
-                jnp.zeros((b,), jnp.int32), jnp.int32(0),
-                _zero_tele(n_shards, l_local), n_undone(done0) == 0)
-        (state_l, cur, _, lanes, levels, hops, paths, plen, steps,
-         tele, gdone) = jax.lax.while_loop(cond, body, init)
-        return (state_l, cur, lanes, levels, hops, paths, plen, steps,
-                gdone) + _tele_rows(tele)
+        *out, tele = descend(
+            *_router(n_shards, axis, n_nodes, cap, backend), state_l,
+            node_l, key_l, root_l, transition=transition,
+            max_steps=max_steps, path_cap=path_cap,
+            tele0=_zero_tele(n_shards, l_local))
+        return (*out, *_tele_rows(tele))
 
     out = jax.shard_map(
         spmd, mesh=auto_mesh(mesh),
         in_specs=(specs, P(axis), P(axis), P(axis)),
-        out_specs=(specs, P(axis), P(axis), P(axis), P(axis), P(axis),
-                   P(axis), P(), P()) + _tele_specs(axis),
+        out_specs=(specs,) + (P(axis),) * 6 + (P(), P())
+        + _tele_specs(axis),
         check_vma=False,
     )(state, node_id, key, root)
-    return out[:9] + (dict(zip(TELE_KEYS, out[9:])),)
+    return out[:9] + (tele_dict(out[9:]),)
 
 
 # --------------------------------------------------------------- eviction
@@ -659,17 +585,11 @@ def evict_lines_sharded(state, node_id, line, *, mesh,
     defers and respins) and applied to the local slabs — releasing the
     holder's latch and flushing dirty exclusive copies first in
     write-back states.  Returns the new sharded state."""
-    n_shards = mesh.shape[axis]
     node_id = jnp.asarray(node_id, jnp.int32)
     line = jnp.asarray(line, jnp.int32)
-    r_total = line.shape[0]
-    if r_total % n_shards:
-        raise ValueError(f"R={r_total} not divisible by "
-                         f"n_shards={n_shards} (use pad_ops)")
-    r = r_total // n_shards
-    cap = bucket_cap if bucket_cap is not None else r
+    n_shards, cap = _split(mesh, axis, line.shape[0], bucket_cap)
     # evictions always land once routed: ceil(r / cap) rounds suffice
-    max_iters = (r + cap - 1) // cap
+    max_iters = -(-line.shape[0] // n_shards // cap)
     specs = _state_specs(state, axis)
 
     def spmd(state_l, node_l, line_l):

@@ -57,13 +57,14 @@ the line), so their effects commute.  ``tests/test_txn_device.py`` and
 against plain references replayed in that order, on flat and 4-shard
 planes.
 
-The sharded mirror (:func:`run_txn_rounds_sharded`) runs the SAME
-iteration inside one ``shard_map``: per-transaction state stays on its
-shard, every grant and write decision is computed from an
-``all_gather`` of the batch's entry metadata (global slot order is
-preserved by the block distribution, so the decisions are the flat
-plane's), each spin is the ``_route_round`` two-all_to_alls loop, and
-liveness is a psum.
+The scheduler loop (``_run``) is written once.  The flat entry point
+binds it to one engine round per spin step; the sharded one
+(:func:`run_txn_rounds_sharded`) runs it inside one ``shard_map``:
+per-transaction state stays on its shard, every grant and write
+decision is computed from an ``all_gather`` of the batch's entry
+metadata (global slot order is preserved by the block distribution, so
+the decisions are the flat plane's), each spin routes its rounds
+(``sharded._route_round``), and liveness is a psum.
 
 The generic tuple-header cases (``algo="2pl"`` / ``"to"``, lane 1 a
 committed-writes counter, lanes ``2 + 2t, 3 + 2t`` tuple ``t``'s
@@ -82,10 +83,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .. import coherence as co
-from .driver import add_tele, run_rounds, zero_flat_tele
+from .driver import (TELE_KEYS, _add_tele, _same, _tele_rows, _zero_tele,
+                     flat_step, spin, tele_dict)
 from .engine import _note_trace
-from .sharded import (TELE_KEYS, _add_tele, _route_round, _state_specs,
-                      _tele_rows, _tele_specs, _zero_tele, auto_mesh)
+from .sharded import _router, _state_specs, _tele_specs, auto_mesh
 from .state import payload_width
 
 LOCK_LANE = 0
@@ -295,15 +296,15 @@ def _writes(lines, mode, held, granted, complete, failed, word, n_lines):
     return rep, new_word, counts
 
 
-def _chunked(spin, chunk: int, tele0, tele_add, total=lambda x: x):
+def _chunked(spin, chunk: int, tele0, total):
     """``spin`` over only the live slots (``line >= 0``) of a spin's
     slots, compacted to the front and presented ``chunk`` at a time, as
     many chunks as the most-loaded shard needs (``total`` lifts a local
-    count to that maximum; ``tele0``/``tele_add`` the spin's telemetry
-    zero and sum).  Reads commute and a write spin writes each
-    line once, so chunks may run one after another; a spin the ``ref``
-    latch kernel serves costs a sequential step per presented slot, so
-    this keeps a round's cost to the lines in flight, not ``B * M``."""
+    count to that maximum; ``tele0`` the spin's telemetry zero).
+    Reads commute and a write spin writes each line once, so chunks may
+    run one after another; a spin the ``ref`` latch kernel serves costs
+    a sequential step per presented slot, so this keeps a round's cost
+    to the lines in flight, not ``B * M``."""
     def run(stt, nodes, lines, is_write, wdata):
         n = lines.shape[0]
         n_pad = -(-n // chunk) * chunk
@@ -329,7 +330,7 @@ def _chunked(spin, chunk: int, tele0, tele_add, total=lambda x: x):
             stt, d, r1, ok1, t1 = spin(
                 stt, sl(nc), sl(lc), sl(wc), None if dc is None else sl(dc))
             data = jax.lax.dynamic_update_slice_in_dim(data, d, at, 0)
-            return stt, k + 1, data, r + r1, ok & ok1, tele_add(tele, t1)
+            return stt, k + 1, data, r + r1, ok & ok1, _add_tele(tele, t1)
 
         init = (stt, jnp.int32(0), jnp.zeros((n_pad, w), jnp.int32),
                 jnp.int32(0), jnp.bool_(True), tele0)
@@ -344,8 +345,7 @@ def _zero_counters():
     return {k: jnp.int32(0) for k in COUNTER_KEYS}
 
 
-def _iteration(program, n_lines, carry, batch, *, spin, gather, mine,
-               spin_tele_add):
+def _iteration(program, n_lines, carry, batch, *, spin, gather, mine):
     """One scheduler iteration on this shard's transactions (all of them
     on a flat plane).  ``gather`` lifts a per-transaction array to the
     whole batch (identity flat, ``all_gather`` sharded), ``mine`` slices
@@ -427,7 +427,7 @@ def _iteration(program, n_lines, carry, batch, *, spin, gather, mine,
             lanes,
             jnp.where(complete[:, None], fx_out, out),
             it + 1, ok_spins & ok1 & ok2, rounds + r1 + r2,
-            spin_tele_add(tele, t1, t2), ctr)
+            _add_tele(_add_tele(tele, t1), t2), ctr)
 
 
 def _init_carry(program, state_l, batch, tele0, w):
@@ -447,7 +447,44 @@ def _as_batch(node_id, typ, inp, lines, mode):
             jnp.asarray(mode, jnp.int32))
 
 
-# ------------------------------------------------------- the flat driver
+def _run(program, state, batch, *, n_lines, step, count, gather, mine,
+         total, chunk, tele0, max_rounds, max_iters):
+    """The scheduler loop over this shard's transactions (all of them on
+    one chip): ``step``/``count`` are ``driver.spin``'s round step and
+    count lift, ``gather``/``mine`` lift a per-transaction array to the
+    whole batch and back, ``total`` lifts a chunk count to its maximum
+    over shards.  Returns the entry points' first ten results, the
+    telemetry rows and the counters."""
+    w = payload_width(state)
+
+    def raw_spin(stt, nodes, lns, is_write, wdata):
+        if wdata is None:
+            wdata = jnp.zeros((lns.shape[0], w), jnp.int32)
+        stt, _, data, r, ok, tele = spin(step, count, stt, nodes, lns,
+                                         is_write, wdata,
+                                         max_rounds=max_rounds, tele0=tele0)
+        return stt, data, r, ok, tele
+    chunked = _chunked(raw_spin, chunk, tele0, total)
+
+    def left(done):
+        return count(jnp.sum((~done).astype(jnp.int32)))
+
+    def cond(c):
+        carry, n = c
+        return (n > 0) & (carry[12] < max_iters) & carry[13]
+
+    def body(c):
+        carry = _iteration(program, n_lines, c[0], batch, spin=chunked,
+                           gather=gather, mine=mine)
+        return carry, left(carry[2])
+
+    init = _init_carry(program, state, batch, tele0, w)
+    carry, n = jax.lax.while_loop(cond, body, (init, left(init[2])))
+    (state, _, _, dec, estep, retr, lns, mds, _, _, _, outs, _, ok,
+     rounds, tele, ctr) = carry
+    return ((state, dec, estep, retr, outs, lns, mds, n == 0, ok, rounds),
+            _tele_rows(tele), ctr)
+
 
 @functools.partial(jax.jit,
                    static_argnames=("program", "n_nodes", "max_rounds",
@@ -465,43 +502,24 @@ def run_txn_rounds(state, node_id, typ, inp, lines, mode, *, program,
     no-wait restarts; ``lines``/``mode`` the entries each txn completed
     with (every phase's); ``spins_ok`` False means an inner coherence
     spin hit ``max_rounds`` (results invalid);
-    ``telemetry`` the flat counter dict summed over every spin;
-    ``counters`` the :data:`COUNTER_KEYS` program counters."""
+    ``telemetry`` the counter dict (``driver.TELE_KEYS``) summed over
+    every spin; ``counters`` the :data:`COUNTER_KEYS` program
+    counters."""
     co.check_node_capacity(n_nodes)
     batch = _as_batch(node_id, typ, inp, lines, mode)
     B, M = batch[3].shape
-    W = payload_width(state)
     n_lines = state["words"].shape[0]
     _note_trace(("txn", program.name, B, M, program.inputs, n_nodes,
-                 max_rounds, max_iters, backend, "dirty" in state, W))
+                 max_rounds, max_iters, backend, "dirty" in state,
+                 payload_width(state)))
+    out, tele, ctr = _run(
+        program, state, batch, n_lines=n_lines,
+        step=flat_step(n_nodes, backend), count=_same, gather=_same,
+        mine=_same, total=_same, chunk=min(B * M, SPIN_SLOTS),
+        tele0=_zero_tele(1, n_lines), max_rounds=max_rounds,
+        max_iters=max_iters)
+    return out + (tele_dict(tele), ctr)
 
-    def raw_spin(stt, nodes, lns, is_write, wdata):
-        stt, _, data, r, ok, tl = run_rounds(
-            stt, nodes, lns, is_write, wdata, n_nodes=n_nodes,
-            max_rounds=max_rounds, backend=backend)
-        return stt, data, r, ok, tl
-    spin = _chunked(raw_spin, min(B * M, SPIN_SLOTS),
-                    zero_flat_tele(n_lines), add_tele)
-
-    def cond(carry):
-        return ~jnp.all(carry[2]) & (carry[12] < max_iters) & carry[13]
-
-    def body(carry):
-        return _iteration(
-            program, n_lines, carry, batch, spin=spin,
-            gather=lambda x: x, mine=lambda x: x,
-            spin_tele_add=lambda t, a, b: add_tele(t, add_tele(a, b)))
-
-    out = jax.lax.while_loop(
-        cond, body, _init_carry(program, state, batch,
-                                zero_flat_tele(n_lines), W))
-    (state, _, done, dec, estep, retr, lns, mds, _, _, _, outs, _, ok,
-     rounds, tele, ctr) = out
-    return (state, dec, estep, retr, outs, lns, mds, jnp.all(done), ok,
-            rounds, tele, ctr)
-
-
-# ---------------------------------------------------- the sharded driver
 
 @functools.partial(
     jax.jit, static_argnames=("program", "mesh", "axis", "n_nodes",
@@ -513,98 +531,43 @@ def run_txn_rounds_sharded(state, node_id, typ, inp, lines, mode, *,
                            max_iters: int = 64,
                            bucket_cap: int | None = None,
                            backend: str = "ref"):
-    """Mesh mirror of :func:`run_txn_rounds`: the SAME iteration inside
-    one ``shard_map``.  Txn slots are block-distributed over the mesh
+    """Mesh form of :func:`run_txn_rounds`: the SAME loop inside one
+    ``shard_map``.  Txn slots are block-distributed over the mesh
     (B divisible by the shard count; pad with ``typ = -1`` rows), every
     grant and write decision is computed from an ``all_gather`` of the
-    batch's entries in GLOBAL slot order, every spin is the
-    two-all_to_alls ``_route_round`` loop, and liveness is a psum — the
-    flat return contract, with the congestion-telemetry dict of
+    batch's entries in GLOBAL slot order, every spin routes its rounds
+    (``sharded._route_round``), and liveness is a psum — the flat
+    return contract, with the congestion-telemetry dict of
     :func:`run_rounds_sharded`'s keys; decisions stay bit-identical."""
     co.check_node_capacity(n_nodes)
     n_shards = mesh.shape[axis]
     batch = _as_batch(node_id, typ, inp, lines, mode)
     B, M = batch[3].shape
-    W = payload_width(state)
     n_lines = state["words"].shape[0]
     if B % n_shards:
         raise ValueError(f"B={B} not divisible by n_shards={n_shards}")
     bl = B // n_shards
     _note_trace(("txn_sharded", program.name, n_shards, B, M,
                  program.inputs, n_nodes, max_rounds, max_iters,
-                 bucket_cap, backend, "dirty" in state, W,
-                 "home" in state, "replica" in state))
+                 bucket_cap, backend, "dirty" in state,
+                 payload_width(state), "home" in state,
+                 "replica" in state))
     specs = _state_specs(state, axis)
-    l_local = n_lines // n_shards
+    chunk = min(bl * M, SPIN_SLOTS)
+    cap = chunk if bucket_cap is None else bucket_cap
 
     def spmd(state_l, *batch_l):
         ai = jax.lax.axis_index(axis)
-
-        def gather(x):
-            return jax.lax.all_gather(x, axis, tiled=True)
-
-        def mine(x):
-            return jax.lax.dynamic_slice_in_dim(x, ai * bl, bl)
-
-        def raw_spin(stt_l, nodes, lns, is_write, wdata):
-            # run_rounds composed from _route_round INSIDE this spmd
-            # (shard_map can't nest) — the run_rounds_sharded loop body
-            cap = bucket_cap if bucket_cap is not None else lns.shape[0]
-            if wdata is None:
-                wdata = jnp.zeros((lns.shape[0], W), jnp.int32)
-
-            def n_pending(p):
-                return jax.lax.psum(
-                    jnp.sum((p >= 0).astype(jnp.int32)), axis)
-
-            def s_cond(c):
-                return ~c[5] & (c[3] < max_rounds)
-
-            def s_body(c):
-                stt, pending, data, r, tele, _ = c
-                stt, served, _, rdata, dtele = _route_round(
-                    stt, nodes, pending, is_write, wdata,
-                    n_shards=n_shards, axis=axis, n_nodes=n_nodes,
-                    cap=cap, backend=backend)
-                data = jnp.where(served[:, None], rdata, data)
-                pending = jnp.where(served, jnp.int32(-1), pending)
-                return (stt, pending, data, r + 1,
-                        _add_tele(tele, dtele), n_pending(pending) == 0)
-
-            init = (stt_l, lns, jnp.zeros((lns.shape[0], W), jnp.int32),
-                    jnp.int32(0), _zero_tele(n_shards, l_local),
-                    n_pending(lns) == 0)
-            stt_l, _, data, r, tele, done = jax.lax.while_loop(
-                s_cond, s_body, init)
-            return stt_l, data, r, done, tele
-        spin = _chunked(raw_spin, min(bl * M, SPIN_SLOTS),
-                        _zero_tele(n_shards, l_local), _add_tele,
-                        total=lambda x: jax.lax.pmax(x, axis))
-
-        def n_live(done):
-            return jax.lax.psum(jnp.sum((~done).astype(jnp.int32)), axis)
-
-        def cond(c):
-            carry, alldone = c
-            return ~alldone & (carry[12] < max_iters) & carry[13]
-
-        def body(c):
-            carry = _iteration(
-                program, n_lines, c[0], batch_l, spin=spin,
-                gather=gather, mine=mine,
-                spin_tele_add=lambda t, a, b: _add_tele(_add_tele(t, a),
-                                                        b))
-            return carry, n_live(carry[2]) == 0
-
-        init = _init_carry(program, state_l, batch_l,
-                           _zero_tele(n_shards, l_local), W)
-        carry, alldone = jax.lax.while_loop(
-            cond, body, (init, n_live(init[2]) == 0))
-        (state_l, _, _, dec, estep, retr, lns, mds, _, _, _, outs, _, ok,
-         rounds, tele, ctr) = carry
-        return ((state_l, dec, estep, retr, outs, lns, mds, alldone, ok,
-                 rounds)
-                + _tele_rows(tele) + tuple(ctr[k] for k in COUNTER_KEYS))
+        step, count = _router(n_shards, axis, n_nodes, cap, backend)
+        out, tele, ctr = _run(
+            program, state_l, batch_l, n_lines=n_lines, step=step,
+            count=count,
+            gather=lambda x: jax.lax.all_gather(x, axis, tiled=True),
+            mine=lambda x: jax.lax.dynamic_slice_in_dim(x, ai * bl, bl),
+            total=lambda x: jax.lax.pmax(x, axis), chunk=chunk,
+            tele0=_zero_tele(n_shards, n_lines // n_shards),
+            max_rounds=max_rounds, max_iters=max_iters)
+        return out + tele + tuple(ctr[k] for k in COUNTER_KEYS)
 
     nk = len(COUNTER_KEYS)
     out = jax.shard_map(
@@ -615,7 +578,7 @@ def run_txn_rounds_sharded(state, node_id, typ, inp, lines, mode, *,
         check_vma=False,
     )(state, *batch)
     nt = len(TELE_KEYS)
-    return (out[:10] + (dict(zip(TELE_KEYS, out[10:10 + nt])),)
+    return (out[:10] + (tele_dict(out[10:10 + nt]),)
             + (dict(zip(COUNTER_KEYS, out[10 + nt:])),))
 
 
@@ -660,69 +623,3 @@ def resolve_program(algo, glines=None, rmask=None) -> TxnProgram:
         return algo
     return tuple_program(algo, int(np.shape(glines)[1]),
                          int(np.shape(rmask)[2]))
-
-
-def run_txn_batch(plane, node_id, *batch, algo, max_iters=None,
-                  max_rounds=None, span=None) -> TxnBatchResult:
-    """Drive one txn batch through ``plane`` (DevicePlane, flat or
-    sharded): ``batch`` is what the program's ``encode`` takes (for
-    ``"2pl"``/``"to"``: ``glines, rmask, wmask, ts``).  ``span`` is the
-    verb's bracket (its child phases are timed into ``host_s``)."""
-    from .plane import _Span
-    sp = _Span() if span is None else span
-    with sp.phase("pad"):
-        program = resolve_program(algo, *batch[:2])
-        typ, inp, lines, mode = program.encode(*batch)
-        if plane.payload_width != program.width:
-            raise ValueError(
-                f"plane payload_width={plane.payload_width} but program "
-                f"{program.name!r} needs {program.width} lanes")
-        B, M = lines.shape
-        node_id = np.broadcast_to(np.asarray(node_id, np.int32),
-                                  (B,)).astype(np.int32)
-        mr = plane.max_rounds if max_rounds is None else max_rounds
-        mi = 4 * B + 16 if max_iters is None else max_iters
-        if plane.sharded:
-            pad = (-B) % plane.n_shards
-            if pad:
-                node_id = np.concatenate([node_id, np.zeros(pad, np.int32)])
-                typ = np.concatenate([typ, np.full(pad, -1, np.int32)])
-                inp = np.concatenate([inp, np.zeros((pad,) + inp.shape[1:],
-                                                    np.int32)])
-                lines = np.concatenate([lines, np.full((pad, M), -1,
-                                                       np.int32)])
-                mode = np.concatenate([mode, np.zeros((pad, M), np.int32)])
-    with sp.phase("dispatch"):
-        if plane.sharded:
-            out = run_txn_rounds_sharded(
-                plane.state, node_id, typ, inp, lines, mode,
-                program=program, mesh=plane.mesh, axis=plane.axis,
-                n_nodes=plane.n_nodes, max_rounds=mr, max_iters=mi,
-                bucket_cap=plane.bucket_cap, backend=plane.backend)
-        else:
-            out = run_txn_rounds(
-                plane.state, node_id, typ, inp, lines, mode,
-                program=program, n_nodes=plane.n_nodes, max_rounds=mr,
-                max_iters=mi, backend=plane.backend)
-    (state, dec, estep, retr, outs, lns, mds, alldone, ok, rounds, tele,
-     ctr) = out
-    with sp.phase("sync"):
-        spins_ok, all_done = bool(ok), bool(alldone)
-    if not spins_ok:
-        raise RuntimeError(f"txn coherence spin hit max_rounds={mr}")
-    if not all_done:
-        raise RuntimeError(
-            f"txn batch not done after {mi} scheduler iterations "
-            f"(livelock? raise max_iters)")
-    plane.state = state
-    with sp.phase("telemetry"):
-        telemetry = plane._telemetry(tele)
-    with sp.phase("results"):
-        counters = {k: int(v) for k, v in jax.device_get(ctr).items()}
-        counters["attempts"] = B + counters["restarts"]
-        res = TxnBatchResult(
-            np.asarray(dec)[:B], np.asarray(estep)[:B],
-            np.asarray(retr)[:B], counters["iters"], int(rounds),
-            telemetry, np.asarray(outs)[:B], np.asarray(lns)[:B],
-            np.asarray(mds)[:B], counters, sp.host_s)
-    return res

@@ -258,6 +258,122 @@ def test_sharded_eviction_write_back_parity():
     assert int(np.asarray(gathered["mem_version"])[0]) == 1  # flushed
 
 
+# ------------------------------------------ flat vs one-shard drivers
+
+def _bump(data, line):
+    return data + jnp.where(line >= 0, 1, 0)[:, None]
+
+
+def _walk(data, key):
+    """Descent transition over a binary tree: lanes 0/1 hold a node's
+    two children (-1 at a leaf), the key's parity picks one."""
+    nxt = jnp.where(key % 2 == 0, data[:, 0], data[:, 1])
+    return nxt < 0, jnp.zeros(nxt.shape, bool), nxt
+
+
+def _drive(verb, mesh):
+    """Three contended batches of ``verb`` through its flat entry point
+    (``mesh`` None) or its sharded one, the state carried along."""
+    from repro.core.rounds.txn import tuple_program
+    rng = np.random.default_rng(7)
+    n, l, r = 4, 8, 12
+    prog = tuple_program("2pl", 2, 2)
+    state = rp.make_state(n, l, write_back=True,
+                          payload_width=prog.width if verb == "txn" else 2)
+    if verb == "descent":
+        kids = np.full((l, 2), -1, np.int32)
+        kids[:3] = [[1, 2], [3, 4], [5, 6]]
+        state["mem_data"] = jnp.asarray(kids)
+    kw = {"n_nodes": n}
+    if mesh is not None:
+        state = rp.shard_state(state, mesh)
+        kw["mesh"] = mesh
+    sh = "" if mesh is None else "_sharded"
+    outs = []
+    for _ in range(3):
+        node = rng.integers(0, n, r).astype(np.int32)
+        line = rng.integers(0, l, r).astype(np.int32)
+        if verb == "ops":
+            out = getattr(rp, "run_rounds" + sh)(
+                state, node, line, rng.integers(0, 2, r).astype(np.int32),
+                rng.integers(1, 99, (r, 2)).astype(np.int32), **kw)
+        elif verb == "rmw":
+            out = getattr(rp, "run_rmw" + sh)(state, node, line, (),
+                                              modify=_bump, **kw)
+        elif verb == "descent":
+            root = np.where(rng.random(r) < 0.2, -1, 0).astype(np.int32)
+            out = getattr(rp, "run_descent" + sh)(
+                state, node, line, root, transition=_walk, **kw)
+        else:
+            b = 6
+            glines = np.sort(np.stack([rng.choice(l, 2, replace=False)
+                                       for _ in range(b)]), axis=1)
+            batch = prog.encode(glines, rng.integers(0, 2, (b, 2, 2)),
+                                rng.integers(0, 2, (b, 2, 2)),
+                                rng.integers(1, 50, b))
+            out = getattr(rp, "run_txn_rounds" + sh)(
+                state, node[:b], *batch, program=prog,
+                max_iters=4 * b + 16, **kw)
+        state = out[0]
+        outs.append(jax.device_get(out))
+    return outs
+
+
+@pytest.mark.parametrize("verb", ["ops", "rmw", "descent", "txn"])
+def test_flat_and_one_shard_drivers_agree(verb):
+    """Each verb's flat driver and its sharded driver on a 1-shard mesh
+    run the same loop over their own round step: bit-identical results
+    and state, and telemetry dicts equal in keys, shapes, dtypes and
+    values."""
+    flat = _drive(verb, None)
+    shd = _drive(verb, _mesh1())
+    at = -2 if verb == "txn" else -1             # the telemetry dict
+    for f, s in zip(flat, shd):
+        assert jax.tree_util.tree_structure(f) == \
+            jax.tree_util.tree_structure(s)
+        assert set(f[at]) == set(s[at]) == {
+            "occupancy", "deferred", "served_per_home", "replica_served",
+            "slot_hits", "slot_whits", "events"}
+        assert f[at]["occupancy"].shape == (1, 1)
+        assert f[at]["events"].shape == (1, 3)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(f)[0],
+                jax.tree_util.tree_leaves(s)):
+            a, b = np.asarray(a), np.asarray(b)
+            where = jax.tree_util.keystr(path)
+            assert a.shape == b.shape and a.dtype == b.dtype, where
+            np.testing.assert_array_equal(a, b, err_msg=where)
+    # the batches take latches and, but for the read-only descent,
+    # contend: some request failed and was retried
+    events = sum(o[at]["events"][0] for o in flat)
+    assert events[0] > 0 and (events[1] > 0 or verb == "descent")
+
+
+def test_bucket_overflow_keeps_capacity_requests_intact():
+    """A FULL bucket plus overflow/pad slots: the first `cap` requests
+    must survive bucketing untouched and the overflow must be counted.
+    Non-kept slots once scattered INTO cell (0, cap-1) and could clobber
+    a legitimate request whenever its bucket was exactly full."""
+    from repro.core.rounds.sharded import _bucket
+    cap, n_shards = 2, 2
+    req = {
+        "node": jnp.asarray([1, 1, 1, 1, 0], jnp.int32),
+        "line": jnp.asarray([0, 2, 4, 1, -1], jnp.int32),  # 3x home0 + pad
+        "isw": jnp.asarray([11, 22, 33, 44, 0], jnp.int32),
+    }
+    buckets, order, keep, _, dropped = _bucket(req, n_shards, cap,
+                                               fields=tuple(req))
+    assert int(dropped) == 1                  # line 4 overflowed home 0
+    # home 0's bucket holds exactly the first two home-0 requests
+    np.testing.assert_array_equal(np.asarray(buckets["line"][0]), [0, 2])
+    np.testing.assert_array_equal(np.asarray(buckets["isw"][0]), [11, 22])
+    np.testing.assert_array_equal(np.asarray(buckets["line"][1]),
+                                  [1, -1])    # home 1: one request + pad
+    # per-original-slot sent mask: line 4 and the pad were NOT sent
+    sent = np.asarray(keep)[np.argsort(np.asarray(order))]
+    np.testing.assert_array_equal(sent, [True, True, False, True, False])
+
+
 # ------------------------------------------------------------- guards
 
 def test_pad_ops_pads_to_shard_multiple():
